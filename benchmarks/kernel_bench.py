@@ -36,10 +36,11 @@ from repro.kernels.spoga_gemm import DEFAULT_BLOCK_K, DEFAULT_BLOCK_M, DEFAULT_B
 SHAPES = ((256, 512, 256), (512, 2048, 512), (1024, 4096, 1024))
 QUICK_SHAPES = ((256, 512, 256),)
 
-# Pallas-interpreter backends execute the kernel body in Python; on
-# non-TPU hosts only time them on the smallest shape.
-_INTERPRETED_OFF_TPU = ("pallas_spoga", "pallas_spoga_dequant", "pallas_deas",
-                        "pallas_interpret")
+# The compiled Pallas names run only on a TPU (off it they raise); their
+# interpret twins execute the kernel bodies in Python, so off-TPU those are
+# timed on the smallest shape only.
+_TPU_ONLY = ("pallas_spoga", "pallas_spoga_dequant", "pallas_deas")
+_INTERPRETED = ("pallas_interpret", "pallas_deas_interpret")
 
 
 def _ceil(a: int, b: int) -> int:
@@ -100,19 +101,22 @@ def run(shapes=SHAPES) -> tuple[list[str], list[dict]]:
                 "platform": jax.default_backend(),
                 "us_per_call": None,
             }
-            timed = on_tpu or name not in _INTERPRETED_OFF_TPU \
-                or (m, k, n) == min(shapes)
-            if timed:
+            skip = None
+            if not on_tpu and name in _TPU_ONLY:
+                skip = "(tpu only)"
+            elif not on_tpu and name in _INTERPRETED and (m, k, n) != min(shapes):
+                skip = "(skipped)"
+            if skip is None:
                 fn = jax.jit(lambda a, b, _b=backend, _s=spec: _b.gemm(a, b, _s))
                 rec["us_per_call"] = round(_time(fn, x, w), 1)
-            us = f"{rec['us_per_call']:12.1f}" if rec["us_per_call"] is not None \
-                else f"{'(skipped)':>12s}"
+            us = f"{rec['us_per_call']:12.1f}" if skip is None else f"{skip:>12s}"
             lines.append(f"{f'{m}x{k}x{n}':>18s} {name:>22s} {us} "
                          f"{nbytes:14.3e} {nbytes / base:9.2f}x")
             records.append(rec)
     lines.append("(deas/spoga HBM ratio == the intermediate-matrix round trips "
-                 "the paper eliminates; Fig. 2a vs 2b. Interpreted Pallas "
-                 "backends are timed only on the smallest shape off-TPU.)")
+                 "the paper eliminates; Fig. 2a vs 2b. Off-TPU the compiled "
+                 "Pallas names are skipped and their interpret twins are "
+                 "timed only on the smallest shape.)")
     return lines, records
 
 

@@ -30,12 +30,11 @@ import numpy as np
 from repro.api.config import RuntimeConfig
 from repro.api.outputs import RequestOutput
 from repro.configs import get_config, reduced as reduce_config
-from repro.models import init_params
 from repro.serving.engine import ServingEngine
 from repro.serving.policies import EnginePolicies
 from repro.serving.request import RequestState, default_detokenizer
 from repro.serving.sampling import SamplingParams
-from repro.shard import build_mesh, shard_params
+from repro.shard import build_mesh, init_sharded_params, shard_params
 
 Prompt = Sequence[int]
 
@@ -62,21 +61,22 @@ class LLM:
         # the single resolution step (model side): RuntimeConfig owns the
         # runtime knobs; the result is the plain frozen ModelConfig jit keys on
         self.config = self.runtime.resolve_model(base)
-        if params is not None:
-            self.params = params
-        else:
-            self.params = init_params(self.config, jax.random.PRNGKey(seed))
-        if checkpoint_dir is not None:
-            from repro.checkpoint.checkpoint import restore_checkpoint
-
-            self.params = restore_checkpoint(checkpoint_dir, None, self.params)
         # sharded serving (repro/shard/): resolve the per-arch Megatron
         # PartitionSpecs into NamedShardings and commit the weights once,
         # here — every engine dispatch then sees the TP layout as a stable
         # input constraint.  mesh=None (the default config) changes nothing.
         self.mesh = build_mesh(self.runtime.mesh)
+        if params is None:
+            # built already sharded: no device ever holds the whole model
+            params = init_sharded_params(self.config, jax.random.PRNGKey(seed),
+                                         self.mesh)
+        if checkpoint_dir is not None:
+            from repro.checkpoint.checkpoint import restore_checkpoint
+
+            params = restore_checkpoint(checkpoint_dir, None, params)
         if self.mesh is not None:
-            self.params = shard_params(self.params, self.mesh, self.config)
+            params = shard_params(params, self.mesh, self.config)
+        self.params = params
         self.tokenizer = tokenizer or default_detokenizer
         self._policies = (policies if policies is not None
                           else self.runtime.build_policies())

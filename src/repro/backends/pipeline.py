@@ -27,6 +27,7 @@ from repro.backends import impls  # noqa: F401  (populates the registry)
 from repro.backends.registry import resolve_backend
 from repro.backends.spec import parse_quant_mode
 from repro.obs import watchdog as _watchdog
+from repro.runtime.sharding import ambient_mesh
 
 __all__ = ["dynamic_quant", "effective_bits", "quantized_linear", "gemm_int"]
 
@@ -131,8 +132,13 @@ def quantized_linear(
     out_dtype=None,
     watch: Optional[bool] = None,
     layer: Optional[str] = None,
+    tp: Optional[str] = None,
 ) -> jnp.ndarray:
     """x (..., K) fp @ w (K, N) fp -> (..., N) fp via the quantized pipeline.
+
+    ``tp`` ("col" | "row" | None) names the weight's tensor-parallel layout;
+    it only matters for a Pallas backend traced under a mesh, where the
+    kernel runs inside ``shard_map`` (``shard/kernels.py``).
 
     ``watch``/``layer`` drive the numerics watchdog explicitly; by
     default the ambient trace-time context set by the model entry points
@@ -162,7 +168,12 @@ def quantized_linear(
     x2 = xq.reshape(-1, k)
     xs2 = xs.reshape(-1, 1)
     ws2 = ws.reshape(1, n)
-    if b.gemm_dequant is not None:
+    mesh = ambient_mesh() if b.pallas else None
+    if mesh is not None:
+        from repro.shard.kernels import sharded_gemm_dequant  # lazy: layering
+
+        out = sharded_gemm_dequant(b, x2, wq, xs2, ws2, spec, mesh, tp)
+    elif b.gemm_dequant is not None:
         out = b.gemm_dequant(x2, wq, xs2, ws2, spec)
     else:
         out = b.gemm(x2, wq, spec).astype(jnp.float32) * xs2 * ws2

@@ -43,6 +43,8 @@ class GemmBackend:
     (M, N) int32 round trip — exactly what the fused kernels avoid).
     ``supports(spec)`` gates specs the strategy cannot express (e.g. the
     materialized DEAS Pallas baseline is pinned to the paper's 2x4b W8A8).
+    ``pallas`` marks a Mosaic kernel: under a mesh it runs inside
+    ``shard_map`` (automatic partitioning cannot split it).
     """
 
     name: str
@@ -51,6 +53,7 @@ class GemmBackend:
     gemm_dequant: Optional[Callable] = None
     supports: Callable[[QuantSpec], bool] = lambda spec: True
     description: str = ""
+    pallas: bool = False
 
 
 _REGISTRY: dict[str, GemmBackend] = {}

@@ -10,11 +10,15 @@ ever materializing the gathered (B, S, H, D) view that the jnp twin
 builds.  A flash-style running softmax (per-lane max / denominator / value
 accumulator in VMEM scratch) folds the pages into the output in one pass.
 
-The int8 byte-size variant fuses page dequantization: int8 payloads ride
-the dot products and the per-(position, head) scales multiply the scores /
-probabilities — the paper's byte-size operand stream applied to decode's
-dominant HBM traffic, in the same shape as ``spoga_gemm_dequant`` fuses
-the epilogue.
+The grid is (lane, page): each step takes one whole page for all KV
+heads, so a block's last two dims are the pool's own (Mosaic refuses a
+size-1 block on ``H_kv``), and the heads are unrolled in the body.
+
+The int8 byte-size variant fuses page dequantization: int8 payloads are
+read from the pool and the per-(position, head) scales multiply the K / V
+rows in VMEM before the dot products — the paper's byte-size operand
+stream applied to decode's dominant HBM traffic, in the same shape as
+``spoga_gemm_dequant`` fuses the epilogue.
 
 Layouts (G = query heads per KV head):
 
@@ -25,8 +29,10 @@ Layouts (G = query heads per KV head):
     lengths  (B,)   int32 valid rows per lane (pos + 1 at decode)
     out      (B, H_kv, G, D) f32
 
-CI runs the kernel through the Pallas interpreter (``interpret=True``),
-mirroring the ``pallas_interpret`` GEMM backend.
+Off-TPU the kernel runs only through the Pallas interpreter
+(``interpret=True``, ``paged_attn_impl="pallas_interpret"``), mirroring the
+``pallas_interpret`` GEMM backend; under a mesh it runs per device inside
+``shard_map`` (``shard/kernels.py``).
 """
 
 from __future__ import annotations
@@ -45,11 +51,19 @@ NEG_INF = -1e30
 
 def _kernel(tables_ref, lengths_ref, q_ref, kp_ref, vp_ref, *rest,
             page_size: int, n_tbl: int, int8: bool):
+    """One grid step = one page of one lane, for all of its KV heads.
+
+    The K/V block is a whole page ``(1, page_size, H_kv, D)``: its last two
+    dims equal the pool's, which is what Mosaic requires of a block (a
+    size-1 block on ``H_kv`` is refused).  The heads are unrolled in the
+    body; each keeps its own flash state in the scratch rows ``[h]``.
+    """
     if int8:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
-    b, p = pl.program_id(0), pl.program_id(2)
+    b, p = pl.program_id(0), pl.program_id(1)
+    n_heads, _, d = q_ref.shape[1:]
 
     @pl.when(p == 0)
     def _init():
@@ -57,34 +71,37 @@ def _kernel(tables_ref, lengths_ref, q_ref, kp_ref, vp_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                    # (G, D)
-    k = kp_ref[0, :, 0, :].astype(jnp.float32)             # (page_size, D)
-    d = q.shape[-1]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-    ) * (d ** -0.5)                                        # (G, page_size)
-    if int8:
-        s = s * ks_ref[0, :, 0][None, :]                   # fused dequant (K)
-    kpos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(kpos < lengths_ref[b], s, NEG_INF)
+    for h in range(n_heads):
+        q = q_ref[0, h].astype(jnp.float32)                # (G, D)
+        k = kp_ref[0, :, h, :].astype(jnp.float32)         # (page_size, D)
+        v = vp_ref[0, :, h, :].astype(jnp.float32)
+        if int8:
+            # fused dequant: per-row scales ride the page rows, so
+            # q.(k*ks) == (q.k)*ks and p@(v*vs) == (p*vs)@v
+            k = k * ks_ref[0, :, h:h + 1]
+            v = v * vs_ref[0, :, h:h + 1]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        ) * (d ** -0.5)                                    # (G, page_size)
+        kpos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < lengths_ref[b], s, NEG_INF)
 
-    # flash update: m/l scratches are (G, 128) lane-replicated scalars
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    pexp = jnp.exp(s - m_new[:, :1])                       # (G, page_size)
-    l_ref[...] = alpha * l_prev + jnp.sum(pexp, axis=1, keepdims=True)
-    m_ref[...] = m_new
-    if int8:
-        pexp = pexp * vs_ref[0, :, 0][None, :]             # fused dequant (V)
-    v = vp_ref[0, :, 0, :].astype(jnp.float32)             # (page_size, D)
-    pv = jax.lax.dot_general(
-        pexp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
+        # flash update: m/l scratches are (G, 128) lane-replicated scalars
+        m_prev, l_prev = m_ref[h], l_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        pexp = jnp.exp(s - m_new[:, :1])                   # (G, page_size)
+        l_ref[h] = alpha * l_prev + jnp.sum(pexp, axis=1, keepdims=True)
+        m_ref[h] = m_new
+        pv = jax.lax.dot_general(
+            pexp, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc_ref[h] = acc_ref[h] * alpha[:, :1] + pv
 
     @pl.when(p == n_tbl - 1)
     def _emit():
-        o_ref[0, 0] = acc_ref[...] / l_ref[...][:, :1]
+        for h in range(n_heads):
+            o_ref[0, h] = acc_ref[h] / l_ref[h][:, :1]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -100,37 +117,37 @@ def paged_attention(q, kp, vp, tables, lengths, *, k_scale=None,
     n_tbl = tables.shape[1]
     int8 = k_scale is not None
 
-    def q_idx(bi, hi, pi, tbl, ln):
-        return (bi, hi, 0, 0)
+    def q_idx(bi, pi, tbl, ln):
+        return (bi, 0, 0, 0)
 
-    def kv_idx(bi, hi, pi, tbl, ln):
-        return (tbl[bi, pi], 0, hi, 0)
+    def kv_idx(bi, pi, tbl, ln):
+        return (tbl[bi, pi], 0, 0, 0)
 
-    def scale_idx(bi, hi, pi, tbl, ln):
-        return (tbl[bi, pi], 0, hi)
+    def scale_idx(bi, pi, tbl, ln):
+        return (tbl[bi, pi], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), q_idx),
-        pl.BlockSpec((1, page_size, 1, d), kv_idx),
-        pl.BlockSpec((1, page_size, 1, d), kv_idx),
+        pl.BlockSpec((1, hkv, g, d), q_idx),
+        pl.BlockSpec((1, page_size, hkv, d), kv_idx),
+        pl.BlockSpec((1, page_size, hkv, d), kv_idx),
     ]
     operands = [q, kp, vp]
     if int8:
         in_specs += [
-            pl.BlockSpec((1, page_size, 1), scale_idx),
-            pl.BlockSpec((1, page_size, 1), scale_idx),
+            pl.BlockSpec((1, page_size, hkv), scale_idx),
+            pl.BlockSpec((1, page_size, hkv), scale_idx),
         ]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, n_tbl),
+        grid=(b, n_tbl),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d), q_idx),
+        out_specs=pl.BlockSpec((1, hkv, g, d), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((g, 128), jnp.float32),   # running max
-            pltpu.VMEM((g, 128), jnp.float32),   # running denominator
-            pltpu.VMEM((g, d), jnp.float32),     # value accumulator
+            pltpu.VMEM((hkv, g, 128), jnp.float32),   # running max
+            pltpu.VMEM((hkv, g, 128), jnp.float32),   # running denominator
+            pltpu.VMEM((hkv, g, d), jnp.float32),     # value accumulator
         ],
     )
     return pl.pallas_call(
@@ -138,7 +155,7 @@ def paged_attention(q, kp, vp, tables, lengths, *, k_scale=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
         compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)

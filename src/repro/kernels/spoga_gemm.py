@@ -30,9 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 RADIX_BITS = 4
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; take
-# whichever this installation provides.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+CompilerParams = pltpu.CompilerParams
 
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_N = 256
@@ -49,10 +47,14 @@ def _slice_tc(t):
 def _slice_planes_tile(t, n_slices: int, slice_bits: int):
     """In-VMEM generalization of ``_slice_tc``: n planes, LSB first.
 
-    Planes are cast to int8 (they fit for slice_bits <= 7 when the operand
-    honors its n_slices * slice_bits budget) so every partial product runs
-    on the MXU's byte path regardless of the source operand width.
+    The shifts run on the tile widened to int32 (Mosaic has no arithmetic
+    right shift for 8/16-bit vectors; sign extension makes the widened
+    shift bitwise equal to the narrow one).  Planes are then cast to int8
+    (they fit for slice_bits <= 7 when the operand honors its
+    n_slices * slice_bits budget) so every partial product runs on the
+    MXU's byte path regardless of the source operand width.
     """
+    t = t.astype(jnp.int32)
     mask = (1 << slice_bits) - 1
     planes = [
         jnp.bitwise_and(jnp.right_shift(t, j * slice_bits), mask).astype(jnp.int8)

@@ -25,13 +25,10 @@ def _make_mesh(shape, axes):
             f"only {have} are visible; on CPU, export "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             "BEFORE jax initializes (or shrink the mesh)")
-    # jax.sharding.AxisType (explicit-mesh API) only exists on newer jax;
-    # older releases default every axis to Auto, which is what we want.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the sharding rules are PartitionSpec constraints for the
+    # partitioner to propagate (jax.make_mesh defaults to Explicit axes).
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

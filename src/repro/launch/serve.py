@@ -81,6 +81,7 @@ from repro.api import (
     serve_batch,
 )
 from repro.configs import default_cache_len
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.frontends import fake_audio_frames, fake_vision_embeds
 
 
@@ -263,7 +264,8 @@ def _runtime_from_args(args) -> RuntimeConfig:
     )
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The CLI's flags; ``build_parser().parse_args([])`` are its defaults."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--reduced", action="store_true")
@@ -383,7 +385,12 @@ def main():
                          "jax.device_count() divisible by tp; use "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=N "
                          "to fake a multi-device host mesh")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
 
     runtime = (load_runtime(args.runtime) if args.runtime
                else _runtime_from_args(args))

@@ -22,6 +22,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, reduced as reduce_config
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import SyntheticTokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import init_params
 from repro.optim.optimizers import adamw_init
@@ -84,6 +85,7 @@ def main():
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -119,24 +119,18 @@ def _constrain_heads(t):
 
     Without the explicit constraint the partitioner reshards the chunked
     attention's 6-D reshapes through an 'involuntary full
-    rematerialization' (replicate-then-repartition) path."""
-    try:
-        from jax.sharding import PartitionSpec as P
-        from jax._src import mesh as mesh_lib
+    rematerialization' (replicate-then-repartition) path.  Identity outside
+    a mesh; dims that don't divide are replicated."""
+    from jax.sharding import PartitionSpec as P
 
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m.empty or t.ndim != 4:
-            return t
-        dp = tuple(a for a in m.axis_names if a in ("pod", "data"))
-        dp_size = 1
-        for a in dp:
-            dp_size *= m.shape[a]
-        model = m.shape.get("model", 1)
-        first = dp if (dp and t.shape[0] % dp_size == 0) else None
-        heads = "model" if t.shape[2] % model == 0 else None
-        return jax.lax.with_sharding_constraint(t, P(first, None, heads, None))
-    except Exception:  # pragma: no cover
+    from repro.runtime.sharding import ambient_mesh, dp_axis_for, model_axis_for
+
+    m = ambient_mesh()
+    if m is None or t.ndim != 4:
         return t
+    return jax.lax.with_sharding_constraint(
+        t, P(dp_axis_for(m, t.shape[0]), None, model_axis_for(m, t.shape[2]),
+             None))
 
 
 def attention_block(x, p, cfg: ModelConfig, positions, *, causal=True, window=None):
@@ -144,14 +138,14 @@ def attention_block(x, p, cfg: ModelConfig, positions, *, causal=True, window=No
     b, s, _ = x.shape
     hd, hq, hkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    q = linear(x, p["wq"], qm, be).reshape(b, s, hq, hd)
-    k = linear(x, p["wk"], qm, be).reshape(b, s, hkv, hd)
-    v = linear(x, p["wv"], qm, be).reshape(b, s, hkv, hd)
+    q = linear(x, p["wq"], qm, be, tp="col").reshape(b, s, hq, hd)
+    k = linear(x, p["wk"], qm, be, tp="col").reshape(b, s, hkv, hd)
+    v = linear(x, p["wv"], qm, be, tp="col").reshape(b, s, hkv, hd)
     q = _constrain_heads(apply_rope(q, positions, cfg.rope_theta))
     k = _constrain_heads(apply_rope(k, positions, cfg.rope_theta))
     v = _constrain_heads(v)
     out = multihead_attention(q, k, v, causal=causal, window=window)
-    return linear(out.reshape(b, s, hq * hd), p["wo"], qm, be), (k, v)
+    return linear(out.reshape(b, s, hq * hd), p["wo"], qm, be, tp="row"), (k, v)
 
 
 def quantize_kv(t):
@@ -169,9 +163,9 @@ def _decode_qkv(x_t, p, cfg: ModelConfig, pos):
     b = x_t.shape[0]
     hd, hq, hkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    q = linear(x_t, p["wq"], qm, be).reshape(b, 1, hq, hd)
-    k = linear(x_t, p["wk"], qm, be).reshape(b, 1, hkv, hd)
-    v = linear(x_t, p["wv"], qm, be).reshape(b, 1, hkv, hd)
+    q = linear(x_t, p["wq"], qm, be, tp="col").reshape(b, 1, hq, hd)
+    k = linear(x_t, p["wk"], qm, be, tp="col").reshape(b, 1, hkv, hd)
+    v = linear(x_t, p["wv"], qm, be, tp="col").reshape(b, 1, hkv, hd)
     posb = pos[:, None]
     q = _constrain_heads(apply_rope(q, posb, cfg.rope_theta))
     k = _constrain_heads(apply_rope(k, posb, cfg.rope_theta))
@@ -184,9 +178,9 @@ def _verify_qkv(x, p, cfg: ModelConfig, pos):
     b, w, _ = x.shape
     hd, hq, hkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    q = linear(x, p["wq"], qm, be).reshape(b, w, hq, hd)
-    k = linear(x, p["wk"], qm, be).reshape(b, w, hkv, hd)
-    v = linear(x, p["wv"], qm, be).reshape(b, w, hkv, hd)
+    q = linear(x, p["wq"], qm, be, tp="col").reshape(b, w, hq, hd)
+    k = linear(x, p["wk"], qm, be, tp="col").reshape(b, w, hkv, hd)
+    v = linear(x, p["wv"], qm, be, tp="col").reshape(b, w, hkv, hd)
     positions = pos[:, None] + jnp.arange(w)[None, :]
     q = _constrain_heads(apply_rope(q, positions, cfg.rope_theta))
     k = _constrain_heads(apply_rope(k, positions, cfg.rope_theta))
@@ -280,7 +274,7 @@ def attention_decode(x_t, p, cfg: ModelConfig, cache, pos, *, window=None):
         valid = kpos <= pos[:, None]
     out = _decode_attend(qg, k_cache, v_cache, k_scale, v_scale, valid)
     out = out.astype(x_t.dtype).reshape(b, 1, hq * hd)
-    return linear(out, p["wo"], qm, be), new_cache
+    return linear(out, p["wo"], qm, be, tp="row"), new_cache
 
 
 def attention_verify(x, p, cfg: ModelConfig, cache, pos):
@@ -329,7 +323,7 @@ def attention_verify(x, p, cfg: ModelConfig, cache, pos):
     valid = _verify_valid(pos, w, smax)
     out = _decode_attend(qg, k_cache, v_cache, k_scale, v_scale, valid)
     out = out.astype(x.dtype).reshape(b, w, hq * hd)
-    return linear(out, p["wo"], qm, be), new_cache
+    return linear(out, p["wo"], qm, be, tp="row"), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -337,9 +331,14 @@ def attention_verify(x, p, cfg: ModelConfig, cache, pos):
 # ---------------------------------------------------------------------------
 
 def _resolve_paged_impl(cfg: ModelConfig) -> str:
-    if cfg.paged_attn_impl is not None:
-        return cfg.paged_attn_impl
-    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+    on_tpu = jax.default_backend() == "tpu"
+    impl = cfg.paged_attn_impl or ("pallas" if on_tpu else "jnp")
+    if impl == "pallas" and not on_tpu:
+        raise RuntimeError(
+            "paged_attn_impl='pallas' compiles a Pallas TPU kernel, but JAX's "
+            f"default backend is {jax.default_backend()!r}; use "
+            "'pallas_interpret' (the kernel body in the interpreter) or 'jnp'")
+    return impl
 
 
 def _write_page(tables, pos, page_size, active):
@@ -414,10 +413,10 @@ def paged_attention_decode(x_t, p, cfg: ModelConfig, cache, pos, tables, *,
         valid = jnp.arange(smax)[None, :] <= pos[:, None]
         out = _decode_attend(qg, k_all, v_all, ks_all, vs_all, valid)
     else:
-        from repro.kernels.paged_attention import paged_attention
+        from repro.shard.kernels import sharded_paged_attention
 
         qk = q[:, 0].reshape(b, g, hkv, hd).transpose(0, 2, 1, 3)  # (B,Hkv,G,D)
-        out = paged_attention(
+        out = sharded_paged_attention(
             qk, kp, vp, tables, pos + 1,
             k_scale=new_cache.get("kp_scale"),
             v_scale=new_cache.get("vp_scale"),
@@ -425,7 +424,7 @@ def paged_attention_decode(x_t, p, cfg: ModelConfig, cache, pos, tables, *,
         )
         out = out.transpose(0, 2, 1, 3)[:, None]  # (B, 1, G, Hkv, D)
     out = out.astype(x_t.dtype).reshape(b, 1, hq * hd)
-    return linear(out, p["wo"], qm, be), new_cache
+    return linear(out, p["wo"], qm, be, tp="row"), new_cache
 
 
 def _write_pages(tables, pos, w, page_size, active):
@@ -484,7 +483,7 @@ def paged_attention_verify(x, p, cfg: ModelConfig, cache, pos, tables, *,
     valid = _verify_valid(pos, w, smax)
     out = _decode_attend(qg, k_all, v_all, ks_all, vs_all, valid)
     out = out.astype(x.dtype).reshape(b, w, hq * hd)
-    return linear(out, p["wo"], qm, be), new_cache
+    return linear(out, p["wo"], qm, be, tp="row"), new_cache
 
 
 def _chunk_pages(tables_row, start, chunk, page_size):
@@ -512,9 +511,9 @@ def attention_chunk(x, p, cfg: ModelConfig, cache, tables_row, start, *,
     hd, hq, hkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     qm, be = cfg.quant_mode, cfg.gemm_backend
     int8_cache = "kp_scale" in cache
-    q = linear(x, p["wq"], qm, be).reshape(b, cs, hq, hd)
-    k = linear(x, p["wk"], qm, be).reshape(b, cs, hkv, hd)
-    v = linear(x, p["wv"], qm, be).reshape(b, cs, hkv, hd)
+    q = linear(x, p["wq"], qm, be, tp="col").reshape(b, cs, hq, hd)
+    k = linear(x, p["wk"], qm, be, tp="col").reshape(b, cs, hkv, hd)
+    v = linear(x, p["wv"], qm, be, tp="col").reshape(b, cs, hkv, hd)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
 
@@ -546,7 +545,7 @@ def attention_chunk(x, p, cfg: ModelConfig, cache, tables_row, start, *,
     qg = q.reshape(b, cs, hq // hkv, hkv, hd)
     out = _attend_chunk(qg, k_all, v_all, start[0], 0, True, None)
     out = out.astype(x.dtype).reshape(b, cs, hq * hd)
-    return linear(out, p["wo"], qm, be), new_cache
+    return linear(out, p["wo"], qm, be, tp="row"), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -562,18 +561,18 @@ def cross_attention_block(x, enc_kv, p, cfg: ModelConfig):
     b, s, _ = x.shape
     hd, hq = cfg.resolved_head_dim, cfg.n_heads
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    q = linear(x, p["wq"], qm, be).reshape(b, s, hq, hd)
+    q = linear(x, p["wq"], qm, be, tp="col").reshape(b, s, hq, hd)
     k, v = enc_kv
     out = multihead_attention(q, k, v, causal=False, window=None)
-    return linear(out.reshape(b, s, hq * hd), p["wo"], qm, be)
+    return linear(out.reshape(b, s, hq * hd), p["wo"], qm, be, tp="row")
 
 
 def encode_cross_kv(enc_out, p, cfg: ModelConfig):
     b, s, _ = enc_out.shape
     hd, hkv = cfg.resolved_head_dim, cfg.n_kv_heads
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    k = linear(enc_out, p["wk"], qm, be).reshape(b, s, hkv, hd)
-    v = linear(enc_out, p["wv"], qm, be).reshape(b, s, hkv, hd)
+    k = linear(enc_out, p["wk"], qm, be, tp="col").reshape(b, s, hkv, hd)
+    v = linear(enc_out, p["wv"], qm, be, tp="col").reshape(b, s, hkv, hd)
     return k, v
 
 
@@ -604,7 +603,7 @@ def _mla_qkv(x, p, cfg, positions):
     b, s, _ = x.shape
     qm, be = cfg.quant_mode, cfg.gemm_backend
     cq = rmsnorm(linear(x, p["w_dq"], qm, be), p["q_norm"], cfg.norm_eps)
-    q = linear(cq, p["w_uq"], qm, be).reshape(b, s, h, -1)
+    q = linear(cq, p["w_uq"], qm, be, tp="col").reshape(b, s, h, -1)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     c_kv = rmsnorm(linear(x, p["w_dkv"], qm, be), p["kv_norm"], cfg.norm_eps)
@@ -619,12 +618,12 @@ def mla_block(x, p, cfg: ModelConfig, positions):
     b, s, _ = x.shape
     qm, be = cfg.quant_mode, cfg.gemm_backend
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(x, p, cfg, positions)
-    k_nope = linear(c_kv, p["w_uk"], qm, be).reshape(b, s, h, m.qk_nope_head_dim)
-    v = linear(c_kv, p["w_uv"], qm, be).reshape(b, s, h, m.v_head_dim)
+    k_nope = linear(c_kv, p["w_uk"], qm, be, tp="col").reshape(b, s, h, m.qk_nope_head_dim)
+    v = linear(c_kv, p["w_uv"], qm, be, tp="col").reshape(b, s, h, m.v_head_dim)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, s, h, m.qk_rope_head_dim))], axis=-1)
     out = multihead_attention(q, k, v, causal=True)
-    out = linear(out.reshape(b, s, h * m.v_head_dim), p["wo"], qm, be)
+    out = linear(out.reshape(b, s, h * m.v_head_dim), p["wo"], qm, be, tp="row")
     return out, (c_kv, k_rope.reshape(b, s, m.qk_rope_head_dim))
 
 
@@ -682,7 +681,7 @@ def mla_decode(x_t, p, cfg: ModelConfig, ckv_cache, krope_cache, pos):
 
     out = _mla_attend(q_nope, q_rope, ckv_cache, krope_cache, pos, p, cfg)
     out = out.astype(x_t.dtype)
-    return linear(out, p["wo"], qm, be), (ckv_cache, krope_cache)
+    return linear(out, p["wo"], qm, be, tp="row"), (ckv_cache, krope_cache)
 
 
 def mla_verify(x, p, cfg: ModelConfig, ckv_cache, krope_cache, pos):
@@ -704,7 +703,7 @@ def mla_verify(x, p, cfg: ModelConfig, ckv_cache, krope_cache, pos):
 
     out = _mla_attend(q_nope, q_rope, ckv_cache, krope_cache, pos, p, cfg)
     out = out.astype(x.dtype)
-    return linear(out, p["wo"], qm, be), (ckv_cache, krope_cache)
+    return linear(out, p["wo"], qm, be, tp="row"), (ckv_cache, krope_cache)
 
 
 def mla_paged_decode(x_t, p, cfg: ModelConfig, cache, pos, tables, *,
@@ -732,7 +731,7 @@ def mla_paged_decode(x_t, p, cfg: ModelConfig, cache, pos, tables, *,
     kr_view = _gather_pages(krp, tables)
     out = _mla_attend(q_nope, q_rope, ckv_view, kr_view, pos, p, cfg)
     out = out.astype(x_t.dtype)
-    return linear(out, p["wo"], qm, be), new_cache
+    return linear(out, p["wo"], qm, be, tp="row"), new_cache
 
 
 def mla_paged_verify(x, p, cfg: ModelConfig, cache, pos, tables, *,
@@ -757,7 +756,7 @@ def mla_paged_verify(x, p, cfg: ModelConfig, cache, pos, tables, *,
     kr_view = _gather_pages(krp, tables)
     out = _mla_attend(q_nope, q_rope, ckv_view, kr_view, pos, p, cfg)
     out = out.astype(x.dtype)
-    return linear(out, p["wo"], qm, be), new_cache
+    return linear(out, p["wo"], qm, be, tp="row"), new_cache
 
 
 def mla_chunk(x, p, cfg: ModelConfig, cache, tables_row, start, *, positions):
@@ -786,8 +785,8 @@ def mla_chunk(x, p, cfg: ModelConfig, cache, tables_row, start, *, positions):
     ckv_all = _gather_pages(ckvp, tables_row)                  # (1, L, rank)
     kr_all = _gather_pages(krp, tables_row)                    # (1, L, rope)
     smax = ckv_all.shape[1]
-    k_nope = linear(ckv_all, p["w_uk"], qm, be).reshape(b, smax, h, m.qk_nope_head_dim)
-    v_all = linear(ckv_all, p["w_uv"], qm, be).reshape(b, smax, h, m.v_head_dim)
+    k_nope = linear(ckv_all, p["w_uk"], qm, be, tp="col").reshape(b, smax, h, m.qk_nope_head_dim)
+    v_all = linear(ckv_all, p["w_uv"], qm, be, tp="col").reshape(b, smax, h, m.v_head_dim)
     k_all = jnp.concatenate(
         [k_nope,
          jnp.broadcast_to(kr_all[:, :, None, :], (b, smax, h, m.qk_rope_head_dim))],
@@ -796,4 +795,4 @@ def mla_chunk(x, p, cfg: ModelConfig, cache, tables_row, start, *, positions):
     qg = q.reshape(b, cs, 1, h, q.shape[-1])
     out = _attend_chunk(qg, k_all, v_all, start[0], 0, True, None)
     out = out.astype(x.dtype).reshape(b, cs, h * m.v_head_dim)
-    return linear(out, p["wo"], qm, be), new_cache
+    return linear(out, p["wo"], qm, be, tp="row"), new_cache

@@ -34,21 +34,22 @@ def truncated_normal_init(key, shape, scale=0.02, dtype=PARAM_DTYPE):
 # forward, straight-through backward (QAT-compatible).
 # ---------------------------------------------------------------------------
 
-def _quantized_forward(x, w, mode, backend):
+def _quantized_forward(x, w, mode, backend, tp):
     """x (..., K) fp, w (K, N) fp -> (..., N) fp via the backend pipeline."""
-    return quantized_linear(x, w, mode, backend=backend, out_dtype=x.dtype)
+    return quantized_linear(x, w, mode, backend=backend, out_dtype=x.dtype,
+                            tp=tp)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _qmatmul_ste(x, w, mode: str, backend):
-    return _quantized_forward(x, w, mode, backend)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _qmatmul_ste(x, w, mode: str, backend, tp):
+    return _quantized_forward(x, w, mode, backend, tp)
 
 
-def _qmatmul_fwd(x, w, mode, backend):
-    return _quantized_forward(x, w, mode, backend), (x, w)
+def _qmatmul_fwd(x, w, mode, backend, tp):
+    return _quantized_forward(x, w, mode, backend, tp), (x, w)
 
 
-def _qmatmul_bwd(mode, backend, res, g):
+def _qmatmul_bwd(mode, backend, tp, res, g):
     # Straight-through: gradients as if the matmul were full-precision.
     x, w = res
     gf = g.astype(jnp.float32)
@@ -62,18 +63,22 @@ def _qmatmul_bwd(mode, backend, res, g):
 _qmatmul_ste.defvjp(_qmatmul_fwd, _qmatmul_bwd, symbolic_zeros=False)
 
 
-def linear(x, w, quant_mode: str = "bf16", backend: str | None = None):
+def linear(x, w, quant_mode: str = "bf16", backend: str | None = None,
+           tp: str | None = None):
     """The single matmul entry point for every model layer.
 
     ``backend`` is an optional GEMM-backend registry name (from
     ``ModelConfig.gemm_backend`` / ``--gemm-backend``); ``None`` defers to
-    the registry's platform auto-selection.
+    the registry's platform auto-selection.  ``tp`` ("col" | "row") is the
+    weight's tensor-parallel layout under the Megatron rules of
+    ``runtime/sharding.py``; a Pallas kernel under a mesh needs it to run
+    on its own shard (``shard/kernels.py``).
     """
     if quant_mode == "bf16":
         return jnp.einsum(
             "...k,kn->...n", x.astype(COMPUTE_DTYPE), w.astype(COMPUTE_DTYPE)
         )
-    return _qmatmul_ste(x.astype(COMPUTE_DTYPE), w, quant_mode, backend)
+    return _qmatmul_ste(x.astype(COMPUTE_DTYPE), w, quant_mode, backend, tp)
 
 
 def init_linear(key, d_in, d_out, scale=0.02):
@@ -123,9 +128,9 @@ def init_glu_mlp(key, d_model, d_ff):
 
 
 def glu_mlp(x, p, act="silu", quant_mode="bf16", backend=None):
-    g = _act(act)(linear(x, p["w_gate"], quant_mode, backend))
-    u = linear(x, p["w_up"], quant_mode, backend)
-    return linear(g * u, p["w_down"], quant_mode, backend)
+    g = _act(act)(linear(x, p["w_gate"], quant_mode, backend, tp="col"))
+    u = linear(x, p["w_up"], quant_mode, backend, tp="col")
+    return linear(g * u, p["w_down"], quant_mode, backend, tp="row")
 
 
 def init_mlp(key, d_model, d_ff):
@@ -135,8 +140,8 @@ def init_mlp(key, d_model, d_ff):
 
 def mlp(x, p, act="gelu", quant_mode="bf16", backend=None):
     return linear(
-        _act(act)(linear(x, p["w_in"], quant_mode, backend)),
-        p["w_out"], quant_mode, backend,
+        _act(act)(linear(x, p["w_in"], quant_mode, backend, tp="col")),
+        p["w_out"], quant_mode, backend, tp="row",
     )
 
 

@@ -69,12 +69,15 @@ def _watched(tag: str):
 # ---------------------------------------------------------------------------
 
 def _stack_period_params(keys_2d, cfg: ModelConfig):
-    """init each slot across periods and stack along a leading axis."""
-    pattern = cfg.block_pattern
+    """init each slot across periods, stacked along a leading axis.
+
+    One vmapped init per slot (the same values as stacking per-period
+    inits): a jitted init then compiles one layer's program, not one per
+    layer, which at 40 layers is most of its compile time."""
     slots = []
-    for s, kind in enumerate(pattern):
-        per_period = [tfm.init_block(keys_2d[i][s], kind, cfg) for i in range(len(keys_2d))]
-        slots.append(jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per_period))
+    for s, kind in enumerate(cfg.block_pattern):
+        keys = jnp.stack([row[s] for row in keys_2d])
+        slots.append(jax.vmap(lambda k, kind=kind: tfm.init_block(k, kind, cfg))(keys))
     return tuple(slots)
 
 

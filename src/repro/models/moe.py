@@ -179,30 +179,20 @@ def _constrain_ep(bufs):
     """Pin the dispatch buffer (B, E, C, d) to batch-over-data x
     expert-over-model sharding (EP+DP).  No-op outside a mesh / on
     non-divisible dims."""
-    try:
-        from jax.sharding import PartitionSpec as P
-        from jax._src import mesh as mesh_lib
+    from jax.sharding import PartitionSpec as P
 
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        if mesh.empty:
-            return bufs
-        dp = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
-        dp_size = 1
-        for a in dp:
-            dp_size *= mesh.shape[a]
-        model = mesh.shape.get("model", 1)
-        first = dp if (dp and bufs.shape[0] % dp_size == 0) else None
-        # EP when the expert dim divides the model axis; otherwise shard
-        # the (128-padded) capacity dim so the buffer still never
-        # replicates across "model".
-        second = third = None
-        if bufs.shape[1] % model == 0:
-            second = "model"
-        elif bufs.shape[2] % model == 0:
-            third = "model"
-        return jax.lax.with_sharding_constraint(bufs, P(first, second, third, None))
-    except Exception:  # pragma: no cover
+    from repro.runtime.sharding import ambient_mesh, dp_axis_for, model_axis_for
+
+    mesh = ambient_mesh()
+    if mesh is None:
         return bufs
+    # EP when the expert dim divides the model axis; otherwise shard the
+    # (128-padded) capacity dim so the buffer still never replicates
+    # across "model".
+    second = model_axis_for(mesh, bufs.shape[1])
+    third = None if second else model_axis_for(mesh, bufs.shape[2])
+    return jax.lax.with_sharding_constraint(
+        bufs, P(dp_axis_for(mesh, bufs.shape[0]), second, third, None))
 
 
 def moe_ffn_reference(x, p, cfg: ModelConfig):

@@ -52,8 +52,8 @@ def init_rglru(key, cfg: ModelConfig):
 
 
 def _rglru_coeffs(xb, p, quant_mode, backend=None):
-    r = jax.nn.sigmoid(linear(xb, p["w_rec_gate"], quant_mode, backend).astype(jnp.float32))
-    i = jax.nn.sigmoid(linear(xb, p["w_in_gate"], quant_mode, backend).astype(jnp.float32))
+    r = jax.nn.sigmoid(linear(xb, p["w_rec_gate"], quant_mode, backend, tp="col").astype(jnp.float32))
+    i = jax.nn.sigmoid(linear(xb, p["w_in_gate"], quant_mode, backend, tp="col").astype(jnp.float32))
     log_a = -_RGLRU_C * jax.nn.softplus(p["lam"]) * r          # (B, S, lru), <= 0
     a = jnp.exp(log_a)
     gated_x = i * xb.astype(jnp.float32)
@@ -80,12 +80,12 @@ def rglru_scan(xb, p, quant_mode, h0=None, backend=None):
 def rglru_block(x, p, cfg: ModelConfig, state=None):
     """Griffin recurrent block. state: None | {"h": (B,lru), "conv": (B,W-1,lru)}."""
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    gate = jax.nn.gelu(linear(x, p["w_gate_branch"], qm, be).astype(jnp.float32))
-    xb_raw = linear(x, p["w_x_branch"], qm, be)
+    gate = jax.nn.gelu(linear(x, p["w_gate_branch"], qm, be, tp="col").astype(jnp.float32))
+    xb_raw = linear(x, p["w_x_branch"], qm, be, tp="col")
     xb = causal_conv1d(xb_raw, p["conv_w"])
     h, h_last = rglru_scan(xb, p, qm, None, backend=be)
     y = (gate * h).astype(x.dtype)
-    out = linear(y, p["w_out"], qm, be)
+    out = linear(y, p["w_out"], qm, be, tp="row")
     new_state = None
     if state is not None:
         # decode continues from here: conv state holds the last W-1 *raw*
@@ -111,8 +111,8 @@ def rglru_chunk(x, p, cfg: ModelConfig, state, n_valid):
     qm, be = cfg.quant_mode, cfg.gemm_backend
     w = cfg.conv_width
     c = x.shape[1]
-    gate = jax.nn.gelu(linear(x, p["w_gate_branch"], qm, be).astype(jnp.float32))
-    xb_raw = linear(x, p["w_x_branch"], qm, be)
+    gate = jax.nn.gelu(linear(x, p["w_gate_branch"], qm, be, tp="col").astype(jnp.float32))
+    xb_raw = linear(x, p["w_x_branch"], qm, be, tp="col")
     # depthwise causal conv with carried context in place of zero padding
     full_raw = jnp.concatenate(
         [state["conv"].astype(jnp.float32), xb_raw.astype(jnp.float32)],
@@ -127,7 +127,7 @@ def rglru_chunk(x, p, cfg: ModelConfig, state, n_valid):
     bcoef = jnp.where(valid, bcoef, 0.0)
     h, h_last = rglru_scan_coeffs(a, bcoef, state["h"])
     y = (gate * h).astype(x.dtype)
-    out = linear(y, p["w_out"], qm, be)
+    out = linear(y, p["w_out"], qm, be, tp="row")
     # conv carry: raw rows at positions [n_valid - W + 1, n_valid) of the
     # ctx+chunk concat — the last W-1 rows ending at the chunk's last
     # valid token (n_valid >= 1 always; the engine never feeds empty chunks)
@@ -151,13 +151,13 @@ def rglru_scan_coeffs(a, b, h0):
 def rglru_decode(x_t, p, cfg: ModelConfig, state):
     """One step. x_t: (B, 1, d); state {"h": (B,lru), "conv": (B,W-1,lru)}."""
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    gate = jax.nn.gelu(linear(x_t, p["w_gate_branch"], qm, be).astype(jnp.float32))
-    xb = linear(x_t, p["w_x_branch"], qm, be)[:, 0, :]
+    gate = jax.nn.gelu(linear(x_t, p["w_gate_branch"], qm, be, tp="col").astype(jnp.float32))
+    xb = linear(x_t, p["w_x_branch"], qm, be, tp="col")[:, 0, :]
     xb_c, conv_state = conv1d_decode(xb, state["conv"], p["conv_w"])
     a, b = _rglru_coeffs(xb_c[:, None, :], p, qm, be)
     h = a[:, 0, :] * state["h"] + b[:, 0, :]
     y = (gate[:, 0, :] * h).astype(x_t.dtype)
-    out = linear(y[:, None, :], p["w_out"], qm, be)
+    out = linear(y[:, None, :], p["w_out"], qm, be, tp="row")
     return out, {"h": h, "conv": conv_state}
 
 
@@ -221,9 +221,9 @@ def mlstm_block(x, p, cfg: ModelConfig, state=None):
     def heads(t):
         return t.reshape(b, s, h_heads, dh).transpose(0, 2, 1, 3).astype(jnp.float32)
 
-    q = heads(linear(x, p["wq"], qm, be)) * (dh ** -0.5)
-    k = heads(linear(x, p["wk"], qm, be)) * (dh ** -0.5)
-    v = heads(linear(x, p["wv"], qm, be))
+    q = heads(linear(x, p["wq"], qm, be, tp="col")) * (dh ** -0.5)
+    k = heads(linear(x, p["wk"], qm, be, tp="col")) * (dh ** -0.5)
+    v = heads(linear(x, p["wv"], qm, be, tp="col"))
     logi = jax.nn.log_sigmoid(
         linear(x, p["w_igate"], qm, be).astype(jnp.float32)
     ).transpose(0, 2, 1)
@@ -254,8 +254,8 @@ def mlstm_block(x, p, cfg: ModelConfig, state=None):
     (C_f, n_f), hs = jax.lax.scan(body, (C0, n0), (qc, kc, vc, ffc, fic))
     h = hs.transpose(1, 2, 0, 3, 4).reshape(b, h_heads, s, dh)
     h = h.transpose(0, 2, 1, 3).reshape(b, s, d)
-    o = jax.nn.sigmoid(linear(x, p["w_ogate"], qm, be).astype(jnp.float32))
-    out = linear((o * h).astype(x.dtype), p["w_out"], qm, be)
+    o = jax.nn.sigmoid(linear(x, p["w_ogate"], qm, be, tp="col").astype(jnp.float32))
+    out = linear((o * h).astype(x.dtype), p["w_out"], qm, be, tp="row")
     new_state = None if state is None else {"C": C_f, "n": n_f}
     return out, new_state
 
@@ -277,9 +277,9 @@ def mlstm_chunk(x, p, cfg: ModelConfig, state, n_valid):
     def heads(t):
         return t.reshape(b, s, h_heads, dh).transpose(0, 2, 1, 3).astype(jnp.float32)
 
-    q = heads(linear(x, p["wq"], qm, be)) * (dh ** -0.5)
-    k = heads(linear(x, p["wk"], qm, be)) * (dh ** -0.5)
-    v = heads(linear(x, p["wv"], qm, be))
+    q = heads(linear(x, p["wq"], qm, be, tp="col")) * (dh ** -0.5)
+    k = heads(linear(x, p["wk"], qm, be, tp="col")) * (dh ** -0.5)
+    v = heads(linear(x, p["wv"], qm, be, tp="col"))
     logi = jax.nn.log_sigmoid(
         linear(x, p["w_igate"], qm, be).astype(jnp.float32)
     ).transpose(0, 2, 1)
@@ -311,8 +311,8 @@ def mlstm_chunk(x, p, cfg: ModelConfig, state, n_valid):
                                   (qc, kc, vc, ffc, fic))
     h = hs.transpose(1, 2, 0, 3, 4).reshape(b, h_heads, s, dh)
     h = h.transpose(0, 2, 1, 3).reshape(b, s, d)
-    o = jax.nn.sigmoid(linear(x, p["w_ogate"], qm, be).astype(jnp.float32))
-    out = linear((o * h).astype(x.dtype), p["w_out"], qm, be)
+    o = jax.nn.sigmoid(linear(x, p["w_ogate"], qm, be, tp="col").astype(jnp.float32))
+    out = linear((o * h).astype(x.dtype), p["w_out"], qm, be, tp="row")
     return out, {"C": C_f, "n": n_f}
 
 
@@ -326,9 +326,9 @@ def mlstm_decode(x_t, p, cfg: ModelConfig, state):
     def heads(t):
         return t.reshape(b, h_heads, dh).astype(jnp.float32)
 
-    q = heads(linear(x_t, p["wq"], qm, be)[:, 0]) * (dh ** -0.5)
-    k = heads(linear(x_t, p["wk"], qm, be)[:, 0]) * (dh ** -0.5)
-    v = heads(linear(x_t, p["wv"], qm, be)[:, 0])
+    q = heads(linear(x_t, p["wq"], qm, be, tp="col")[:, 0]) * (dh ** -0.5)
+    k = heads(linear(x_t, p["wk"], qm, be, tp="col")[:, 0]) * (dh ** -0.5)
+    v = heads(linear(x_t, p["wv"], qm, be, tp="col")[:, 0])
     i = jax.nn.sigmoid(linear(x_t, p["w_igate"], qm, be).astype(jnp.float32))[:, 0][..., None]
     f = jax.nn.sigmoid(linear(x_t, p["w_fgate"], qm, be).astype(jnp.float32))[:, 0][..., None]
     C = f[..., None] * state["C"] + (i * k)[..., :, None] * v[..., None, :]
@@ -336,8 +336,8 @@ def mlstm_decode(x_t, p, cfg: ModelConfig, state):
     num = jnp.einsum("bhd,bhde->bhe", q, C)
     den = jnp.maximum(jnp.abs(jnp.einsum("bhd,bhd->bh", q, n))[..., None], 1.0)
     h = (num / den).reshape(b, 1, d)
-    o = jax.nn.sigmoid(linear(x_t, p["w_ogate"], qm, be).astype(jnp.float32))
-    out = linear((o * h).astype(x_t.dtype), p["w_out"], qm, be)
+    o = jax.nn.sigmoid(linear(x_t, p["w_ogate"], qm, be, tp="col").astype(jnp.float32))
+    out = linear((o * h).astype(x_t.dtype), p["w_out"], qm, be, tp="row")
     return out, {"C": C, "n": n}
 
 
@@ -376,7 +376,7 @@ def slstm_block(x, p, cfg: ModelConfig, state=None):
     b, s, d = x.shape
     hh = cfg.n_heads
     dh = d // hh
-    zifo = linear(x, p["w_zifo"], qm, be).astype(jnp.float32).reshape(b, s, 4, hh, dh)
+    zifo = linear(x, p["w_zifo"], qm, be, tp="col").astype(jnp.float32).reshape(b, s, 4, hh, dh)
     if state is None:
         zeros = jnp.zeros((b, hh, dh), jnp.float32)
         carry = (zeros, zeros, zeros)
@@ -388,7 +388,7 @@ def slstm_block(x, p, cfg: ModelConfig, state=None):
 
     (c, n, h_last), hs = jax.lax.scan(step, carry, zifo.transpose(1, 0, 2, 3, 4))
     h = hs.transpose(1, 0, 2, 3).reshape(b, s, d).astype(x.dtype)
-    out = linear(h, p["w_out"], qm, be)
+    out = linear(h, p["w_out"], qm, be, tp="row")
     new_state = None if state is None else {"c": c, "n": n, "h": h_last}
     return out, new_state
 
@@ -402,7 +402,7 @@ def slstm_chunk(x, p, cfg: ModelConfig, state, n_valid):
     b, s, d = x.shape
     hh = cfg.n_heads
     dh = d // hh
-    zifo = linear(x, p["w_zifo"], qm, be).astype(jnp.float32).reshape(b, s, 4, hh, dh)
+    zifo = linear(x, p["w_zifo"], qm, be, tp="col").astype(jnp.float32).reshape(b, s, 4, hh, dh)
     carry0 = (state["c"], state["n"], state["h"])
 
     def step(carry, xs):
@@ -416,7 +416,7 @@ def slstm_chunk(x, p, cfg: ModelConfig, state, n_valid):
         step, carry0,
         (zifo.transpose(1, 0, 2, 3, 4), jnp.arange(s, dtype=jnp.int32)))
     h = hs.transpose(1, 0, 2, 3).reshape(b, s, d).astype(x.dtype)
-    out = linear(h, p["w_out"], qm, be)
+    out = linear(h, p["w_out"], qm, be, tp="row")
     return out, {"c": c, "n": n, "h": h_last}
 
 
@@ -425,8 +425,8 @@ def slstm_decode(x_t, p, cfg: ModelConfig, state):
     b, _, d = x_t.shape
     hh = cfg.n_heads
     dh = d // hh
-    zifo = linear(x_t, p["w_zifo"], qm, be).astype(jnp.float32).reshape(b, 4, hh, dh)
+    zifo = linear(x_t, p["w_zifo"], qm, be, tp="col").astype(jnp.float32).reshape(b, 4, hh, dh)
     carry = (state["c"], state["n"], state["h"])
     (c, n, h), h_out = _slstm_step(p, cfg, carry, zifo)
-    out = linear(h_out.reshape(b, 1, d).astype(x_t.dtype), p["w_out"], qm, be)
+    out = linear(h_out.reshape(b, 1, d).astype(x_t.dtype), p["w_out"], qm, be, tp="row")
     return out, {"c": c, "n": n, "h": h}

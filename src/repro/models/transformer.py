@@ -298,24 +298,6 @@ def layer_layout(cfg: ModelConfig, n_layers=None):
     return lead, n_periods, tail_kinds
 
 
-@jax.custom_vjp
-def _opt_barrier(h):
-    return jax.lax.optimization_barrier(h)
-
-
-def _opt_barrier_fwd(h):
-    return _opt_barrier(h), None
-
-
-def _opt_barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-# optimization_barrier has no differentiation rule on some jax versions
-# (0.4.x); a barrier is linear, so its VJP is a barrier on the cotangent.
-_opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
-
-
 def scan_periods(x, stacked_params, cfg: ModelConfig, positions, *, causal=True):
     """Run n_periods x pattern via lax.scan. stacked_params: tuple per slot."""
     from repro.runtime.sharding import constrain_activations
@@ -327,7 +309,7 @@ def scan_periods(x, stacked_params, cfg: ModelConfig, positions, *, causal=True)
         h = constrain_activations(h)  # SP: carry saved seq-sharded for bwd
         # barrier: stops XLA hoisting the rmsnorm f32 upcast across the
         # remat boundary (it would store the carry stack at 2x bytes)
-        h = _opt_barrier(h)
+        h = jax.lax.optimization_barrier(h)
         for s, kind in enumerate(pattern):
             h, a, _ = apply_block(h, slot_params[s], kind, cfg, positions, causal=causal)
             aux = aux + a

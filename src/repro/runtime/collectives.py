@@ -14,18 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# jax.shard_map graduated from jax.experimental on newer releases (and
-# renamed check_rep -> check_vma); export a version-stable alias for tests
-# and launch code.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma, **kw)
-
 INT8_MAX = 127.0
 
 

@@ -258,24 +258,41 @@ def pool_specs(cache_shapes_tree, mesh):
     return jax.tree_util.tree_map_with_path(rule, cache_shapes_tree)
 
 
-def _sp_constrain(x, seq_axis):
-    """Internal: pin (B, S, d) to batch-over-DP with the given seq sharding."""
-    try:
-        from jax._src import mesh as mesh_lib
+def ambient_mesh():
+    """The mesh of the enclosing ``with mesh:`` block, or None.
 
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m.empty or x.ndim != 3:
-            return x
-        dp = tuple(a for a in m.axis_names if a in ("pod", "data"))
-        dp_size = 1
-        for a in dp:
-            dp_size *= m.shape[a]
-        model_size = m.shape.get("model", 1)
-        first = dp if (dp and x.shape[0] % dp_size == 0) else None
-        second = seq_axis if (seq_axis is None or x.shape[1] % model_size == 0) else None
-        return jax.lax.with_sharding_constraint(x, P(first, second, None))
-    except Exception:  # pragma: no cover
+    The serving engine traces its jits inside ``with mesh:`` (see
+    ``serving/engine._with_mesh``); this is how trace-time code (the
+    constraints below, the kernel wrappers in ``shard/kernels.py``) finds
+    it without threading a mesh argument through every model function.
+    """
+    from jax._src import mesh as mesh_lib
+
+    m = mesh_lib.thread_resources.env.physical_mesh
+    return None if m.empty else m
+
+
+def dp_axis_for(m, dim: int):
+    """The mesh's DP axes if they divide ``dim``, else None (replicate)."""
+    dp = data_axes(m)
+    return dp if (dp and dim % _axis_size(m, dp) == 0) else None
+
+
+def model_axis_for(m, dim: int):
+    """"model" if the mesh has that axis and it divides ``dim``, else None."""
+    return ("model" if "model" in m.axis_names and dim % m.shape["model"] == 0
+            else None)
+
+
+def _sp_constrain(x, seq_axis):
+    """Internal: pin (B, S, d) to batch-over-DP with the given seq sharding.
+    Identity outside a mesh; a dim that does not divide is replicated."""
+    m = ambient_mesh()
+    if m is None or x.ndim != 3:
         return x
+    second = seq_axis and model_axis_for(m, x.shape[1])
+    return jax.lax.with_sharding_constraint(
+        x, P(dp_axis_for(m, x.shape[0]), second, None))
 
 
 def sp_enter(x):
@@ -302,25 +319,10 @@ def constrain_activations(x):
     carry (saved for backward) is what dominates HBM at 100B scale; without
     this it is replicated over the model axis (16x larger).
 
-    No-op outside a mesh context (CPU unit tests) or when dims don't
-    divide.  XLA SPMD re-gathers inside attention/MLP as needed.
+    No-op outside a mesh context (CPU unit tests); dims that don't divide
+    are replicated.  XLA SPMD re-gathers inside attention/MLP as needed.
     """
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m.empty or x.ndim != 3:
-            return x
-        dp = tuple(a for a in m.axis_names if a in ("pod", "data"))
-        dp_size = 1
-        for a in dp:
-            dp_size *= m.shape[a]
-        model_size = m.shape.get("model", 1)
-        first = dp if (dp and x.shape[0] % dp_size == 0) else None
-        second = "model" if x.shape[1] % model_size == 0 else None
-        return jax.lax.with_sharding_constraint(x, P(first, second, None))
-    except Exception:  # pragma: no cover — never fail a model for sharding
-        return x
+    return _sp_constrain(x, "model")
 
 
 def constrain_decode_carry(x):

@@ -29,6 +29,7 @@ not logits for equality).
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.shard.core import (
     build_mesh,
+    init_sharded_params,
     mesh_axis_size,
     pool_shardings,
     shard_params,
@@ -39,6 +40,7 @@ from repro.shard.memory import describe_mesh, tree_device_bytes
 __all__ = [
     "build_mesh",
     "describe_mesh",
+    "init_sharded_params",
     "make_host_mesh",
     "make_production_mesh",
     "mesh_axis_size",

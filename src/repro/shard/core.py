@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -53,6 +54,20 @@ def shard_params(params, mesh, cfg):
     """
     specs = param_specs(params, mesh, cfg, fsdp=False)
     return jax.device_put(params, named(specs, mesh))
+
+
+def init_sharded_params(cfg, key, mesh=None):
+    """Initialise parameters in one jitted program.  Under a mesh the
+    program's outputs carry the TP layout (``out_shardings``), so each
+    device builds only its own shards: a model that needs several chips is
+    never materialised whole on one of them."""
+    from repro.models import init_params  # lazy: models import the kernels
+
+    init = functools.partial(init_params, cfg)
+    if mesh is None:
+        return jax.jit(init)(key)
+    specs = param_specs(jax.eval_shape(init, key), mesh, cfg, fsdp=False)
+    return jax.jit(init, out_shardings=named(specs, mesh))(key)
 
 
 def pool_shardings(cache_shapes_tree, mesh):

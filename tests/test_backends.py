@@ -2,9 +2,10 @@
 
 Covers the tentpole refactor's guarantees without optional deps:
 
-* every registered backend == ``direct_matmul`` bit-exactly, over odd /
-  non-tile-multiple shapes (exercising the padded-slice path of the fused
-  ``spoga_gemm_dequant`` kernel through ``pallas_interpret`` on CPU);
+* every backend that runs on CPU == ``direct_matmul`` bit-exactly, over
+  odd / non-tile-multiple shapes (exercising the padded-slice path of the
+  fused ``spoga_gemm_dequant`` kernel through ``pallas_interpret``); the
+  compiled Pallas names raise off-TPU;
 * ``slice_planes`` round-trips for all (n_slices, slice_bits) combos
   including the extremes (-128, int16 min);
 * the ``w4a8`` / ``w4a4`` / ``w16a16`` parametric modes run end-to-end and
@@ -30,10 +31,15 @@ from repro.backends import (
 from repro.core.slicing import reconstruct_planes, slice_planes
 from repro.core.spoga import direct_matmul, sliced_matmul
 
-EXPECTED_BACKENDS = {
+# The compiled Pallas names run only on a TPU (their shapes are compile
+# cases in tests/test_tpu_compile.py); on CPU the kernel bodies run through
+# the named interpret twins.
+COMPILED_BACKENDS = {"pallas_spoga", "pallas_spoga_dequant", "pallas_deas"}
+CPU_BACKENDS = {
     "jnp_spoga", "jnp_deas", "direct",
-    "pallas_spoga", "pallas_spoga_dequant", "pallas_deas", "pallas_interpret",
+    "pallas_interpret", "pallas_deas_interpret",
 }
+EXPECTED_BACKENDS = COMPILED_BACKENDS | CPU_BACKENDS
 
 # Odd / non-tile-multiple shapes: every padding path in the kernels fires.
 SHAPES = [(8, 16, 8), (33, 70, 45), (1, 249, 16), (130, 257, 100)]
@@ -94,7 +100,7 @@ class TestRegistry:
 # Exactness: every backend vs the native direct GEMM
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("name", sorted(EXPECTED_BACKENDS))
+@pytest.mark.parametrize("name", sorted(CPU_BACKENDS))
 @pytest.mark.parametrize("m,k,n", SHAPES)
 def test_backend_exact_vs_direct(name, m, k, n):
     x, w = _rand_int8(m * k + n, (m, k)), _rand_int8(k * n + m, (k, n))
@@ -102,6 +108,18 @@ def test_backend_exact_vs_direct(name, m, k, n):
     got = b.gemm(x, w, spec)
     assert got.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got), np.asarray(direct_matmul(x, w)))
+
+
+@pytest.mark.parametrize("name", sorted(COMPILED_BACKENDS))
+def test_compiled_backend_raises_off_tpu(name):
+    """A compiled Pallas name never falls back to the interpreter: off-TPU
+    it raises, naming the interpret twin to use instead."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the compiled kernels run natively on TPU")
+    b, spec = resolve_backend("int8_spoga", name)
+    x, w = _rand_int8(0, (8, 16)), _rand_int8(1, (16, 8))
+    with pytest.raises(RuntimeError, match="_interpret"):
+        b.gemm(x, w, spec)
 
 
 @pytest.mark.parametrize("m,k,n", SHAPES)

@@ -8,7 +8,9 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.runtime.collectives import compressed_psum_mean, psum_mean, shard_map
+from jax import shard_map
+
+from repro.runtime.collectives import compressed_psum_mean, psum_mean
 
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 1, reason="needs at least one device")

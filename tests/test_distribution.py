@@ -22,6 +22,7 @@ _SCRIPT = textwrap.dedent("""
 
     from repro.configs import get_config, reduced
     from repro.configs.base import TrainConfig
+    from repro.launch.mesh import _make_mesh
     from repro.launch.steps import make_train_step
     from repro.models import init_params
     from repro.optim.optimizers import adamw_init
@@ -42,7 +43,7 @@ _SCRIPT = textwrap.dedent("""
     p1, o1, m1 = step_ref(params, opt, batch)
 
     # 2 x 4 (data x model) mesh, full sharding rules
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = _make_mesh((2, 4), ("data", "model"))
     p_specs = shard_lib.param_specs(jax.eval_shape(lambda: params), mesh, cfg,
                                     fsdp=True)
     o_specs = shard_lib.opt_state_specs(jax.eval_shape(lambda: opt), p_specs,
@@ -89,6 +90,7 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
     from repro.checkpoint.checkpoint import restore_checkpoint, save_checkpoint
     from repro.configs import get_config, reduced
     from repro.configs.base import TrainConfig
+    from repro.launch.mesh import _make_mesh
     from repro.launch.steps import make_train_step
     from repro.models import init_params
     from repro.optim.optimizers import adamw_init
@@ -102,7 +104,7 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
                                           cfg.vocab_size)}
 
     def step_on_mesh(mesh_shape, p_in, o_in):
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = _make_mesh(mesh_shape, ("data", "model"))
         p_specs = shard_lib.param_specs(jax.eval_shape(lambda: params), mesh,
                                         cfg, fsdp=True)
         with mesh:

@@ -74,7 +74,9 @@ def test_grad_compression_trains():
 
     from repro.models import lm_loss
     from repro.optim.optimizers import adamw_init, adamw_update
-    from repro.runtime.collectives import compressed_psum_mean, shard_map
+    from jax import shard_map
+
+    from repro.runtime.collectives import compressed_psum_mean
 
     cfg = reduced(get_config("llama3.2-1b")).with_(n_layers=2, remat=False)
     tcfg = TrainConfig(learning_rate=3e-3, warmup_steps=3, total_steps=25)
