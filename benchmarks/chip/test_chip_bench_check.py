@@ -1,0 +1,236 @@
+"""The comparison that decides ``correct``, at a size a test run holds.
+
+Tiny configurations of the benchmark's two families (the program's
+``int8_spoga`` GEMMs and int8 paged cache, jnp twins on the CPU; dense
+GQA, and GQA with a dropless top-k MoE) are driven through the harness
+with its look for a chip skipped.  The sound program passes; the control
+(the plain reference one precision step lower, int4 weights) reads above
+the limit; and each fault a serving cell can have, planted in the
+program's decode step or its MoE layer, makes ``correct`` false.
+"""
+
+import importlib.util
+import json
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from chipbench import check, spec
+
+CHIP = Path(__file__).resolve().parent
+SEED = 2 ** 33 + 5
+
+MODEL = {"hidden_size": 128, "intermediate_size": 256, "num_attention_heads": 4,
+         "num_key_value_heads": 2, "head_dim": 32, "num_hidden_layers": 2,
+         "vocab_size": 512, "rope_theta": 1e6, "rms_norm_eps": 1e-5,
+         "tie_word_embeddings": False, "hidden_act": "silu"}
+# readings at this size (widest logit gap over a sample of ~400 served
+# tokens, seeds SEED to SEED + 11): program 0.0053-0.0258, control
+# 0.0427-0.0952; the limit sits between, with more room above the program
+LIMIT = 0.035
+RUNTIME = {"quant": {"mode": "int8_spoga"},
+           "kv": {"mode": "paged", "dtype": "int8", "cache_len": 152},
+           "scheduler": {"n_slots": 4, "prefill_buckets": [16, 32, 64, 128]}}
+CONFIG = {"family": "gqa_dense", "model": MODEL, "check": {"max_logit_gap": LIMIT},
+          "program": {"arch": "mistral-nemo-12b", "runtime": RUNTIME}}
+MOE_MODEL = dict(MODEL, num_local_experts=4, num_experts_per_tok=2, intermediate_size=256)
+# readings of the MoE at this size (capacity factor E / k, dropless; seeds
+# SEED to SEED + 11, ~410 served tokens): program 0.0-0.0256, control
+# 0.0319-0.127; the limit sits between
+MOE_LIMIT = 0.029
+MOE_CONFIG = {"family": "gqa_moe", "model": MOE_MODEL, "check": {"max_logit_gap": MOE_LIMIT},
+              "program": {"arch": "granite-moe-3b-a800m", "moe_capacity_factor": 2.0,
+                          "runtime": RUNTIME}}
+MIX = {"kind": "open_loop", "rate_rps": 40.0, "stream": True,
+       "prompt": {"dist": "lognormal", "median": 40, "sigma": 0.9, "min": 8, "max": 120},
+       "output": {"dist": "lognormal", "median": 12, "sigma": 0.8, "min": 4, "max": 24},
+       "check_tokens": 400}
+WINDOW_S = 1.5
+
+
+@pytest.fixture(scope="module")
+def run_mod():
+    spec_ = importlib.util.spec_from_file_location("chipbench_run", CHIP / "run.py")
+    mod = importlib.util.module_from_spec(spec_)
+    spec_.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture
+def bench(monkeypatch):
+    peaks = spec.peaks_for("TPU v5 lite")
+    monkeypatch.setattr(spec, "peaks_for", lambda kind: peaks)
+    return json.loads((CHIP.parents[1] / "BENCHMARK.json").read_text())
+
+
+@pytest.fixture
+def cell(bench):
+    return spec.Cell("nemo12b-chat", "tiny", "tiny", 1, CONFIG, MIX, bench)
+
+
+@pytest.fixture
+def moe_cell(bench):
+    return spec.Cell("granite3b-chat", "tiny-moe", "tiny", 1, MOE_CONFIG, MIX, bench)
+
+
+@pytest.fixture
+def fresh_programs():
+    """The engine caches its jitted programs per configuration; a planted
+    fault needs fresh traces, and must not leak into other tests."""
+    from repro.serving import engine
+
+    def clear():
+        for obj in vars(engine).values():
+            if callable(getattr(obj, "cache_clear", None)):
+                obj.cache_clear()
+
+    clear()
+    yield engine
+    clear()
+
+
+def test_sound_program_is_correct_and_control_is_not(run_mod, cell):
+    res = run_mod.run_cell(cell, SEED, WINDOW_S, False, jax.devices())
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    assert list(res)[-1] == "checks"
+    assert set(res["metrics"]) == {"itl_p50_ms", "setup_s"}
+
+    # the control: the reference at int4 weights, on the tokens served
+    _, engine, (tracked, _, _, _) = run_mod.serve(cell, SEED, WINDOW_S)
+    pairs = run_mod.check_pairs(cell, engine, tracked, SEED)
+    g = check.gaps("gqa_dense", MODEL, SEED, pairs, quant_ctrl="w4a8")
+    assert g["served"].max() <= LIMIT < g["control"].max()
+    assert not check.verdict({"max_logit_gap": {"value": float(g["control"].max()),
+                                                "limit": LIMIT}})
+
+
+def test_sound_moe_is_correct_and_control_is_not(run_mod, moe_cell):
+    res = run_mod.run_cell(moe_cell, SEED, WINDOW_S, False, jax.devices())
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    _, engine, (tracked, _, _, _) = run_mod.serve(moe_cell, SEED, WINDOW_S)
+    pairs = run_mod.check_pairs(moe_cell, engine, tracked, SEED)
+    g = check.gaps("gqa_moe", MOE_MODEL, SEED, pairs, quant_ctrl="w4a8")
+    assert g["served"].max() <= MOE_LIMIT < g["control"].max()
+
+
+def test_backlog_cell_runs_through_chunked_prefill_and_is_correct(run_mod, bench):
+    """The offline-batch drive loop: a backlog fed so that a lane that frees
+    is refilled at once, prompts longer than a chunk."""
+    mix = {"kind": "backlog", "backlog": 60, "stream": False,
+           "prompt": {"dist": "loguniform", "min": 64, "max": 200},
+           "output": {"dist": "uniform", "min": 4, "max": 12},
+           "check_tokens": 100}
+    runtime = dict(RUNTIME, kv=dict(RUNTIME["kv"], cache_len=224),
+                   scheduler={"n_slots": 4, "prefill_chunk": 32})
+    config = dict(CONFIG, program=dict(CONFIG["program"], runtime=runtime))
+    backlog = spec.Cell("nemo12b-chat", "tiny", "tiny", 1, config, mix, bench)
+    res = run_mod.run_cell(backlog, SEED + 2, WINDOW_S, False, jax.devices())
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+
+
+def test_a_fill_serves_before_the_window_and_counts_as_set_up(run_mod, cell):
+    """A mix with a fill: the window opens on an engine already serving at
+    the mix's rate; what the fill served is not measured."""
+    mix = dict(MIX, fill_s=1.0)
+    filled = spec.Cell("nemo12b-chat", "tiny", "tiny", 1, CONFIG, mix, cell.bench)
+    _, _, (tracked, window, _, _) = run_mod.serve(filled, SEED + 3, WINDOW_S)
+    early = [t for t in tracked if t.due < window[0]]
+    assert len(early) == 40 and all(t.req.output_tokens for t in early)
+    assert sum(1 for t in tracked if window[0] <= t.due < window[1]) == 60
+    assert window[0] - early[0].due == pytest.approx(1.0)
+    assert window[0] - run_mod.T_START >= 1.0       # set-up holds the fill
+
+
+def _decode_fault(kind):
+    from repro.serving import engine as eng
+
+    sound = eng.make_serve_step
+
+    def planted(cfg, with_active=False):
+        decode = sound(cfg, with_active=with_active)
+
+        def step(params, tokens, cache, active=None):
+            if kind == "half_batch":
+                active = active & (jnp.arange(active.shape[0]) % 2 == 0)
+            logits, new_cache = decode(params, tokens, cache, active)
+            if kind == "token_altered":
+                logits = jnp.roll(logits, 1, axis=-1)
+            if kind == "state_unchanged":
+                new_cache = cache
+            return logits, new_cache
+
+        return step
+
+    return planted
+
+
+@pytest.mark.parametrize("fault", ["token_altered", "state_unchanged", "half_batch"])
+def test_planted_fault_is_not_correct(run_mod, cell, fresh_programs, monkeypatch, fault):
+    monkeypatch.setattr(fresh_programs, "make_serve_step", _decode_fault(fault))
+    res = run_mod.run_cell(cell, SEED + 1, WINDOW_S, False, jax.devices())
+    assert not res["correct"], res["checks"]
+    assert res["checks"]["max_logit_gap"]["value"] > LIMIT
+
+
+def _moe_fault(kind):
+    """The program's MoE layer with one expert's output dropped, or every
+    token sent to the wrong experts (the router's columns rolled by one)."""
+    from repro.models import moe
+
+    sound = moe.moe_ffn
+
+    def planted(x, p, cfg):
+        if kind == "expert_dropped":
+            p = dict(p, experts_down=p["experts_down"].at[0].set(0))
+        if kind == "expert_misrouted":
+            p = dict(p, router=jnp.roll(p["router"], 1, axis=-1))
+        return sound(x, p, cfg)
+
+    return planted
+
+
+@pytest.mark.parametrize("fault", ["expert_dropped", "expert_misrouted"])
+def test_planted_moe_fault_is_not_correct(run_mod, moe_cell, fresh_programs, monkeypatch,
+                                          fault):
+    from repro.models import moe
+
+    monkeypatch.setattr(moe, "moe_ffn", _moe_fault(fault))
+    res = run_mod.run_cell(moe_cell, SEED + 1, WINDOW_S, False, jax.devices())
+    assert not res["correct"], res["checks"]
+    assert res["checks"]["max_logit_gap"]["value"] > MOE_LIMIT
+
+
+@pytest.mark.parametrize("moe", [False, True], ids=["dense", "moe"])
+def test_reference_draws_the_weights_the_program_was_given(moe, bench):
+    """The reference never reads the program's arrays; it draws them
+    again from the seed, and gets the same values (in the published head
+    order, which the program's ``wq``/``wo`` permute)."""
+    from chipbench import weights as W
+    from repro.models.model import param_shapes
+
+    conf = dict(MOE_CONFIG if moe else CONFIG)
+    m = conf["model"] = dict(conf["model"], num_hidden_layers=3)
+    c = spec.Cell("x", "x", "x", 1, conf, MIX, bench)
+    params = c.layout.program_params(m, SEED, param_shapes(spec.model_config(c)))
+    root = W.root_key(W.seed_words(SEED))
+    hq, hkv, hd, d = 4, 2, 32, 128
+    for layer in range(3):
+        ref = W.layer_weights(root, c.reference.layer_specs(m), layer)
+        blk = jax.tree_util.tree_map(lambda x: x[layer], params["blocks"][0])
+        wq = blk["attn"]["wq"].reshape(d, hq // hkv, hkv, hd).transpose(0, 2, 1, 3)
+        assert jnp.array_equal(wq.reshape(d, hq * hd), ref["attn/wq"])
+        wo = blk["attn"]["wo"].reshape(hq // hkv, hkv, hd, d).transpose(1, 0, 2, 3)
+        assert jnp.array_equal(wo.reshape(hq * hd, d), ref["attn/wo"])
+        for name, leaf in ref.items():
+            if name not in ("attn/wq", "attn/wo"):
+                group, key = name.split("/") if "/" in name else (None, name)
+                got = blk[group][key] if group else blk[key]
+                assert jnp.array_equal(got, leaf), name
+    for name in ("embed", "head"):
+        table = jax.jit(lambda: W.table(root, m, name))()
+        assert jnp.array_equal(params[name], table)
