@@ -21,6 +21,7 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.backends import impls  # noqa: F401  (populates the registry)
@@ -101,8 +102,6 @@ def _stage_watchdog_stats(label: str, quant_mode: str, xf, wf, xq, xs, wq,
       statically (the fused gemm_dequant path never materializes the
       accumulator, so this bound is the only runtime view of it).
     """
-    import jax
-
     from repro.quant.qtensor import rail_hits
 
     xqf = xq.astype(jnp.float32)
@@ -148,9 +147,11 @@ def quantized_linear(
     b, spec = resolve_backend(quant_mode, backend)
     a_bits, w_bits = effective_bits(spec, x.shape[-1])
     xf = x.astype(jnp.float32)
-    wf = w.astype(jnp.float32)
     xq, xs = dynamic_quant(xf, axis=-1, bits=a_bits)
-    wq, ws = dynamic_quant(wf, axis=0, bits=w_bits)
+    # the weight's per-call re-quantization, named for the device trace
+    with jax.named_scope("weight_quant"):
+        wf = w.astype(jnp.float32)
+        wq, ws = dynamic_quant(wf, axis=0, bits=w_bits)
 
     ctx = _watchdog.trace_ctx()
     if watch if watch is not None else ctx is not None:

@@ -37,10 +37,10 @@ as the baseline ``benchmarks/serve_bench.py`` beats.
 Observability (``repro/obs/``): ``--trace out.json`` writes the engine's
 span timeline as Chrome trace-event JSON (load at https://ui.perfetto.dev);
 ``--events out.jsonl`` writes the scheduler decision log (one JSON object
-per admit/reject/chunk/CoW/defrag/finish event); ``--fence-spans`` makes
-spans block on device values so they measure device work, not dispatch;
-``--profile DIR`` wraps the first ``--profile-steps`` engine steps in a
-``jax.profiler`` device trace; ``--debug-invariants`` checks the page
+per admit/reject/chunk/CoW/defrag/finish event); ``--profile DIR`` wraps
+the first ``--profile-steps`` engine steps in a ``jax.profiler`` device
+trace, where the engine's phases show as ``engine.*`` host spans beside
+the device's ops; ``--debug-invariants`` checks the page
 pool's bookkeeping after every step.  ``--metrics-port P`` serves a live
 Prometheus ``/metrics`` endpoint (plus ``/healthz`` and a JSON
 ``/snapshot``) off the engine's registries; ``--watchdog`` arms the
@@ -208,7 +208,6 @@ def _obs_from_args(args) -> ObsConfig:
     return ObsConfig(
         trace=args.trace,
         events=args.events,
-        fence_spans=args.fence_spans,
         profile_dir=args.profile,
         profile_steps=args.profile_steps,
         debug_invariants=args.debug_invariants,
@@ -348,9 +347,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "trace-event JSON (load in Perfetto)")
     ap.add_argument("--events", default=None, metavar="OUT.jsonl",
                     help="obs: write the scheduler decision log as JSONL")
-    ap.add_argument("--fence-spans", action="store_true",
-                    help="obs: block spans on device values so they measure "
-                         "device work (serializes the decode pipeline)")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="obs: jax.profiler device trace over the first "
                          "--profile-steps engine steps, written under DIR")

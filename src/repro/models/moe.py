@@ -70,7 +70,9 @@ def _grouped_matmul(x, w, quant_mode, backend=None):
         family = get_backend(backend).family
     a_bits, w_bits = effective_bits(spec, x.shape[-1])
     xq, xs = dynamic_quant(x.astype(jnp.float32), axis=-1, bits=a_bits)
-    wq, ws = dynamic_quant(w.astype(jnp.float32), axis=1, bits=w_bits)
+    # the experts' per-call re-quantization, named for the device trace
+    with jax.named_scope("weight_quant"):
+        wq, ws = dynamic_quant(w.astype(jnp.float32), axis=1, bits=w_bits)
 
     e_axis = x.ndim - 3
 

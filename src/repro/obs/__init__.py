@@ -5,10 +5,11 @@ comparative photonic-accelerator claim rests on per-stage timing
 attribution — so the serving engine gets a first-class observability
 layer instead of a bag of mean-only counters:
 
-* ``trace``   — nested spans over engine dispatches (prefill / chunk /
-  decode / verify / defrag), optionally fenced with ``block_until_ready``
-  so they measure device work, exported as Chrome trace-event JSON
-  (Perfetto-loadable).
+* ``trace``   — nested spans over the engine's phases (step, schedule,
+  prefill / chunk / decode / verify / defrag, page tables, token pulls),
+  each also a ``jax.profiler`` annotation ``engine.<name>`` on the
+  device trace's clock, exported as Chrome trace-event JSON
+  (Perfetto-loadable) when recording is on.
 * ``metrics`` — counters, gauges and log-bucketed histograms with exact
   percentile queries; the registry ``serving.EngineMetrics`` is built on.
 * ``events``  — the structured scheduler event log: every admit / reject /

@@ -22,7 +22,7 @@ class ObsConfig:
     """Observability knobs (all off by default).
 
     ``enabled=None`` means *auto*: tracing/events turn on iff a sink path
-    (or ``debug_invariants``/``fence_spans``) asks for them, so setting
+    (or ``debug_invariants``) asks for them, so setting
     ``trace="out.json"`` is sufficient.  ``enabled=True`` collects in
     memory even without file sinks (read via ``llm.obs``);
     ``enabled=False`` forces everything off regardless of paths.
@@ -33,9 +33,6 @@ class ObsConfig:
     trace: Optional[str] = None
     # scheduler event-log JSONL output path (None = don't write a file)
     events: Optional[str] = None
-    # block_until_ready-fence spans so they bracket device work instead of
-    # async dispatch (serializes the decode pipeline — measurement mode)
-    fence_spans: bool = False
     # jax.profiler: wrap profile_steps engine steps in a device trace
     # written under this directory (None = no profiling)
     profile_dir: Optional[str] = None
@@ -72,8 +69,8 @@ class ObsConfig:
     def resolved_enabled(self) -> bool:
         if self.enabled is not None:
             return self.enabled
-        return bool(self.trace or self.events or self.fence_spans
-                    or self.debug_invariants or self.record_path)
+        return bool(self.trace or self.events or self.debug_invariants
+                    or self.record_path)
 
     def build(self) -> "Observability":
         """The live bundle this config describes (null sinks when off)."""
@@ -98,7 +95,7 @@ class ObsConfig:
         else:
             events = EventLog()
         return Observability(
-            tracer=Tracer(fence_spans=self.fence_spans) if on else NULL_TRACER,
+            tracer=Tracer() if on else NULL_TRACER,
             events=events,
             profiler=(StepProfiler(self.profile_dir, self.profile_steps)
                       if self.profile_dir else NULL_PROFILER),

@@ -9,18 +9,18 @@ timeline.
 
 Two properties the serving engine depends on:
 
-* **Async-dispatch honesty.**  JAX dispatches return before the device
-  finishes, so a bare span measures *enqueue* time, not device work.  A
-  span may register device values with ``sp.fence(x)``; when the tracer
-  was built with ``fence_spans=True`` the span blocks on them
-  (``jax.block_until_ready``) before stamping its end timestamp, so the
-  span brackets the device computation.  With ``fence_spans=False`` the
-  fence call is free and **no extra host sync ever happens** — the
-  engine's lazy decode pipelining is untouched.
-* **Zero overhead when disabled.**  ``NULL_TRACER`` (a ``NullTracer``)
-  hands out one shared no-op span: no event list grows, no timestamps are
-  taken, nothing is fenced.  Engine code traces unconditionally and the
-  null objects make the disabled path vanish.
+* **On the profiler's clock.**  Every span, recorded or not, is also a
+  ``jax.profiler.TraceAnnotation`` named ``engine.<name>``: inside a
+  ``jax.profiler`` trace the engine's phases land on the host plane, on
+  the same clock as the device's events, so a device gap can be read
+  against the host work around it.  JAX dispatches return before the
+  device finishes, so a dispatch span (``decode``, ``prefill``) measures
+  the *enqueue*; the device events themselves show when the work ran.
+* **Cheap when disabled.**  ``NULL_TRACER`` (a ``NullTracer``) hands out
+  a bare ``ProfilerSpan``: one TraceMe, which records nothing outside a
+  profiler session — no event list grows, no timestamp is taken, no
+  arguments are kept, nothing waits on the device.  Engine code traces
+  unconditionally.
 
 Spans nest by call structure: the tracer keeps a stack, stamps each span
 with its ``depth``, and Perfetto reconstructs the hierarchy from timestamp
@@ -42,16 +42,26 @@ import json
 import time
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 
 def _now_us() -> float:
     return time.perf_counter_ns() / 1000.0
 
 
+class ProfilerSpan(TraceAnnotation):
+    """A span on the profiler's clock only: ``NullTracer``'s span, and the
+    profiler half of a recorded ``Span``."""
+
+    def set(self, **kw) -> None:
+        pass
+
+
 class Span:
     """One in-flight span; use via ``with tracer.span(...) as sp``."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_fences", "_depth",
-                 "_lanes")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_depth", "_lanes",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict,
                  lanes: tuple = ()):
@@ -59,58 +69,29 @@ class Span:
         self.name = name
         self.args = args
         self._t0 = 0.0
-        self._fences: list = []
         self._depth = 0
         self._lanes = lanes
-
-    def fence(self, *values) -> None:
-        """Register device values the span must wait on before closing
-        (only honoured when the tracer fences; otherwise free)."""
-        if self._tracer.fence_spans:
-            self._fences.extend(values)
+        self._annotation = ProfilerSpan(f"engine.{name}")
 
     def set(self, **kw) -> None:
         """Attach (or update) span args after entry."""
         self.args.update(kw)
 
     def __enter__(self) -> "Span":
+        self._annotation.__enter__()
         self._depth = len(self._tracer._stack)
         self._tracer._stack.append(self)
         self._t0 = _now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if self._fences:
-            import jax
-
-            jax.block_until_ready(self._fences)
         t1 = _now_us()
         stack = self._tracer._stack
         if stack and stack[-1] is self:
             stack.pop()
         self._tracer._emit(self, t1)
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
-
-
-class _NullSpan:
-    """Shared do-nothing span: the disabled tracer's entire hot path."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> bool:
-        return False
-
-    def fence(self, *values) -> None:
-        pass
-
-    def set(self, **kw) -> None:
-        pass
-
-
-_NULL_SPAN = _NullSpan()
 
 
 class Tracer:
@@ -118,8 +99,7 @@ class Tracer:
 
     enabled = True
 
-    def __init__(self, fence_spans: bool = False):
-        self.fence_spans = fence_spans
+    def __init__(self):
         # finished events, already in Chrome trace-event dict form
         self.events: list[dict] = []
         self._stack: list[Span] = []
@@ -129,7 +109,8 @@ class Tracer:
     # -- recording ---------------------------------------------------------
     def span(self, name: str, lanes=None, lane=None, **args) -> Span:
         """Open a span.  ``lanes``/``lane`` name the engine slots the
-        dispatch covers; the span is mirrored onto each lane's track."""
+        dispatch covers (any iterable of slot ids, read here); the span is
+        mirrored onto each lane's track."""
         if lane is not None:
             lanes = (lane,)
         return Span(self, name, args,
@@ -188,14 +169,13 @@ class Tracer:
 
 
 class NullTracer:
-    """Disabled tracer: every span is the shared no-op span."""
+    """Disabled tracer: every span is a bare ``ProfilerSpan``."""
 
     enabled = False
-    fence_spans = False
     events: tuple = ()
 
-    def span(self, name: str, **args) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, **args) -> ProfilerSpan:
+        return ProfilerSpan(f"engine.{name}")
 
     def instant(self, name: str, **args) -> None:
         pass

@@ -600,7 +600,7 @@ class ServingEngine:
                                  mode="cold", queue_wait_s=req.queue_wait_s)
         t0 = time.perf_counter()
         with self.obs.tracer.span("prefill", lane=slot, req=req.req_id,
-                                  slot=slot, tokens=padded_len) as sp:
+                                  slot=slot, tokens=padded_len):
             if self.paged:
                 tok_dev, self.store.cache = self._paged_admit(
                     req, slot, tokens, padded_len, common, reserved=reserved)
@@ -612,11 +612,12 @@ class ServingEngine:
                     np.asarray([req.prompt_len], np.int32), jnp.int32(slot),
                     *common, self.store._axes_flat,
                 )
-            sp.fence(tok_dev)
         req.cost.prefill_s += time.perf_counter() - t0
         req.cost.dispatches += 1
         self.metrics.inc("prefill_dispatches")
-        self._arm_lane(req, slot, int(np.asarray(tok_dev)[0]))
+        with self.obs.tracer.span("first_token_pull"):
+            tok = int(np.asarray(tok_dev)[0])
+        self._arm_lane(req, slot, tok)
 
     def _admit_group(self, group: list[tuple[Request, int]]) -> None:
         """Stacked admission: same-bucket requests prefill as one batch=k
@@ -644,16 +645,16 @@ class ServingEngine:
         admit_fn = _jitted_admit_group(self.cfg, self.engine_cfg.cache_len, k,
                                        self.mesh)
         t0 = time.perf_counter()
-        with self.obs.tracer.span("prefill_stacked", lanes=slots.tolist(),
-                                  k=k, tokens=padded_len) as sp:
+        with self.obs.tracer.span("prefill_stacked", lanes=slots,
+                                  k=k, tokens=padded_len):
             toks_dev, self.store.cache = admit_fn(
                 self.store.cache, self.params, tokens, lengths, slots,
                 temps, topk, greedy, keys, self.store._axes_flat)
-            sp.fence(toks_dev)
         share = (time.perf_counter() - t0) / k
         self.metrics.inc("prefill_dispatches")
         self.metrics.inc("stacked_prefills", k)
-        toks = np.asarray(toks_dev)
+        with self.obs.tracer.span("first_token_pull"):
+            toks = np.asarray(toks_dev)
         for i, (req, slot) in enumerate(group):
             req.cost.prefill_s += share
             req.cost.dispatches += 1
@@ -701,16 +702,16 @@ class ServingEngine:
         admit_fn = _jitted_admit_paged_group(self.cfg, single_len, k,
                                              self.mesh)
         t0 = time.perf_counter()
-        with self.obs.tracer.span("prefill_stacked", lanes=lanes.tolist(),
-                                  k=k, tokens=padded_len) as sp:
+        with self.obs.tracer.span("prefill_stacked", lanes=lanes,
+                                  k=k, tokens=padded_len):
             toks_dev, self.store.cache = admit_fn(
                 self.store.cache, self.params, tokens, lengths, lanes,
                 page_ids, table_rows, temps, topk, greedy, keys)
-            sp.fence(toks_dev)
         share = (time.perf_counter() - t0) / k
         self.metrics.inc("prefill_dispatches")
         self.metrics.inc("stacked_prefills", k)
-        toks = np.asarray(toks_dev)
+        with self.obs.tracer.span("first_token_pull"):
+            toks = np.asarray(toks_dev)
         for i, (req, slot) in enumerate(group):
             req.cost.prefill_s += share
             req.cost.dispatches += 1
@@ -994,11 +995,10 @@ class ServingEngine:
         tokens[0, :n] = req.prompt[start:start + n]
         t0 = time.perf_counter()
         with self.obs.tracer.span("chunk", lane=slot, req=req.req_id,
-                                  slot=slot, start=start, n=n) as sp:
+                                  slot=slot, start=start, n=n):
             logits, self.store.cache = self._chunk_fn(
                 self.params, self.store.cache, tokens, jnp.int32(slot),
                 np.asarray([start], np.int32), np.asarray([n], np.int32))
-            sp.fence(logits)
         req.cost.prefill_s += time.perf_counter() - t0
         req.cost.dispatches += 1
         req.prefill_done = start + n
@@ -1015,7 +1015,9 @@ class ServingEngine:
             mgr.set_length(slot, req.prompt_len)
             self.scheduler.promote(slot)
             self._maybe_publish(req, slot)
-            self._arm_lane(req, slot, int(np.asarray(tok_dev)[0]))
+            with self.obs.tracer.span("first_token_pull"):
+                tok = int(np.asarray(tok_dev)[0])
+            self._arm_lane(req, slot, tok)
             if self._should_evict(req):  # max_new_tokens == 1 (or instant EOS)
                 self._evict(slot, finished)
 
@@ -1047,7 +1049,9 @@ class ServingEngine:
         self._step_idx += 1
         self.metrics.inc("steps")
         finished: list[Request] = []
-        self._shed_late(finished)
+        tracer = self.obs.tracer
+        with tracer.span("schedule"):
+            self._shed_late(finished)
         budget = self.engine_cfg.max_prefills_per_step
 
         t0 = time.perf_counter()
@@ -1068,10 +1072,11 @@ class ServingEngine:
         # scatter per-lane pages); chunked and prefix-seeded admissions
         # stay single-file via sentinel buckets.
         while budget > 0:
-            group = self.scheduler.schedule_group(
-                admit_ok=self._admit_gate(),
-                bucket_of=self._admit_bucket,
-                max_group=self.scheduler.free_slots)
+            with tracer.span("schedule"):
+                group = self.scheduler.schedule_group(
+                    admit_ok=self._admit_gate(),
+                    bucket_of=self._admit_bucket,
+                    max_group=self.scheduler.free_slots)
             if not group:
                 break
             budget -= 1
@@ -1096,7 +1101,8 @@ class ServingEngine:
                 if self._should_evict(req):  # max_new_tokens == 1 / instant EOS
                     self._evict(slot, finished)
         if did_prefill:
-            jax.block_until_ready(self.store.cache["pos"])
+            with tracer.span("admit_sync"):
+                jax.block_until_ready(self.store.cache["pos"])
             self.metrics.inc("prefill_s", time.perf_counter() - t0)
 
         occupancy = len(self.scheduler.running) + len(self.scheduler.chunking)
@@ -1123,29 +1129,29 @@ class ServingEngine:
             running = self.scheduler.running
             if self.paged and self._has_paged_kinds:
                 mgr = self.store.manager
-                for slot in running:
-                    row = int(mgr.lengths[slot])
-                    if self.prefix is not None:
-                        # a lane's first write into a shared page forks it
-                        # (structurally the admission fork already covers
-                        # this; the guard keeps the invariant unconditional)
-                        move = mgr.ensure_writable(slot, row)
-                        if move is not None:
-                            self._cow(slot, move)
-                    mgr.ensure(slot, row + 1)
-                    # KV footprint integral: pages held x decode steps
-                    running[slot].cost.page_steps += len(mgr.lane_pages[slot])
-                self.store.sync_tables()
+                with tracer.span("page_tables"):
+                    for slot in running:
+                        row = int(mgr.lengths[slot])
+                        if self.prefix is not None:
+                            # a lane's first write into a shared page forks
+                            # it (structurally the admission fork already
+                            # covers this; the guard keeps the invariant
+                            # unconditional)
+                            move = mgr.ensure_writable(slot, row)
+                            if move is not None:
+                                self._cow(slot, move)
+                        mgr.ensure(slot, row + 1)
+                        # KV footprint integral: pages held x decode steps
+                        running[slot].cost.page_steps += len(mgr.lane_pages[slot])
+                    self.store.sync_tables()
                 self.metrics.max_gauge("peak_pages_used", mgr.pages_in_use)
             active = np.zeros((self.engine_cfg.n_slots,), bool)
             active[list(running)] = True
-            with self.obs.tracer.span("decode", lanes=list(running),
-                                      batch=len(running)) as sp:
+            with tracer.span("decode", lanes=running, batch=len(running)):
                 toks, self.store.cache = self._decode_sample(
                     self.params, self._tokens, self.store.cache, active,
                     self._temps, self._topk, self._greedy, self._keys,
                     not bool(self._greedy.all()))
-                sp.fence(toks)
             if self.paged:
                 self.store.manager.advance(running)
             # feed the sampled tokens into the next decode device-to-device;
@@ -1169,7 +1175,7 @@ class ServingEngine:
         # admissions (ROADMAP PR 3 follow-up: defrag existed, untriggered)
         if (self.paged and self._has_paged_kinds
                 and self.policies.defrag.should_defrag(self.store.manager)):
-            with self.obs.tracer.span("defrag") as sp:
+            with tracer.span("defrag") as sp:
                 moves = self.store.defrag()
                 sp.set(pages_moved=len(moves))
             if moves:
@@ -1235,23 +1241,23 @@ class ServingEngine:
         mgr = self.store.manager if self.paged else None
         base_row = {}
         if self.paged and self._has_paged_kinds:
-            for slot in slots:
-                row = int(mgr.lengths[slot])
-                base_row[slot] = row
-                if self.prefix is not None:
-                    # the whole verify window must be privately writable
-                    for move in mgr.ensure_writable_range(slot, row, w):
-                        self._cow(slot, move)
-                mgr.ensure(slot, row + w)
-                running[slot].cost.page_steps += len(mgr.lane_pages[slot])
-            self.store.sync_tables()
+            with self.obs.tracer.span("page_tables"):
+                for slot in slots:
+                    row = int(mgr.lengths[slot])
+                    base_row[slot] = row
+                    if self.prefix is not None:
+                        # the whole verify window must be privately writable
+                        for move in mgr.ensure_writable_range(slot, row, w):
+                            self._cow(slot, move)
+                    mgr.ensure(slot, row + w)
+                    running[slot].cost.page_steps += len(mgr.lane_pages[slot])
+                self.store.sync_tables()
             self.metrics.max_gauge("peak_pages_used", mgr.pages_in_use)
 
         with self.obs.tracer.span("verify", batch=len(slots), width=w,
-                                  lanes=slots) as sp:
+                                  lanes=slots):
             self.store.cache, targets, accepted = self._verify_fn(
                 self.params, self.store.cache, toks, n_draft, active)
-            sp.fence(targets, accepted)
         self.metrics.inc("verify_dispatches")
         self.metrics.inc("decode_steps")
         targets = np.asarray(targets)
@@ -1335,16 +1341,21 @@ class ServingEngine:
         return False
 
     def _flush(self, finished: list[Request]) -> None:
-        """Materialize pending decode tokens, then evict completed lanes."""
-        for toks_dev, mapping in self._pending:
-            toks = np.asarray(toks_dev)
-            for slot, req in mapping.items():
-                req.append_token(int(toks[slot]))
+        """Materialize pending decode tokens, then deliver them (streaming
+        callbacks), advance PRNG keys and evict completed lanes."""
+        tracer = self.obs.tracer
+        with tracer.span("token_pull"):
+            pulled = [(np.asarray(toks_dev), mapping)
+                      for toks_dev, mapping in self._pending]
         self._pending.clear()
-        for slot, req in list(self.scheduler.running.items()):
-            self._keys[slot] = self._lane_key(req)
-            if self._should_evict(req):
-                self._evict(slot, finished)
+        with tracer.span("token_deliver"):
+            for toks, mapping in pulled:
+                for slot, req in mapping.items():
+                    req.append_token(int(toks[slot]))
+            for slot, req in list(self.scheduler.running.items()):
+                self._keys[slot] = self._lane_key(req)
+                if self._should_evict(req):
+                    self._evict(slot, finished)
 
     def _evict(self, slot: int, finished: list[Request]) -> None:
         req = self.scheduler.release(slot)

@@ -40,10 +40,10 @@ class RequestCost:
 
     Device-time shares are host-measured around each dispatch and split
     evenly across the requests riding it (batched decode/verify), so the
-    per-phase totals sum to engine dispatch time.  Without
-    ``fence_spans`` async dispatch means these measure *enqueue* +
-    any sync the step forced; with ``ObsConfig(fence_spans=True)`` they
-    bracket device work.  ``page_steps`` integrates pages held per decode
+    per-phase totals sum to engine dispatch time.  Dispatch is async, so
+    these measure *enqueue* + any sync the step forced; a ``jax.profiler``
+    trace shows the device work against the engine's ``engine.*`` spans.
+    ``page_steps`` integrates pages held per decode
     step (paged engines) — the request's KV-memory x time footprint.
     """
 

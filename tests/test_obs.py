@@ -31,6 +31,8 @@ from repro.obs import (
     StepProfiler,
     Tracer,
 )
+from repro.api.config import QuantRuntime
+from repro.obs.trace import ProfilerSpan
 from repro.paging.manager import PageManager
 from repro.serving.metrics import EngineMetrics
 
@@ -87,26 +89,14 @@ def test_tracer_chrome_document_shape(tmp_path):
         json.dumps(evs))
 
 
-def test_span_fence_is_free_unless_enabled():
-    x = jnp.ones((4,))
-    tr = Tracer(fence_spans=False)
-    with tr.span("decode") as sp:
-        sp.fence(x)
-        assert sp._fences == []  # not even retained -> no sync at exit
-    tr_f = Tracer(fence_spans=True)
-    with tr_f.span("decode") as sp:
-        sp.fence(x)
-        assert sp._fences == [x]
-    assert tr_f.events[-1]["dur"] >= 0
-
-
 def test_null_tracer_is_inert():
     sp1 = NULL_TRACER.span("a", x=1)
     sp2 = NULL_TRACER.span("b")
-    assert sp1 is sp2  # one shared no-op span, nothing allocated
+    # a bare profiler annotation: nothing kept but the name
+    assert isinstance(sp1, ProfilerSpan) and not hasattr(sp1, "args")
     with sp1 as sp:
-        sp.fence(jnp.ones(()))
-        sp.set(y=2)
+        with sp2:
+            sp.set(y=2)
     NULL_TRACER.instant("never")
     assert NULL_TRACER.events == ()
     assert NULL_TRACER.to_chrome()["traceEvents"] == []
@@ -216,7 +206,6 @@ def test_obs_config_auto_enable_and_build():
     assert not ObsConfig().resolved_enabled
     assert ObsConfig(trace="t.json").resolved_enabled
     assert ObsConfig(events="e.jsonl").resolved_enabled
-    assert ObsConfig(fence_spans=True).resolved_enabled
     assert ObsConfig(debug_invariants=True).resolved_enabled
     assert ObsConfig(enabled=True).resolved_enabled
     # explicit False wins over sink paths
@@ -233,7 +222,7 @@ def test_obs_config_auto_enable_and_build():
 
 def test_runtime_config_obs_roundtrip():
     rc = RuntimeConfig(obs=ObsConfig(trace="t.json", events="e.jsonl",
-                                     fence_spans=True, profile_steps=5,
+                                     profile_steps=5,
                                      debug_invariants=True))
     blob = json.dumps(rc.to_dict())
     assert RuntimeConfig.from_dict(json.loads(blob)) == rc
@@ -253,6 +242,96 @@ def test_step_profiler_wraps_n_steps(tmp_path):
     prof.close()
     prof.close()  # idempotent
     assert any(tmp_path.rglob("*")), "profiler wrote nothing"
+
+
+def _profiled_engine_spans(obs_cfg, log_dir):
+    """A tiny streaming paged engine served for a few steps inside a
+    ``jax.profiler`` trace: the ``engine.*`` host events, ``(name, start,
+    end)``, and the LLM."""
+    from jax.profiler import ProfileData
+
+    runtime = RuntimeConfig(
+        reduced=True, quant=QuantRuntime(mode="int8_spoga"),
+        kv=KVConfig(mode="paged", page_size=8),
+        scheduler=SchedulerConfig(n_slots=2), obs=obs_cfg)
+    llm = LLM(arch="llama3.2-1b", runtime=runtime)
+    engine = llm.build_engine(12, 6)
+    rng = np.random.default_rng(0)
+
+    def add():
+        engine.add_request(rng.integers(1, 100, 12).tolist(), 6,
+                           on_token=lambda tok: None)
+
+    add()
+    while engine.has_work:  # compile outside the trace
+        engine.step()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        add()
+        add()
+        for _ in range(3):
+            engine.step()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = log_dir.rglob("*.xplane.pb")
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("engine.")]
+    return spans, llm
+
+
+@pytest.mark.parametrize("obs_cfg", [ObsConfig(), ObsConfig(enabled=True)],
+                         ids=["obs_off", "obs_on"])
+def test_engine_spans_land_in_a_profiler_trace(obs_cfg, tmp_path):
+    spans, llm = _profiled_engine_spans(obs_cfg, tmp_path)
+    steps = [(a, b) for name, a, b in spans if name == "engine.step"]
+    assert len(steps) == 3
+    phases = ("engine.schedule", "engine.page_tables", "engine.decode",
+              "engine.token_pull", "engine.token_deliver")
+    for a, b in steps:
+        inside = {name for name, x, y in spans if a <= x and y <= b}
+        assert set(phases) <= inside, sorted(inside)
+    # the admission step: its prefill, first-token pull and sync
+    names = {name for name, _, _ in spans}
+    assert {"engine.prefill", "engine.first_token_pull",
+            "engine.admit_sync"} <= names
+    # recording, where on, keeps its own names beside the profiler's
+    recorded = {e["name"] for e in llm.obs.tracer.events}
+    if obs_cfg.resolved_enabled:
+        assert {n.removeprefix("engine.") for n in names} <= recorded
+    else:
+        assert recorded == set()
+
+
+def _lower_quantized_linear():
+    from repro.backends.pipeline import quantized_linear
+
+    x = jnp.ones((4, 64), jnp.bfloat16)
+    w = jnp.ones((64, 128), jnp.bfloat16)
+    return jax.jit(lambda x, w: quantized_linear(x, w, "int8_spoga")).lower(x, w)
+
+
+def _lower_grouped_matmul():
+    from repro.models.moe import _grouped_matmul
+
+    x = jnp.ones((2, 4, 8, 64), jnp.bfloat16)
+    w = jnp.ones((4, 64, 32), jnp.bfloat16)
+    return jax.jit(lambda x, w: _grouped_matmul(x, w, "int8_spoga")).lower(x, w)
+
+
+@pytest.mark.parametrize("lower", [_lower_quantized_linear, _lower_grouped_matmul],
+                         ids=["quantized_linear", "grouped_matmul"])
+def test_weight_requantization_is_named_in_the_hlo(lower):
+    text = lower().as_text(debug_info=True)
+    quant = [line for line in text.splitlines() if "weight_quant" in line]
+    # the amax reduction and the rounding to int8 codes both carry it
+    assert any("reduce" in line and "max" in line for line in quant), quant
+    assert any("round" in line for line in quant), quant
 
 
 # ---------------------------------------------------------------------------
@@ -341,10 +420,9 @@ def test_disabled_obs_is_noop_and_enabled_is_output_invisible():
     assert all(o.timeline is None and o.queue_wait_s is None
                for o in outs_off)
 
-    # enabled, with the most invasive settings (fenced spans + per-step
-    # invariant checking): greedy token streams must stay bitwise equal
-    llm_on, outs_on = _serve(ObsConfig(fence_spans=True,
-                                       debug_invariants=True))
+    # enabled, with the most invasive setting (per-step invariant
+    # checking): greedy token streams must stay bitwise equal
+    llm_on, outs_on = _serve(ObsConfig(debug_invariants=True))
     assert [o.token_ids for o in outs_on] == [o.token_ids for o in outs_off]
 
     # spans were recorded for the dispatch kinds this workload exercises
