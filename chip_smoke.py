@@ -7,7 +7,9 @@ One chip runs two phases in this one process:
 
 1. kernels — ``spoga_gemm_dequant`` (W8A8 and w4a8) at llama3.2-1b's decode,
    prefill and LM-head shapes, and ``paged_attention`` (bf16 and int8 pages)
-   at its GQA geometry, each compiled and compared with its jnp reference;
+   at its GQA geometry and, int8, at the served geometries of
+   mistral-nemo-12b and granite-3.0-3b-a800m with ragged lengths, each
+   compiled and compared with its jnp reference;
 2. engine — ``LLM(arch="llama3.2-1b")`` at full width with random weights,
    once with the serve CLI's defaults (bf16, slot cache) and once on the
    paper's path (int8 SPOGA GEMMs, int8 paged KV), serving synthetic
@@ -113,7 +115,7 @@ def kernel_phase(seed: int) -> None:
 
     from repro.configs import get_config
     from repro.core.spoga import direct_matmul
-    from repro.kernels.paged_attention import paged_attention, paged_attention_ref
+    from repro.kernels.paged_attention import pages_per_block
     from repro.kernels.spoga_gemm_dequant import spoga_gemm_dequant
 
     cfg = get_config(LLAMA)
@@ -141,36 +143,57 @@ def kernel_phase(seed: int) -> None:
 
     hkv, g, hd, ps = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.resolved_head_dim, 16
     n_tbl = 36                                  # 576 rows per lane
-    n_pages = lanes * n_tbl + 1                 # page 0 is the trash page
+    lengths = np.concatenate([[1, n_tbl * ps], rng.integers(1, n_tbl * ps, lanes - 2)])
+    for kind in ("bf16", "int8"):
+        check_paged_attention(rng, kind, lanes, (hkv, g, hd), n_tbl, lengths)
+    # the served geometry: 32 lanes of 257-page tables, lengths on and
+    # either side of a block boundary, inactive and full lanes
+    lanes, n_tbl = 32, 257
+    for geometry in ((8, 4, 128), (8, 3, 64)):      # mistral-nemo, granite-3.0
+        rows = ps * pages_per_block(ps, geometry[0], geometry[2], jnp.int8, n_tbl)
+        edges = [1, rows - 1, rows, rows + 1, n_tbl * ps]
+        lengths = np.concatenate([edges, rng.choice(
+            [1, 1, 600, 1065, 2100], lanes - len(edges))])
+        check_paged_attention(rng, "int8", lanes, geometry, n_tbl, lengths)
+
+
+def check_paged_attention(rng, kind, lanes, geometry, n_tbl, lengths) -> None:
+    """``paged_attention`` on random pages against its reference: tables
+    of distinct random pages (page 0 is the trash page), ``lengths`` rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.paged_attention import paged_attention, paged_attention_ref
+
+    (hkv, g, hd), ps = geometry, 16
+    n_pages = lanes * n_tbl + 1
     tables = jnp.asarray(rng.permutation(np.arange(1, n_pages))
                          .reshape(lanes, n_tbl), jnp.int32)
-    lengths = jnp.asarray(np.concatenate(
-        [[1, n_tbl * ps], rng.integers(1, n_tbl * ps, lanes - 2)]), jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
     q = jnp.asarray(rng.normal(size=(lanes, hkv, g, hd)), jnp.bfloat16)
     pool = (n_pages, ps, hkv, hd)
-    for kind in ("bf16", "int8"):
-        if kind == "int8":
-            kp = jnp.asarray(rng.integers(-127, 128, pool), jnp.int8)
-            vp = jnp.asarray(rng.integers(-127, 128, pool), jnp.int8)
-            scales = dict(
-                k_scale=jnp.asarray(rng.uniform(5e-3, 2e-2, pool[:3]), jnp.float32),
-                v_scale=jnp.asarray(rng.uniform(5e-3, 2e-2, pool[:3]), jnp.float32))
-        else:
-            kp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
-            vp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
-            scales = {}
-        with compile_clock() as c:
-            got = paged_attention(q, kp, vp, tables, lengths, **scales)
-            got.block_until_ready()
-        with jax.default_matmul_precision("highest"):
-            want = paged_attention_ref(q, kp, vp, tables, lengths, **scales)
-        got, want = np.asarray(got), np.asarray(want)
-        assert np.isfinite(got).all(), f"paged_attention {kind}: non-finite"
-        err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
-        assert err <= ATTN_TOL, f"paged_attention {kind}: rel err {err} > {ATTN_TOL}"
-        print(f"[kernels] paged_attention {kind} H_kv={hkv} G={g} D={hd} "
-              f"page={ps}: max err {err:.3e} of scale (tol {ATTN_TOL}) "
-              f"(compile {c.compile_s:.2f}s)")
+    if kind == "int8":
+        kp = jnp.asarray(rng.integers(-127, 128, pool), jnp.int8)
+        vp = jnp.asarray(rng.integers(-127, 128, pool), jnp.int8)
+        scales = dict(
+            k_scale=jnp.asarray(rng.uniform(5e-3, 2e-2, pool[:3]), jnp.float32),
+            v_scale=jnp.asarray(rng.uniform(5e-3, 2e-2, pool[:3]), jnp.float32))
+    else:
+        kp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
+        vp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
+        scales = {}
+    with compile_clock() as c:
+        got = paged_attention(q, kp, vp, tables, lengths, **scales)
+        got.block_until_ready()
+    with jax.default_matmul_precision("highest"):
+        want = paged_attention_ref(q, kp, vp, tables, lengths, **scales)
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got).all(), f"paged_attention {kind}: non-finite"
+    err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+    assert err <= ATTN_TOL, f"paged_attention {kind}: rel err {err} > {ATTN_TOL}"
+    print(f"[kernels] paged_attention {kind} H_kv={hkv} G={g} D={hd} "
+          f"page={ps} lanes={lanes} table={n_tbl}: max err {err:.3e} of scale "
+          f"(tol {ATTN_TOL}) (compile {c.compile_s:.2f}s)")
 
 
 # -- phase 2: the engine ----------------------------------------------------

@@ -3,16 +3,31 @@
 The serving engine's paged KV cache (repro/paging/) stores K/V in a global
 page pool ``(n_pages, page_size, H_kv, D)`` shared by every lane; a lane's
 logical sequence is the concatenation of the physical pages its block
-table names.  The kernel streams those pages straight from the pool —
-``PrefetchScalarGridSpec`` hands the block table to the BlockSpec index
-maps, so page ``j`` of lane ``b`` is DMA'd from ``tables[b, j]`` without
-ever materializing the gathered (B, S, H, D) view that the jnp twin
-builds.  A flash-style running softmax (per-lane max / denominator / value
-accumulator in VMEM scratch) folds the pages into the output in one pass.
+table names.  The kernel streams those pages straight from the pool into
+VMEM without ever materializing the gathered (B, S, H, D) view that the
+jnp twin builds.  A flash-style running softmax (per-lane max /
+denominator / value accumulator in VMEM scratch) folds them into the
+output in one pass.
 
-The grid is (lane, page): each step takes one whole page for all KV
-heads, so a block's last two dims are the pool's own (Mosaic refuses a
-size-1 block on ``H_kv``), and the heads are unrolled in the body.
+The grid walks only live blocks.  A block is ``pages_per_block`` whole
+pages of all KV heads (``ppb * page_size`` rows); lane ``b`` needs
+``cdiv(lengths[b], block rows)`` of them, at least one, so an inactive
+lane (length 1) costs one block and blocks past a lane's length are never
+visited.  The grid is one step per live (lane, block) pair, its length
+a traced value (the sum of the lanes' live blocks), and each step's pages
+arrive as ``ppb`` page-sized BlockSpecs per pool array whose index maps
+read ``tables[b, j]`` from the prefetched scalars: the pipeline fetches
+the next step's pages, the next lane's first block included, while the
+current block is computed.  A ragged last block of the table clamps its
+page indices; every block masks rows by ``kpos < length``.  The block size
+is derived from the shapes (:func:`pages_per_block`), not passed in.  The
+heads are unrolled in the body, each with its own flash state, which is
+set at a lane's first block and emitted at its last.
+
+(Manual async copies from a ``pl.ANY`` pool cannot express this: Mosaic
+refuses a page slice of a pool whose minor dims are not 128-aligned —
+D = 64, and the ``(page_size, H_kv)`` scale planes — while BlockSpec
+windows of the same pages compile.)
 
 The int8 byte-size variant fuses page dequantization: int8 payloads are
 read from the pool and the per-(position, head) scales multiply the K / V
@@ -26,7 +41,7 @@ Layouts (G = query heads per KV head):
     kp, vp   (n_pages, page_size, H_kv, D)   bf16 | int8
     k_scale, v_scale  (n_pages, page_size, H_kv) f32 (int8 variant)
     tables   (B, P) int32 physical page ids
-    lengths  (B,)   int32 valid rows per lane (pos + 1 at decode)
+    lengths  (B,)   int32 valid rows per lane (pos + 1 at decode), >= 1
     out      (B, H_kv, G, D) f32
 
 Off-TPU the kernel runs only through the Pallas interpreter
@@ -47,50 +62,79 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.spoga_gemm import CompilerParams
 
 NEG_INF = -1e30
+# rows a block aims at: of 64-512, the fastest at chat lengths on a v5e
+# (PERF.md); smaller blocks pay the per-step overhead more often, larger
+# ones attend more masked rows past the lanes' lengths
+BLOCK_ROWS = 128
+# logical bytes the double-buffered K/V page blocks (and scales) may take
+BLOCK_VMEM_BYTES = 8 << 20
 
 
-def _kernel(tables_ref, lengths_ref, q_ref, kp_ref, vp_ref, *rest,
-            page_size: int, n_tbl: int, int8: bool):
-    """One grid step = one page of one lane, for all of its KV heads.
+def pages_per_block(page_size: int, n_kv_heads: int, head_dim: int,
+                    dtype, n_tbl: int) -> int:
+    """Pages per block for a pool of this geometry: ``BLOCK_ROWS`` rows of
+    whole pages, fewer where two buffers of the K and V blocks would pass
+    ``BLOCK_VMEM_BYTES``, never more than the table holds."""
+    dtype = jnp.dtype(dtype)
+    row = n_kv_heads * (head_dim * dtype.itemsize + 4 * (dtype == jnp.int8))
+    fit = BLOCK_VMEM_BYTES // (2 * 2 * page_size * row)
+    return max(1, min(BLOCK_ROWS // page_size, fit, n_tbl))
 
-    The K/V block is a whole page ``(1, page_size, H_kv, D)``: its last two
-    dims equal the pool's, which is what Mosaic requires of a block (a
-    size-1 block on ``H_kv`` is refused).  The heads are unrolled in the
-    body; each keeps its own flash state in the scratch rows ``[h]``.
+
+def _live_blocks(lengths, rows: int, n_blocks: int):
+    return jnp.clip(pl.cdiv(lengths, rows), 1, n_blocks)
+
+
+def _kernel(lane_ref, blk_ref, tables_ref, lengths_ref, q_ref, *refs,
+            page_size: int, ppb: int, n_blocks: int, int8: bool):
+    """One grid step = one live block of one lane, for all its KV heads.
+
+    ``refs`` holds ``ppb`` page blocks ``(1, page_size, H_kv, D)`` of each
+    pool array (K, V, and in the int8 variant K and V scales), then the
+    output and the flash scratch.  A block's last two dims are the pool's
+    own (Mosaic refuses a size-1 block on ``H_kv``).
     """
-    if int8:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-    b, p = pl.program_id(0), pl.program_id(1)
-    n_heads, _, d = q_ref.shape[1:]
+    n_in = 4 if int8 else 2
+    pages = [refs[a * ppb:(a + 1) * ppb] for a in range(n_in)]
+    o_ref, m_ref, l_ref, acc_ref = refs[n_in * ppb:]
+    step = pl.program_id(0)
+    lane, blk = lane_ref[step], blk_ref[step]
+    length = lengths_ref[lane]
+    n_heads, g, d = q_ref.shape[1:]
+    rows = ppb * page_size
 
-    @pl.when(p == 0)
+    @pl.when(blk == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    def gather(a, h):
+        """Rows of head ``h`` of pool array ``a`` across the block's pages."""
+        out = []
+        for p in range(ppb):
+            x = pages[a][p][0, :, h, :].astype(jnp.float32)   # (page_size, D)
+            if int8:
+                # fused dequant: per-row scales ride the page rows, so
+                # q.(k*ks) == (q.k)*ks and p@(v*vs) == (p*vs)@v
+                x = x * pages[a + 2][p][0, :, h:h + 1]
+            out.append(x)
+        return jnp.concatenate(out, axis=0)                  # (rows, D)
+
+    kpos = blk * rows + jax.lax.broadcasted_iota(jnp.int32, (g, rows), 1)
     for h in range(n_heads):
         q = q_ref[0, h].astype(jnp.float32)                # (G, D)
-        k = kp_ref[0, :, h, :].astype(jnp.float32)         # (page_size, D)
-        v = vp_ref[0, :, h, :].astype(jnp.float32)
-        if int8:
-            # fused dequant: per-row scales ride the page rows, so
-            # q.(k*ks) == (q.k)*ks and p@(v*vs) == (p*vs)@v
-            k = k * ks_ref[0, :, h:h + 1]
-            v = v * vs_ref[0, :, h:h + 1]
+        k, v = gather(0, h), gather(1, h)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        ) * (d ** -0.5)                                    # (G, page_size)
-        kpos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < lengths_ref[b], s, NEG_INF)
+        ) * (d ** -0.5)                                    # (G, rows)
+        s = jnp.where(kpos < length, s, NEG_INF)
 
         # flash update: m/l scratches are (G, 128) lane-replicated scalars
         m_prev, l_prev = m_ref[h], l_ref[h]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new[:, :1])                   # (G, page_size)
+        pexp = jnp.exp(s - m_new[:, :1])                   # (G, rows)
         l_ref[h] = alpha * l_prev + jnp.sum(pexp, axis=1, keepdims=True)
         m_ref[h] = m_new
         pv = jax.lax.dot_general(
@@ -98,7 +142,7 @@ def _kernel(tables_ref, lengths_ref, q_ref, kp_ref, vp_ref, *rest,
             preferred_element_type=jnp.float32)
         acc_ref[h] = acc_ref[h] * alpha[:, :1] + pv
 
-    @pl.when(p == n_tbl - 1)
+    @pl.when(blk == _live_blocks(length, rows, n_blocks) - 1)
     def _emit():
         for h in range(n_heads):
             o_ref[0, h] = acc_ref[h] / l_ref[h][:, :1]
@@ -116,32 +160,35 @@ def paged_attention(q, kp, vp, tables, lengths, *, k_scale=None,
     page_size = kp.shape[1]
     n_tbl = tables.shape[1]
     int8 = k_scale is not None
+    ppb = pages_per_block(page_size, hkv, d, kp.dtype, n_tbl)
+    n_blocks = pl.cdiv(n_tbl, ppb)
+    lengths = lengths.astype(jnp.int32)
 
-    def q_idx(bi, pi, tbl, ln):
-        return (bi, 0, 0, 0)
+    # the work list: step i attends block blk[i] of lane lane[i]; lanes in
+    # order, each over its live blocks only
+    live = _live_blocks(lengths, ppb * page_size, n_blocks)
+    ends = jnp.cumsum(live)
+    steps = jnp.arange(b * n_blocks, dtype=jnp.int32)
+    lane = jnp.minimum(jnp.sum(steps[:, None] >= ends[None, :], axis=1,
+                               dtype=jnp.int32), b - 1)
+    blk = steps - (ends - live)[lane]
 
-    def kv_idx(bi, pi, tbl, ln):
-        return (tbl[bi, pi], 0, 0, 0)
+    def q_idx(i, lane, blk, tbl, ln):
+        return (lane[i], 0, 0, 0)
 
-    def scale_idx(bi, pi, tbl, ln):
-        return (tbl[bi, pi], 0, 0)
+    def page_spec(p, block):
+        def idx(i, lane, blk, tbl, ln):
+            j = jnp.minimum(blk[i] * ppb + p, n_tbl - 1)
+            return (tbl[lane[i] * n_tbl + j],) + (0,) * (len(block) - 1)
+        return pl.BlockSpec(block, idx)
 
-    in_specs = [
-        pl.BlockSpec((1, hkv, g, d), q_idx),
-        pl.BlockSpec((1, page_size, hkv, d), kv_idx),
-        pl.BlockSpec((1, page_size, hkv, d), kv_idx),
-    ]
-    operands = [q, kp, vp]
-    if int8:
-        in_specs += [
-            pl.BlockSpec((1, page_size, hkv), scale_idx),
-            pl.BlockSpec((1, page_size, hkv), scale_idx),
-        ]
-        operands += [k_scale, v_scale]
-
+    pools = [kp, vp] + ([k_scale, v_scale] if int8 else [])
+    in_specs = [pl.BlockSpec((1, hkv, g, d), q_idx)]
+    for pool in pools:
+        in_specs += [page_spec(p, (1,) + pool.shape[1:]) for p in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_tbl),
+        num_scalar_prefetch=4,
+        grid=(ends[-1],),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hkv, g, d), q_idx),
         scratch_shapes=[
@@ -151,14 +198,15 @@ def paged_attention(q, kp, vp, tables, lengths, *, k_scale=None,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, page_size=page_size, n_tbl=n_tbl, int8=int8),
+        functools.partial(_kernel, page_size=page_size, ppb=ppb,
+                          n_blocks=n_blocks, int8=int8),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        # a lane's blocks run in order: its flash state carries across them
+        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
+    )(lane, blk, tables.astype(jnp.int32).reshape(-1), lengths, q,
+      *[pool for pool in pools for _ in range(ppb)])
 
 
 def paged_attention_ref(q, kp, vp, tables, lengths, *, k_scale=None,

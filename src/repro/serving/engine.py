@@ -82,6 +82,7 @@ from repro.configs.base import (
     default_cache_len,
     pages_for,
 )
+from repro.kernels.paged_attention import pages_per_block
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.obs import DISABLED, Observability
 from repro.paging import (
@@ -111,6 +112,20 @@ PAGED_KINDS = frozenset({"attn", "mla", "moe", "dense_ffn_layer"})
 _ZERO_KEY = np.zeros((2,), np.uint32)
 
 _sample_jit = jax.jit(sample_tokens)
+
+
+def _attn_block_geometry(store: PagedCache):
+    """(rows per block, blocks per table) of the paged-attention kernel on
+    this pool's K/V pages as one device holds them, or None for a stack
+    with no K/V pool (MLA latents, recurrent state)."""
+    cache = store.cache
+    for blk in (*cache["head_blocks"], *cache["blocks"], *cache["tail_blocks"]):
+        if "kp" in blk:
+            kp = blk["kp"]
+            ps, hkv, d = kp.sharding.shard_shape(kp.shape)[-3:]
+            ppb = pages_per_block(ps, hkv, d, kp.dtype, store.max_pages)
+            return ppb * ps, -(-store.max_pages // ppb)
+    return None
 
 
 def _roundup(n: int, m: int) -> int:
@@ -411,6 +426,7 @@ class ServingEngine:
             self._chunk_fn = (
                 _jitted_chunk_step(cfg, self._chunk_len, mesh)
                 if self._chunk_len is not None else None)
+            self._attn_blocks = _attn_block_geometry(self.store)
         else:
             if engine_cfg.prefill_chunk is not None:
                 raise ValueError("chunked prefill requires cache_mode='paged'")
@@ -420,6 +436,7 @@ class ServingEngine:
             self.store = SlotCache(cfg, n, engine_cfg.cache_len)
             self.prefix = None
             self._chunk_len = None
+            self._attn_blocks = None
 
         self._admit_fn = (None if self.paged
                           else _jitted_admit(cfg, engine_cfg.cache_len, mesh))
@@ -1145,6 +1162,8 @@ class ServingEngine:
                         running[slot].cost.page_steps += len(mgr.lane_pages[slot])
                     self.store.sync_tables()
                 self.metrics.max_gauge("peak_pages_used", mgr.pages_in_use)
+                if self._attn_blocks is not None:
+                    self._count_attn_blocks(list(running))
             active = np.zeros((self.engine_cfg.n_slots,), bool)
             active[list(running)] = True
             with tracer.span("decode", lanes=running, batch=len(running)):
@@ -1185,6 +1204,18 @@ class ServingEngine:
                                      moves=[[int(s), int(d)] for s, d in moves],
                                      step=self._step_idx)
         return finished
+
+    def _count_attn_blocks(self, lanes: list) -> None:
+        """The paged-attention kernel's blocks this decode step, per layer:
+        the live blocks it walks (a running lane attends its rows plus the
+        one being written, an idle lane one row) against every lane's
+        whole table, from the host's lengths (no device sync)."""
+        rows, n_blocks = self._attn_blocks
+        lens = np.ones((self.engine_cfg.n_slots,), np.int64)
+        lens[lanes] = self.store.manager.lengths[lanes] + 1
+        live = np.clip(-(-lens // rows), 1, n_blocks)
+        self.metrics.inc("attn_blocks_live", int(live.sum()))
+        self.metrics.inc("attn_blocks_walked", len(lens) * n_blocks)
 
     # ------------------------------------------------------------------
     # Speculative decoding (repro/spec/)

@@ -40,6 +40,9 @@ from repro.serving.request import Request
 _COUNTERS = (
     "steps", "prefills", "prefill_dispatches", "stacked_prefills",
     "decode_steps", "chunk_steps", "defrag_count", "defrag_pages_moved",
+    # paged-attention kernel blocks per layer, summed over decode steps:
+    # the live blocks walked, and lanes x blocks of the whole table
+    "attn_blocks_live", "attn_blocks_walked",
     "prefix_hits", "prefix_misses", "prefix_hit_tokens", "prefix_cow_forks",
     "prefix_evicted_pages", "spec_proposed", "spec_accepted",
     "verify_dispatches",
@@ -215,6 +218,11 @@ class EngineMetrics:
             "prefill_dispatches": self.prefill_dispatches,
             "stacked_prefills": self.stacked_prefills,
             "decode_steps": self.decode_steps,
+            "attn_blocks_live": self.attn_blocks_live,
+            "attn_blocks_walked": self.attn_blocks_walked,
+            "attn_live_share": round(
+                self.attn_blocks_live / self.attn_blocks_walked, 4)
+            if self.attn_blocks_walked else 0.0,
             "prefill_s": round(self.prefill_s, 4),
             "decode_s": round(self.decode_s, 4),
             "ttft_mean_s": round(_mean([r.ttft_s for r in reqs]), 4),

@@ -14,7 +14,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced
-from repro.kernels.paged_attention import paged_attention, paged_attention_ref
+from repro.kernels.paged_attention import (
+    paged_attention, paged_attention_ref, pages_per_block)
 from repro.models import init_params, prefill
 from repro.paging import PageManager, PagedCache
 
@@ -145,14 +146,33 @@ def test_paged_cache_defrag_preserves_lane_contents():
 # Pallas paged-attention kernel vs jnp twin
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("int8", [False, True])
-def test_paged_attention_kernel_matches_ref(int8):
-    rng = np.random.default_rng(0)
-    b, hkv, g, d, ps, n_pages, n_tbl = 3, 2, 4, 32, 8, 16, 4
+# (page_size, table pages, lengths from block rows and table rows) per
+# case; the block cases follow the kernel's own block size, with a table of
+# two whole blocks and a ragged third
+_PA_CASES = {
+    "mixed": (8, 4, lambda rows, full: [1, 17, 32]),
+    "block_edges": (16, None, lambda rows, full: [rows - 1, rows, rows + 1,
+                                                 2 * rows - 1, 2 * rows, 2 * rows + 1]),
+    "ragged_table": (16, None, lambda rows, full: [2 * rows + 3, full - 1, full]),
+    "inactive_lanes": (16, None, lambda rows, full: [1, full, 1, rows + 5, 1]),
+    "whole_table": (16, None, lambda rows, full: [full]),
+}
+
+
+def _pa_inputs(rng, case, int8, bf16=False):
+    b_ps, n_tbl, lens = _PA_CASES[case]
+    hkv, g, d, ps = 2, 4, 32, b_ps
+    pool_dt = jnp.int8 if int8 else (jnp.bfloat16 if bf16 else jnp.float32)
+    if n_tbl is None:
+        ppb = pages_per_block(ps, hkv, d, pool_dt, 1 << 20)
+        n_tbl = 2 * ppb + ppb // 2 + 1
+    ppb = pages_per_block(ps, hkv, d, pool_dt, n_tbl)
+    lengths = lens(ppb * ps, n_tbl * ps)
+    b = len(lengths)
+    n_pages = b * n_tbl + 1
     q = jnp.asarray(rng.normal(size=(b, hkv, g, d)), jnp.float32)
     tables = jnp.asarray(rng.permutation(np.arange(1, n_pages))[:b * n_tbl]
                          .reshape(b, n_tbl), jnp.int32)
-    lengths = jnp.asarray([1, 17, 32], jnp.int32)   # partial / multi / full
     if int8:
         kp = jnp.asarray(rng.integers(-127, 128, (n_pages, ps, hkv, d)), jnp.int8)
         vp = jnp.asarray(rng.integers(-127, 128, (n_pages, ps, hkv, d)), jnp.int8)
@@ -161,9 +181,23 @@ def test_paged_attention_kernel_matches_ref(int8):
             v_scale=jnp.asarray(rng.uniform(0.005, 0.02, (n_pages, ps, hkv)), jnp.float32),
         )
     else:
-        kp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), pool_dt)
+        vp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), pool_dt)
         scales = {}
+    return q, kp, vp, tables, jnp.asarray(lengths, jnp.int32), scales
+
+
+@pytest.mark.parametrize("int8, case", [
+    pytest.param(False, "mixed", id="False"),        # partial / multi / full
+    pytest.param(True, "mixed", id="True"),
+    *[pytest.param(int8, case, id=f"{'int8' if int8 else 'bf16'}-{case}")
+      for case in ("block_edges", "ragged_table", "inactive_lanes", "whole_table")
+      for int8 in (False, True)],
+])
+def test_paged_attention_kernel_matches_ref(int8, case):
+    rng = np.random.default_rng(0)
+    q, kp, vp, tables, lengths, scales = _pa_inputs(
+        rng, case, int8, bf16=case != "mixed")
     ref = paged_attention_ref(q, kp, vp, tables, lengths, **scales)
     out = paged_attention(q, kp, vp, tables, lengths, interpret=True, **scales)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -191,3 +225,15 @@ def test_paged_attention_ignores_stale_pages():
     ref6 = paged_attention(q, kp, vp, tables, jnp.asarray([6]), interpret=True)
     assert not np.allclose(np.asarray(out5), np.asarray(out5b))  # valid row changed
     np.testing.assert_allclose(np.asarray(out6), np.asarray(ref6))
+
+    # table entries past a lane's last page (stale or trash ids) are fetched
+    # with the lane's last block but never attended: poisoning the pages
+    # they name leaves the output unchanged
+    q, kp, vp, tables, _, _ = _pa_inputs(rng, "whole_table", False)
+    ps = kp.shape[1]
+    lengths = jnp.asarray([3 * ps + 5], jnp.int32)   # 4 live pages of a block
+    out = paged_attention(q, kp, vp, tables, lengths, interpret=True)
+    dead = tables[0, 4:]
+    kp2, vp2 = kp.at[dead].set(99.0), vp.at[dead].set(-99.0)
+    out2 = paged_attention(q, kp2, vp2, tables, lengths, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))

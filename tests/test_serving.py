@@ -276,6 +276,33 @@ def test_engine_paged_int8_matches_slot_int8():
     assert outs["paged"] == outs["slot"]
 
 
+def test_engine_counts_attn_blocks():
+    """The paged-attention block counters follow the lanes' lengths: a lane
+    attending rows 2r-5 .. 2r+3 (r rows to a kernel block) walks two blocks,
+    then three from row 2r+1, and the idle lane one; every step counts
+    2 lanes x the table's blocks (64 pages of 8 rows).  A stack with no K/V
+    pool (MLA latents) counts nothing."""
+    cfg, params, engine = _engine_setup(
+        "llama3.2-1b", None, cache_len=512,
+        ecfg_kw=dict(cache_mode="paged", page_size=8))
+    rows, n_blocks = engine._attn_blocks
+    assert rows % 8 == 0 and n_blocks == -(-64 // (rows // 8)) >= 3
+    prompt = np.random.default_rng(0).integers(0, cfg.vocab_size,
+                                               2 * rows - 6).tolist()
+    rep = engine.run([(0, prompt, 10)]).report()
+    assert rep["decode_steps"] == 9
+    assert rep["attn_blocks_live"] == 6 * 2 + 3 * 3 + 9 * 1
+    assert rep["attn_blocks_walked"] == 9 * 2 * n_blocks
+    assert rep["attn_live_share"] == round(30 / (18 * n_blocks), 4)
+
+    cfg, params, engine = _engine_setup(
+        "minicpm3-4b", None, ecfg_kw=dict(cache_mode="paged", page_size=8))
+    assert engine._attn_blocks is None
+    prompt = np.random.default_rng(0).integers(0, cfg.vocab_size, 5).tolist()
+    rep = engine.run([(0, prompt, 4)]).report()
+    assert rep["attn_blocks_walked"] == 0 and rep["attn_live_share"] == 0.0
+
+
 def test_engine_paged_decode_traced_once():
     """Acceptance: growth, admission, eviction and table refreshes never
     retrace the decode step (fixed shapes end to end)."""

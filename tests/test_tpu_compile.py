@@ -104,10 +104,16 @@ def test_compiled_backend_compiles(one_chip, on_tpu, name, m, k, n):
     assert "tpu_custom_call" in hlo
 
 
-def _paged_args(sharding, int8, lanes=LANES, pages_per_lane=36):
-    hkv, d = LLAMA.n_kv_heads, LLAMA.resolved_head_dim
-    g, ps = LLAMA.n_heads // hkv, 16
-    n_pages = lanes * pages_per_lane + 1
+def _paged_args(sharding, int8, lanes=LANES, pages_per_lane=36, geometry=None):
+    """Paged-attention operands: llama3.2-1b's heads by default, else
+    ``geometry`` = (H_kv, G, D) over a pool of 4097 pages."""
+    if geometry is None:
+        hkv, d = LLAMA.n_kv_heads, LLAMA.resolved_head_dim
+        g = LLAMA.n_heads // hkv
+        n_pages = lanes * pages_per_lane + 1
+    else:
+        (hkv, g, d), n_pages = geometry, 4097
+    ps = 16
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     dt = jnp.int8 if int8 else jnp.bfloat16
     args = [s((lanes, hkv, g, d), jnp.bfloat16), s((n_pages, ps, hkv, d), dt),
@@ -125,9 +131,19 @@ def _paged(attn):
     return call
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_paged_attention_compiles(one_chip, int8):
-    hlo = _compile(_paged(paged_attention), *_paged_args(one_chip, int8))
+# the served geometry: 32 lanes of 257-page tables (a table that is not a
+# multiple of the block), H_kv 8 with D 128, G 4 (mistral-nemo-12b) and
+# D 64, G 3 (granite-3.0-3b-a800m)
+SERVED = {"nemo-int8": (8, 4, 128), "granite-int8": (8, 3, 64)}
+
+
+@pytest.mark.parametrize("int8, geometry", [
+    (False, None), (True, None), *[(True, g) for g in SERVED.values()]],
+    ids=["bf16", "int8", *SERVED])
+def test_paged_attention_compiles(one_chip, int8, geometry):
+    kw = {} if geometry is None else dict(lanes=32, pages_per_lane=257,
+                                          geometry=geometry)
+    hlo = _compile(_paged(paged_attention), *_paged_args(one_chip, int8, **kw))
     assert "tpu_custom_call" in hlo
 
 
