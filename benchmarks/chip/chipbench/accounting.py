@@ -70,24 +70,38 @@ def tok_s(rec: dict) -> float:
 # Logical work of the model, from the configuration's published sizes
 # ---------------------------------------------------------------------------
 
+def layer_counts(fam, m: dict) -> dict:
+    """The logical sizes of the reference family module ``fam``, layer by
+    layer (``reference.layer_kinds``): each layer's ``layer_params`` and
+    ``kernel_gemms``, and the (query-key, value) width of an attention
+    head.  A run's record keeps them as ``counts``."""
+    from reference import attn_dims, layer_kinds
+
+    kinds = layer_kinds(fam, m)
+    return {"layer_params": [fam.layer_params(m, k) for k in kinds],
+            "kernel_gemms": [fam.kernel_gemms(m, k) for k in kinds],
+            "attn_dims": list(attn_dims(fam, m))}
+
+
 def body_params(rec: dict) -> int:
-    """Active matmul parameters a token passes through below the head
-    (``rec["counts"]["layer_params"]``: the reference family's
-    ``layer_params``, per layer)."""
-    p = rec["counts"]["layer_params"]
-    return rec["model"]["num_hidden_layers"] * (p["attn"] + p["ffn_active"])
+    """Active matmul parameters a token passes through below the head,
+    summed over the layers of ``rec["counts"]["layer_params"]``."""
+    return sum(p["attn"] + p["ffn_active"] for p in rec["counts"]["layer_params"])
 
 
 def head_params(m: dict) -> int:
     return m["vocab_size"] * m["hidden_size"]
 
 
-def attn_ops(m: dict, first: int, last: int) -> float:
+def attn_ops(m: dict, first: int, last: int, dims=None) -> float:
     """Operations of attention for query positions ``[first, last)``, each
-    attending every key up to and including itself: two matrix products
-    (scores, values) of ``2 * keys * head_dim`` per query head per layer."""
+    attending every key up to and including itself: two matrix products,
+    scores of ``2 * keys * qk_dim`` and values of ``2 * keys * v_dim``, per
+    query head per layer (``dims``: ``(qk_dim, v_dim)``, ``head_dim`` both
+    where not given)."""
+    qk, v = dims or (m["head_dim"], m["head_dim"])
     keys = (last * (last + 1) - first * (first + 1)) / 2.0
-    return (4.0 * keys * m["num_attention_heads"] * m["head_dim"]
+    return (2.0 * keys * (qk + v) * m["num_attention_heads"]
             * m["num_hidden_layers"])
 
 
@@ -98,18 +112,19 @@ def model_ops_in_window(rec: dict, m: dict) -> float:
     idle lanes and re-quantization are not counted."""
     t0, t1 = rec["window"]
     body, head = 2.0 * body_params(rec), 2.0 * head_params(m)
+    dims = rec["counts"]["attn_dims"]
     ops = 0.0
     for r in rec["requests"]:
         p = r["prompt_len"]
         for t, a, b in r["prefill_events"]:
             if t0 <= t < t1:
-                ops += body * (b - a) + attn_ops(m, a, b)
+                ops += body * (b - a) + attn_ops(m, a, b, dims)
         for j, t in enumerate(r["token_times"]):
             if not t0 <= t < t1:
                 continue
             ops += head
             if j > 0:   # token j-1 was decoded at position p + j - 1
-                ops += body + attn_ops(m, p + j - 1, p + j)
+                ops += body + attn_ops(m, p + j - 1, p + j, dims)
     return ops
 
 
@@ -167,15 +182,14 @@ def gemm_rows(step: dict, serving: dict) -> list[int]:
 
 def gemm_least_seconds(rec: dict, lo: float, hi: float) -> float:
     """Least time of every quantized GEMM the steps in ``[lo, hi]``
-    dispatched (``rec["counts"]["kernel_gemms"]``: the (K, N) of one
+    dispatched (``rec["counts"]["kernel_gemms"]``: the (K, N) of each
     layer's plain linear GEMMs, from the reference family)."""
-    m, peaks = rec["model"], rec["peaks"]
-    per_layer = rec["counts"]["kernel_gemms"]
+    peaks = rec["peaks"]
+    gemms = [kn for layer in rec["counts"]["kernel_gemms"] for kn in layer]
     total = 0.0
     for st in steps_in(rec, lo, hi):
         for mm in gemm_rows(st, rec["serving"]):
-            total += m["num_hidden_layers"] * sum(
-                gemm_least_time(mm, k, n, peaks) for k, n in per_layer)
+            total += sum(gemm_least_time(mm, k, n, peaks) for k, n in gemms)
     return total
 
 

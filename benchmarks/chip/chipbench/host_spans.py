@@ -1,16 +1,15 @@
 """The engine's own spans in a profiler trace: the ``engine.*``
 annotations that the program's tracer (``repro.obs.trace``) puts on the
-host plane, on the device trace's clock.
+host plane, on the device trace's clock, which ``trace_reduce.read_events``
+keeps beside the harness's annotations.
 
-``read_host`` keeps them beside the harness's annotations;
 ``host_spans`` clips them to the ``measured_slice``; ``label_at`` names
 the host work around an instant, innermost first: the harness's
 ``token_cb``, then the innermost ``engine.*`` span, then the harness's
-``admit``, ``await_arrival`` and ``step``; ``idle_gaps`` labels the
-device's longest gaps so, and ``idle_by_label`` splits all of the
-device's idle time by those labels; ``host_ms_per_step`` prices the host
-work that each decode step's tokens wait on.  A trace of a program without the
-spans holds none: every reading is then empty or ``None``.
+``admit``, ``await_arrival`` and ``step``; ``idle_by_label`` splits all of
+the device's idle time by those labels; ``host_ms_per_step`` prices the
+host work that each decode step's tokens wait on.  A trace of a program
+without the spans holds none: every reading is then empty or ``None``.
 """
 
 from __future__ import annotations
@@ -19,28 +18,11 @@ import statistics
 
 from chipbench import trace_reduce
 
-PREFIX = "engine."
+PREFIX = trace_reduce.ENGINE_PREFIX
 STEP, DECODE = PREFIX + "step", PREFIX + "decode"
 # the engine's blocking waits on the device, not host work
 WAITS = (PREFIX + "token_pull", PREFIX + "first_token_pull", PREFIX + "admit_sync")
 HARNESS = ("admit", "await_arrival", "step")      # outermost last
-
-
-def read_host(path: str) -> list:
-    """Host events of one trace file: the slice, the harness's
-    annotations and the engine's spans, each ``(name, start_ns, end_ns)``."""
-    from jax.profiler import ProfileData
-
-    keep = (trace_reduce.SLICE,) + trace_reduce.HOST_LABELS
-    host = []
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host"):
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name in keep or ev.name.startswith(PREFIX):
-                    host.append((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns))
-    return host
 
 
 def host_spans(host) -> dict:
@@ -73,14 +55,6 @@ def idle_intervals(host, devices) -> list:
                                  if b > lo and a < hi])
     edges = [lo] + [x for iv in merged for x in iv] + [hi]
     return [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
-
-
-def idle_gaps(host, devices, top: int = trace_reduce.TOP_N) -> list:
-    """The longest idle gaps, longest first, each ``[label, seconds]`` by
-    ``label_at`` at the gap's middle."""
-    gaps = [[label_at(host, (a + b) / 2), (b - a) / 1e9]
-            for a, b in idle_intervals(host, devices)]
-    return sorted(gaps, key=lambda g: -g[1])[:top]
 
 
 def split(host, a, b) -> dict:

@@ -47,6 +47,18 @@ class Cell:
         """The program's side of the family, ``layouts/<family>.py``."""
         return importlib.import_module(f"layouts.{self.family}")
 
+    def program_params(self, seed: int, shapes):
+        """The program's parameter tree (``shapes``) filled from the seed:
+        every layer with its kind's weights, converted by the layout's
+        ``to_program``."""
+        from chipbench import weights
+        from reference import layer_kinds
+
+        m, ref = self.model, self.reference
+        return weights.program_params(m, seed, shapes, layer_kinds(ref, m),
+                                      lambda kind: ref.layer_specs(m, kind),
+                                      self.layout.to_program)
+
     @property
     def serving(self) -> dict:
         """The deployment's serving geometry, from the configuration's

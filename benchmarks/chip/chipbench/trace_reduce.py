@@ -1,7 +1,7 @@
 """Reduce a profiler trace (``*.xplane.pb``) to what the per-layer
 metrics read: device busy time, device self time per operation, device
-time per kernel, and the idle gaps labelled by what the harness was
-doing.
+time per kernel, the idle gaps labelled by what the harness was doing,
+each device op's HLO scope, and the program's ``engine.*`` host spans.
 
 The harness marks the slice it measures with a ``measured_slice``
 annotation on the host; everything is clipped to that slice.  Device
@@ -9,7 +9,10 @@ operations are the events of each TPU plane's ``XLA Ops`` line; a
 kernel's events are those whose name, or whose HLO text, names it.  Host
 labels are the harness's own annotations (``step``, ``admit``,
 ``await_arrival``, ``token_cb``); a gap that none of them covers is the
-harness's own bookkeeping between steps.
+harness's own bookkeeping between steps.  An op's scope (``scopes``,
+``op_scopes.read_scopes``) and the engine's spans (``host_spans``,
+``host_spans.host_spans``) are kept apart from the rest, which they leave
+as it was.
 """
 
 from __future__ import annotations
@@ -21,6 +24,7 @@ from collections import defaultdict
 
 SLICE = "measured_slice"
 HOST_LABELS = ("token_cb", "admit", "await_arrival", "step")   # innermost first
+ENGINE_PREFIX = "engine."       # the program's own host spans
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 TOP_N = 10
@@ -42,8 +46,10 @@ def _stats(ev) -> dict:
 
 
 def read_events(path: str):
-    """(host annotations, device ops per TPU index) from one trace file.
-    Each event is (name, start_ns, end_ns, hlo_text)."""
+    """(host annotations, device ops per TPU index) from one trace file:
+    the slice, the harness's annotations and the program's ``engine.*``
+    spans, each (name, start_ns, end_ns); each device op (name, start_ns,
+    end_ns, hlo_text)."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
@@ -61,7 +67,8 @@ def read_events(path: str):
                         (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, text))
             elif plane.name.startswith("/host"):
                 for ev in line.events:
-                    if ev.name == SLICE or ev.name in HOST_LABELS:
+                    if (ev.name == SLICE or ev.name in HOST_LABELS
+                            or ev.name.startswith(ENGINE_PREFIX)):
                         host.append((ev.name, ev.start_ns,
                                      ev.start_ns + ev.duration_ns))
     return host, dict(devices)
@@ -147,8 +154,17 @@ def _op_key(name: str) -> str:
 
 
 def reduce_trace(trace_dir: str, chips: int) -> dict:
-    host, devices = read_events(find_xplane(trace_dir))
-    return reduce_events(host, devices, chips)
+    """``reduce_events`` of the newest trace under ``trace_dir``, with each
+    device op's HLO scope (``scopes``: event name -> ``tf_op``) and the
+    slice's ``engine.*`` spans (``host_spans``)."""
+    from chipbench import host_spans, op_scopes
+
+    path = find_xplane(trace_dir)
+    host, devices = read_events(path)
+    out = reduce_events(host, devices, chips)
+    out["scopes"] = op_scopes.read_scopes(path)
+    out["host_spans"] = host_spans.host_spans(host)
+    return out
 
 
 def kernel_seconds(reduced: dict, needle: str) -> tuple[float, int]:

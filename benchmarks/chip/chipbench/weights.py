@@ -94,28 +94,61 @@ def _path_name(path) -> list[str]:
     return [str(getattr(e, "key", getattr(e, "idx", e))) for e in path]
 
 
-def program_params(m: dict, seed: int, shapes, specs: dict, to_program):
+def _layer_groups(shapes) -> dict:
+    """``{tree prefix: (layer indices, stacked)}`` of the program's layer
+    trees: ``head_blocks/<i>`` is layer ``i``; ``blocks/<s>`` is stacked
+    over periods, period ``q`` of slot ``s`` being layer ``head + q p + s``
+    (``p`` slots); ``tail_blocks/<i>`` is the depth remainder after them."""
+    head = len(shapes.get("head_blocks", []))
+    slots = shapes.get("blocks", ())
+    periods = jax.tree_util.tree_leaves(slots[0])[0].shape[0] if slots else 0
+    groups = {f"head_blocks/{i}": ([i], False) for i in range(head)}
+    groups.update({f"blocks/{s}": ([head + q * len(slots) + s for q in range(periods)], True)
+                   for s in range(len(slots))})
+    first_tail = head + periods * len(slots)
+    groups.update({f"tail_blocks/{i}": ([first_tail + i], False)
+                   for i in range(len(shapes.get("tail_blocks", [])))})
+    return groups
+
+
+def program_params(m: dict, seed: int, shapes, kinds, specs_of, to_program):
     """The program's parameter tree (``shapes``: its ``eval_shape`` of
-    ``init_params``) filled from the seed in one jitted call.  ``specs``
-    is the family's ``layer_specs``; ``to_program(name, w, m)`` converts a
-    published leaf to the program's layout.  Covers stacks of one block
-    kind scanned over every layer, with no leading or trailing unscanned
-    layers; a layout file whose program holds another tree writes its own
-    ``program_params``."""
+    ``init_params``) filled from the seed in one jitted call.  ``kinds``
+    gives each layer's kind (``reference.layer_kinds``), ``specs_of(kind)``
+    that kind's ``layer_specs``; ``to_program(name, w, m)`` converts a
+    published leaf to the program's layout.  Layer ``l`` is drawn with its
+    kind's specs under the same keys wherever the program keeps it (see
+    ``_layer_groups``); the layers of one stacked slot share a kind."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     names = ["/".join(_path_name(p)) for p, _ in flat]
-    n_layers = m["num_hidden_layers"]
+    groups = _layer_groups(shapes)
+    n_layers = sum(len(layers) for layers, _ in groups.values())
+    if n_layers != len(kinds):
+        raise ValueError(f"the program holds {n_layers} layers, the family "
+                         f"gives {len(kinds)} kinds")
+    for prefix, (layers, _) in groups.items():
+        if len({kinds[l] for l in layers}) > 1:
+            raise ValueError(f"{prefix} stacks layers of kinds "
+                             f"{sorted({str(kinds[l]) for l in layers})}")
 
     def make(words):
         root = root_key(words)
-        per_layer = jax.lax.map(
-            lambda l: {n: to_program(n, layer_leaf(root, specs, n, l), m)
-                       for n in specs},
-            jnp.arange(n_layers))
+
+        def draw(layer, kind):
+            specs = specs_of(kind)
+            return {n: to_program(n, layer_leaf(root, specs, n, layer), m) for n in specs}
+
+        drawn = {prefix: (jax.lax.map(lambda l, k=kinds[layers[0]]: draw(l, k),
+                                      jnp.asarray(layers, jnp.int32))
+                          if stacked else draw(layers[0], kinds[layers[0]]))
+                 for prefix, (layers, stacked) in groups.items()}
         out = []
         for name, (_, sd) in zip(names, flat):
-            if name.startswith("blocks/0/"):
-                leaf = per_layer[name[len("blocks/0/"):]]
+            group = "/".join(name.split("/")[:2])
+            if group in drawn:
+                leaf = drawn[group].get(name[len(group) + 1:])
+                if leaf is None:
+                    raise ValueError(f"no weights for the program's leaf {name!r}")
             elif name in ("embed", "head"):
                 leaf = table(root, m, name)
             elif name == "final_norm":

@@ -104,11 +104,9 @@ def main() -> int:
     OUT.write_bytes(op_scopes.prune(raw, KEEP_PLANES.match,
                                     lambda line: line not in DROP_LINES, {op_scopes.TF_OP}))
 
+    red = trace_reduce.reduce_trace(str(OUT.parent), 1)
+    scopes, hs = red["scopes"], red["host_spans"]
     host, devices = trace_reduce.read_events(str(OUT))
-    red = trace_reduce.reduce_events(host, devices, 1)
-    scopes = op_scopes.read_scopes(str(OUT))
-    host = host_spans.read_host(str(OUT))
-    hs = host_spans.host_spans(host)
     size = OUT.stat().st_size
     print(json.dumps({"bytes": size, "raw_bytes": len(raw), "window_s": red["window_s"],
                       "busy_s": red["busy_s"], "top_ops": red["top_ops"],
@@ -117,7 +115,7 @@ def main() -> int:
                                                   scopes.items() if "weight_quant" in op}),
                       "phases": host_spans.phase_seconds(hs),
                       "host_ms_per_step": host_spans.host_ms_per_step(hs),
-                      "idle_gaps": host_spans.idle_gaps(host, devices)}))
+                      "idle_by_label": host_spans.idle_by_label(host, devices)}))
     if size > MAX_BYTES:
         print(f"{size} bytes: over {MAX_BYTES}", file=sys.stderr)
         return 1

@@ -91,6 +91,25 @@ def gqa_specs(m: dict) -> dict:
     }
 
 
+def mlp_specs(d: int, f: int) -> dict:
+    """name -> spec of a SiLU-gated MLP of width ``f``, published layout."""
+    bf = jnp.bfloat16
+    return {"mlp/w_gate": ((d, f), bf, LINEAR_STD, 0.0),
+            "mlp/w_up": ((d, f), bf, LINEAR_STD, 0.0),
+            "mlp/w_down": ((f, d), bf, LINEAR_STD, 0.0)}
+
+
+def mlp(h, w, m, quant):
+    """The SiLU-gated MLP of ``mlp_specs``."""
+    gate = jax.nn.silu(lin(h, w["mlp/w_gate"], quant))
+    return lin(gate * lin(h, w["mlp/w_up"], quant), w["mlp/w_down"], quant)
+
+
+def mlp_gemms(d: int, f: int) -> list[tuple[int, int]]:
+    """(K, N) of the MLP's gate, up and down projections."""
+    return [(d, f), (d, f), (f, d)]
+
+
 def gqa_layer(x, w, m, quant, ffn):
     """One pre-norm decoder layer over a whole (padded) sequence,
     positions 0..S-1: GQA attention, then ``ffn(h, w, m, quant)``."""

@@ -1,11 +1,12 @@
 """Plain float32 reference of a configuration's decoder, in ``jax.numpy``.
 
-The layer is the configuration's family's (``reference/<family>.py``,
-named by the configuration file's ``family``); this module runs it: no KV
-cache, no batching, no quantization, the whole sequence recomputed from
-its tokens, layer by layer, with each layer's weights drawn again from
-the seed (``chipbench.weights``), then the final norm and the head in
-vocabulary blocks.
+The layers are the configuration's family's (``reference/<family>.py``,
+named by the configuration file's ``family``), one kind per layer
+(``reference.layer_kinds``); this module runs them: no KV cache, no
+batching, no quantization, the whole sequence recomputed from its tokens,
+layer by layer, with each layer's weights drawn again from the seed
+(``chipbench.weights``) with its kind's specs, then the final norm and the
+head in vocabulary blocks.  One program is compiled per kind.
 
 Departures from the published models are the configuration file's
 ``reduced`` and ``assumed``; weights are random (uniform, seeded).
@@ -29,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench import weights as W
+from reference import layer_kinds
 from reference.common import HI, QUERY_BLOCK, rmsnorm
 
 
@@ -38,10 +40,10 @@ def family(name: str):
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_program(fam: str, m_items: tuple, quant):
+def _layer_program(fam: str, m_items: tuple, quant, kind):
     m = dict(m_items)
     layer = family(fam).layer
-    return jax.jit(lambda x, w: layer(x, w, m, quant))
+    return jax.jit(lambda x, w: layer(x, w, m, quant, kind))
 
 
 def _padded(n: int) -> int:
@@ -75,13 +77,16 @@ def scored_rows(fam: str, m: dict, seed: int, pairs, quant=None) -> np.ndarray:
     """Final-normed float32 hidden rows at every served position of each
     (prompt, served tokens) pair: the row after prompt token ``-1`` and
     after each served token but the last, in order (P, d).  Each sequence
-    runs layer by layer; every shape is padded (lengths to a power of two,
-    rows to a multiple of ``ROW_PAD``) so repeated runs reuse compiled
-    programs."""
-    layer_fn = _layer_program(
-        fam, tuple(sorted((k, v) for k, v in m.items() if not isinstance(v, (dict, list)))),
-        quant)
-    specs = family(fam).layer_specs(m)
+    runs layer by layer, each layer through the program of its kind; every
+    shape is padded (lengths to a power of two, rows to a multiple of
+    ``ROW_PAD``) so repeated runs reuse compiled programs."""
+    f = family(fam)
+    kinds = layer_kinds(f, m)
+    m_items = tuple(sorted((k, v) for k, v in m.items() if not isinstance(v, (dict, list))))
+    programs = {k: (_layer_program(fam, m_items, quant, k),
+                    jax.jit(lambda kw, l, specs=f.layer_specs(m, k):
+                            W.layer_weights(W.root_key(kw), specs, l)))
+                for k in dict.fromkeys(kinds)}
     words = W.seed_words(seed)
     embed = jax.jit(lambda kw: W.table(W.root_key(kw), m, "embed"))(words)
     seqs = [list(p) + list(s[:-1]) for p, s in pairs]
@@ -91,8 +96,8 @@ def scored_rows(fam: str, m: dict, seed: int, pairs, quant=None) -> np.ndarray:
         ids[:len(toks)] = toks
         xs.append(_embed_rows(embed, ids))
     del embed
-    layer_w = jax.jit(lambda kw, l: W.layer_weights(W.root_key(kw), specs, l))
-    for layer in range(m["num_hidden_layers"]):
+    for layer, kind in enumerate(kinds):
+        layer_fn, layer_w = programs[kind]
         w = layer_w(words, layer)
         xs = [layer_fn(x, w) for x in xs]
         del w

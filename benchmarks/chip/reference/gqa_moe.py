@@ -2,11 +2,14 @@
 mixture of SiLU-gated experts (Granite 3.0 MoE, Mixtral), as the
 published configurations describe it: the softmax router's top
 ``num_experts_per_tok`` renormalised over the chosen experts, every
-routed token kept.  Each expert's width is ``intermediate_size``.
+routed token kept.  Each expert's width is ``intermediate_size``.  The
+first ``first_k_dense_replace`` layers (DeepSeekMoE's key; none if absent)
+are dense: the same attention with a SiLU-gated MLP of width
+``dense_intermediate_size`` (``intermediate_size`` if absent) in the
+experts' place.
 
-Same interface as every family file (``layer_specs``, ``layer``,
-``layer_params``, ``kernel_gemms``).  Nothing here imports the program
-(``repro``).
+Layer kinds ``dense`` and ``moe`` (the family interface:
+``reference/__init__.py``).  Nothing here imports the program (``repro``).
 """
 
 from __future__ import annotations
@@ -20,8 +23,19 @@ from reference import common as C
 TOKEN_BLOCK = 512
 
 
-def layer_specs(m: dict) -> dict:
+def layer_kinds(m: dict) -> list[str]:
+    lead = m.get("first_k_dense_replace", 0)
+    return ["dense"] * lead + ["moe"] * (m["num_hidden_layers"] - lead)
+
+
+def _dense_width(m: dict) -> int:
+    return m.get("dense_intermediate_size", m["intermediate_size"])
+
+
+def layer_specs(m: dict, kind="moe") -> dict:
     d, f, e = m["hidden_size"], m["intermediate_size"], m["num_local_experts"]
+    if kind == "dense":
+        return {**C.gqa_specs(m), **C.mlp_specs(d, _dense_width(m))}
     bf = jnp.bfloat16
     return {**C.gqa_specs(m),
             "moe/router": ((d, e), jnp.float32, LINEAR_STD, 0.0),
@@ -60,19 +74,25 @@ def _moe(h, w, m, quant):
     return out.reshape(t, -1)
 
 
-def layer(x, w, m, quant):
-    return C.gqa_layer(x, w, m, quant, _moe)
+def layer(x, w, m, quant, kind="moe"):
+    return C.gqa_layer(x, w, m, quant, C.mlp if kind == "dense" else _moe)
 
 
-def layer_params(m: dict) -> dict:
+def layer_params(m: dict, kind="moe") -> dict:
     """Matmul parameters of one layer: ``attn`` and ``ffn_active`` (per
-    token: the router plus ``num_experts_per_tok`` experts)."""
+    token: the router plus ``num_experts_per_tok`` experts, or the dense
+    layer's MLP)."""
     d, f = m["hidden_size"], m["intermediate_size"]
     e, k = m["num_local_experts"], m["num_experts_per_tok"]
+    if kind == "dense":
+        return {"attn": C.gqa_params(m), "ffn_active": 3 * d * _dense_width(m)}
     return {"attn": C.gqa_params(m), "ffn_active": d * e + k * 3 * d * f}
 
 
-def kernel_gemms(m: dict) -> list[tuple[int, int]]:
+def kernel_gemms(m: dict, kind="moe") -> list[tuple[int, int]]:
     """(K, N) of one layer's plain linear GEMMs: the attention
-    projections (the experts' grouped GEMMs are not plain linears)."""
+    projections, and a dense layer's MLP (the experts' grouped GEMMs are
+    not plain linears)."""
+    if kind == "dense":
+        return C.gqa_gemms(m) + C.mlp_gemms(m["hidden_size"], _dense_width(m))
     return C.gqa_gemms(m)

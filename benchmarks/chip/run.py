@@ -108,7 +108,7 @@ def build(cell, seed: int):
 
     mc = spec.model_config(cell)
     rt = spec.runtime_config(cell)
-    params = cell.layout.program_params(cell.model, seed, param_shapes(mc))
+    params = cell.program_params(seed, param_shapes(mc))
     jax.block_until_ready(params)
     llm = LLM(config=mc, runtime=rt, params=params)
     rows = cell.serving["cache_len"]
@@ -256,8 +256,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devs) -> dict:
         "requests": [t.record() for t in tracked],
         "steps": steps,
         "serving": cell.serving,
-        "counts": {"layer_params": cell.reference.layer_params(m),
-                   "kernel_gemms": cell.reference.kernel_gemms(m)},
+        "counts": accounting.layer_counts(cell.reference, m),
         "slice": None, "trace": None,
     }
     if trace_dir is not None:

@@ -1,20 +1,18 @@
-"""Run one cell as ``run.py`` does and also read the engine's own spans
-from its traced slice.
+"""Run one cell as ``run.py`` does and also print the diagnostics of its
+traced slice that the benchmark does not report.
 
     python3 benchmarks/chip/spans.py --workload <cell> --seed <n> \\
-        --seconds <run_seconds> --trace <0|1> [--obs 1]
+        --seconds <run_seconds> [--trace 1] [--obs 1]
 
 Prints ``run.py``'s result line with one more key, ``host_spans`` (with
-``--trace 1``): the median host time per decode step less its blocking
-waits (``host_ms_per_step.chat``), the share of device busy time in ops
-under the program's ``weight_quant`` scope (``requant_share.chat``), the
-device's longest idle gaps labelled by the innermost engine phase around
-them (``idle_gaps``), all of its idle time split by those labels
-(``idle_by_label``), the time and count of each ``engine.*`` span in the
-slice (``phases``), and the slice's heaviest device ops with their HLO
-op names (``ops``).  ``--obs 1`` serves with the program's span
-recording on (``ObsConfig(trace=...)``), to price it.  The benchmark's
-own reduction reads neither spans nor op names yet (PERF.md, section 7).
+``--trace 1``): all of the device's idle time split by the innermost
+engine phase or harness annotation around it (``idle_by_label``), the
+time and count of each ``engine.*`` span in the slice (``phases``), and
+the slice's heaviest device ops with their HLO op names (``ops``).
+``--obs 1`` serves with the program's span recording on
+(``ObsConfig(trace=...)``), to price it.  The per-layer metrics that read
+the same spans and scopes (``host_ms_per_step.chat``,
+``requant_share.chat``) are in the result line's ``metrics``.
 """
 
 from __future__ import annotations
@@ -28,31 +26,24 @@ import tempfile
 from collections import defaultdict
 
 import run
-from chipbench import host_spans, op_scopes, spec, trace_reduce
+from chipbench import host_spans, spec, trace_reduce
 
 TOP_OPS = 16
 EXTRA: dict = {}
 
 
-def reduce_trace(trace_dir: str, chips: int) -> dict:
-    """``trace_reduce.reduce_trace``, reading the engine's spans besides."""
-    path = trace_reduce.find_xplane(trace_dir)
-    host, devices = trace_reduce.read_events(path)
-    out = trace_reduce.reduce_events(host, devices, chips)
-    host = host_spans.read_host(path)
-    hs = host_spans.host_spans(host)
-    scopes = op_scopes.read_scopes(path)
+def reduce_trace(trace_dir: str, chips: int, _reduce=trace_reduce.reduce_trace) -> dict:
+    """``trace_reduce.reduce_trace``, keeping the diagnostics in ``EXTRA``."""
+    out = _reduce(trace_dir, chips)
+    host, devices = trace_reduce.read_events(trace_reduce.find_xplane(trace_dir))
     ops = defaultdict(lambda: [0.0, set()])
     for name, seconds, _ in out["events"]:
         op = ops[trace_reduce._op_key(name)]
         op[0] += seconds
-        op[1].add(scopes.get(name, ""))
+        op[1].add(out["scopes"].get(name, ""))
     EXTRA.update({
-        "host_ms_per_step.chat": host_spans.host_ms_per_step(hs),
-        "requant_share.chat": op_scopes.scope_share(out, scopes, "weight_quant"),
-        "idle_gaps": host_spans.idle_gaps(host, devices),
         "idle_by_label": host_spans.idle_by_label(host, devices),
-        "phases": host_spans.phase_seconds(hs),
+        "phases": host_spans.phase_seconds(out["host_spans"]),
         "ops": sorted(([k, s, sorted(names)[:3]] for k, (s, names) in ops.items()),
                       key=lambda o: -o[1])[:TOP_OPS]})
     return out

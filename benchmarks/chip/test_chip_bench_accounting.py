@@ -65,8 +65,7 @@ def test_tok_s_counts_prefilled_rows_and_tokens_inside_window():
 
 
 def _counts(fam, m):
-    return {"model": m, "counts": {"layer_params": fam.layer_params(m),
-                                   "kernel_gemms": fam.kernel_gemms(m)}}
+    return {"model": m, "counts": acc.layer_counts(fam, m)}
 
 
 def test_layer_parameters_of_nemo_and_granite_by_hand():
@@ -94,6 +93,44 @@ def test_kernel_gemms_are_the_plain_linears_of_a_layer():
     step = {"decode": 2, "oneshot_prompts": [600, 64], "chunks": 1}
     serving = {"n_slots": 32, "prefill_buckets": [64, 512, 1024], "prefill_chunk": 256}
     assert acc.gemm_rows(step, serving) == [32, 32, 1024, 64, 256]
+
+
+@pytest.mark.parametrize("fam, m", [(gqa_dense, NEMO), (gqa_moe, GRANITE)],
+                         ids=["nemo", "granite"])
+def test_sums_over_a_uniform_stack_are_the_products_of_one_layer(fam, m):
+    """Per-layer counts of a stack of one kind add up to one layer's count
+    times the depth, as they were taken before."""
+    rec = dict(_counts(fam, m), peaks=PEAKS, serving={"n_slots": 32, "prefill_buckets": [64, 4096]},
+               steps=[{"t0": 0.0, "t1": 1.0, "decode": 3, "oneshot_prompts": [600], "chunks": 0}])
+    n, p = m["num_hidden_layers"], fam.layer_params(m)
+    assert acc.body_params(rec) == n * (p["attn"] + p["ffn_active"])
+    want = sum(n * sum(acc.gemm_least_time(mm, k, nn, PEAKS) for k, nn in fam.kernel_gemms(m))
+               for mm in (32, 32, 32, 4096))
+    assert acc.gemm_least_seconds(rec, 0.0, 1.0) == pytest.approx(want, rel=1e-12)
+    assert rec["counts"]["attn_dims"] == [m["head_dim"]] * 2
+    for first, last in ((0, 600), (600, 601), (17, 4099)):
+        keys = (last * (last + 1) - first * (first + 1)) / 2.0
+        want = 4.0 * keys * m["num_attention_heads"] * m["head_dim"] * n
+        got = acc.attn_ops(m, first, last, rec["counts"]["attn_dims"])
+        assert got == pytest.approx(want, rel=1e-12)
+
+
+def test_counts_follow_the_kind_of_each_layer():
+    """A leading dense layer counts its MLP; the MoE layers their router
+    and routed experts; the dense layer's MLP GEMMs are plain linears."""
+    m = dict(GRANITE, num_hidden_layers=3, first_k_dense_replace=1,
+             dense_intermediate_size=4096)
+    c = acc.layer_counts(gqa_moe, m)
+    attn = gqa_moe.layer_params(GRANITE)["attn"]
+    assert [p["ffn_active"] for p in c["layer_params"]] == [
+        3 * 1536 * 4096] + [1536 * 40 + 8 * 3 * 1536 * 512] * 2
+    assert acc.body_params({"counts": c}) == 3 * attn + 3 * 1536 * 4096 + 2 * (
+        1536 * 40 + 8 * 3 * 1536 * 512)
+    assert c["kernel_gemms"][0][4:] == [(1536, 4096), (1536, 4096), (4096, 1536)]
+    assert c["kernel_gemms"][1] == c["kernel_gemms"][2] == gqa_moe.kernel_gemms(GRANITE)
+    # attention whose query-key and value widths differ (MLA: 192 and 128)
+    mla = {"num_attention_heads": 16, "head_dim": 128, "num_hidden_layers": 1}
+    assert acc.attn_ops(mla, 0, 1, (192, 128)) == 2 * 1 * (192 + 128) * 16
 
 
 def test_attention_ops_are_causal_sums():

@@ -53,12 +53,13 @@ def test_idle_gaps_name_the_innermost_engine_phase():
     assert hs.label_at(host, 20.5 * MS) == "between_steps"
     assert hs.label_at(host, 19.5 * MS) == "step"
     assert hs.label_at(host, 35 * MS) == "await_arrival"
-    gaps = hs.idle_gaps(host, _device())
+    gaps = [[hs.label_at(host, (a + b) / 2), (b - a) / 1e9]
+            for a, b in hs.idle_intervals(host, _device())]
     # [2, 7) page tables + decode enqueue (mid 4.5), [14, 22) delivery and
     # the harness (mid 18), [27, 40) the sleep (mid 33.5)
-    assert gaps == [["await_arrival", pytest.approx(0.013)],
+    assert gaps == [["engine.page_tables", pytest.approx(0.005)],
                     ["engine.token_deliver", pytest.approx(0.008)],
-                    ["engine.page_tables", pytest.approx(0.005)]]
+                    ["await_arrival", pytest.approx(0.013)]]
     # every idle ms, split where an annotation starts or ends
     by_label = hs.idle_by_label(host, _device())
     assert by_label == {
@@ -129,7 +130,7 @@ def test_op_scopes_read_the_recorded_v5e_trace(tmp_path):
 def test_the_recorded_engine_trace_holds_the_engine_phases():
     """Three engine steps of two decoding lanes inside the slice, each
     followed by a 5-ms sleep."""
-    host = hs.read_host(str(ENGINE_TRACE))
+    host, devices = tr.read_events(str(ENGINE_TRACE))
     spans = hs.host_spans(host)
     steps = [(a, b) for name, a, b in spans["spans"] if name == hs.STEP]
     assert len(steps) == 3
@@ -139,9 +140,13 @@ def test_the_recorded_engine_trace_holds_the_engine_phases():
         assert phases <= {n for n, x, y in spans["spans"] if a <= x and y <= b}
     ms = hs.host_ms_per_step(spans)
     assert 0 < ms < min(b - a for a, b in steps) / 1e6
-    gaps = hs.idle_gaps(host, tr.read_events(str(ENGINE_TRACE))[1])
-    assert [label for label, _ in gaps[:3]] == ["await_arrival"] * 3
-    assert all(0.005 < s < 0.01 for _, s in gaps[:3])
+    gaps = sorted(((b - a) / 1e9, hs.label_at(host, (a + b) / 2))
+                  for a, b in hs.idle_intervals(host, devices))[::-1]
+    assert [label for _, label in gaps[:3]] == ["await_arrival"] * 3
+    assert all(0.005 < s < 0.01 for s, _ in gaps[:3])
+    assert list(hs.idle_by_label(host, devices))[0] == "await_arrival"
+    # the harness's reduction keeps the same spans
+    assert tr.reduce_trace(str(ENGINE_TRACE.parent), 1)["host_spans"] == spans
 
 
 def test_the_recorded_engine_trace_names_the_requantization():

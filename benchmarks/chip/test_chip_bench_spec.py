@@ -24,8 +24,9 @@ def test_cell_finds_its_pieces_by_name(name):
     assert "check" in cell.config and cell.config["check"]["max_logit_gap"] > 0
     for fn in ("layer_specs", "layer", "layer_params", "kernel_gemms"):
         assert callable(getattr(cell.reference, fn)), fn
-    for fn in ("model_config", "program_params"):
+    for fn in ("model_config", "to_program"):
         assert callable(getattr(cell.layout, fn)), fn
+    assert callable(cell.program_params)
     # the deployment's lanes hold the mix's longest request
     serving = cell.serving
     assert serving["n_slots"] >= 1
