@@ -28,6 +28,7 @@ from repro.configs import (
     llama32_1b,
     granite_moe_3b,
     deepseek_moe_16b,
+    deepseek_v2_lite,
     xlstm_125m,
     llava_next_mistral_7b,
     seamless_m4t_large_v2,
@@ -41,6 +42,7 @@ ARCHS = {
     "llama3.2-1b": llama32_1b.CONFIG,
     "granite-moe-3b-a800m": granite_moe_3b.CONFIG,
     "deepseek-moe-16b": deepseek_moe_16b.CONFIG,
+    "deepseek-v2-lite": deepseek_v2_lite.CONFIG,
     "xlstm-125m": xlstm_125m.CONFIG,
     "llava-next-mistral-7b": llava_next_mistral_7b.CONFIG,
     "seamless-m4t-large-v2": seamless_m4t_large_v2.CONFIG,
@@ -100,10 +102,11 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
             capacity_factor=8.0,
             first_k_dense=cfg.moe.first_k_dense,
             d_ff_dense=256 if cfg.moe.d_ff_dense else 0,
+            norm_topk_prob=cfg.moe.norm_topk_prob,
         )
     if cfg.use_mla:
         kw["mla"] = MLAConfig(
-            q_lora_rank=64, kv_lora_rank=32, qk_nope_head_dim=16,
-            qk_rope_head_dim=8, v_head_dim=16,
+            q_lora_rank=64 if cfg.mla.q_lora_rank else None, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
         )
     return dataclasses.replace(cfg, **kw)

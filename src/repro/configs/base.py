@@ -62,15 +62,49 @@ class MoEConfig:
     capacity_factor: float = 1.25
     first_k_dense: int = 0        # leading layers that use a dense FFN
     d_ff_dense: int = 0           # hidden size of those dense FFN layers
+    # renormalise the top-k router weights over the chosen experts
+    # (Mixtral, Granite); DeepSeek-V2 keeps the full softmax's weights
+    norm_topk_prob: bool = True
+    # expert parallelism: this device holds routed experts
+    # [first_held, first_held + n_held) of the router's num_experts and
+    # computes only their part of the layer (0 = every expert)
+    n_held: int = 0
+    first_held: int = 0
+
+    def __post_init__(self):
+        if self.first_held < 0 or self.first_held + self.held > self.num_experts:
+            raise ValueError(
+                f"held experts [{self.first_held}, {self.first_held + self.held}) "
+                f"must lie within the router's {self.num_experts}")
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768     # None: a direct d -> H * qk_dim query
     kv_lora_rank: int = 256
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
     v_head_dim: int = 64
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension of rotary embeddings (DeepSeek-V2's
+    ``rope_scaling``): inverse frequencies blended between interpolated and
+    original over a linear ramp between the dims that ``beta_fast`` and
+    ``beta_slow`` rotations fix, cos/sin scaled by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)`` and the
+    attention scale by ``mscale(factor, mscale_all_dim) ** 2``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,6 +124,7 @@ class ModelConfig:
     # attention
     sliding_window: Optional[int] = None  # for local_attn blocks
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[RopeScaling] = None
     use_mla: bool = False
     mla: Optional[MLAConfig] = None
     # MoE
@@ -160,6 +195,9 @@ class ModelConfig:
                 f"'pallas_interpret', got {self.paged_attn_impl!r}")
         if self.family == "moe" and self.moe is None:
             raise ValueError("moe family requires moe config")
+        if self.rope_scaling is not None and not self.use_mla:
+            raise ValueError("rope_scaling (YaRN) is implemented for latent "
+                             "attention (use_mla) only")
 
     @property
     def resolved_head_dim(self) -> int:
@@ -168,6 +206,12 @@ class ModelConfig:
     @property
     def pattern_period(self) -> int:
         return len(self.block_pattern)
+
+    def uses_mla(self, kind: str) -> bool:
+        """Whether a block of (effective) ``kind`` attends through latent
+        attention: every full-attention block of an MLA model, its MoE
+        and leading dense blocks included (DeepSeek-V2)."""
+        return self.use_mla and kind in ("attn", "mla", "moe", "dense_ffn_layer")
 
     def layer_type(self, i: int) -> str:
         if self.moe is not None and i < self.moe.first_k_dense:

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, init_linear, linear, rmsnorm
+from repro.models.layers import apply_rope, init_linear, linear, rmsnorm, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -43,14 +43,15 @@ def init_attention(key, cfg: ModelConfig):
     }
 
 
-def _attend_chunk(q, k, v, q_offset, kv_offset, causal, window):
-    """q: (B, C, G, Hkv, D); k/v: (B, S, Hkv, D). Exact fp32 softmax."""
+def _attend_chunk(q, k, v, q_offset, kv_offset, causal, window, scale=None):
+    """q: (B, C, G, Hkv, D); k/v: (B, S, Hkv, D). Exact fp32 softmax;
+    scores scaled by ``scale`` (``D ** -0.5`` if None)."""
     d = q.shape[-1]
     # bf16 operands, f32 accumulation: never materializes an f32 copy of
     # the (B, S, H, D) keys (at 32k-decode that copy alone is GiBs/device).
     scores = jnp.einsum("bcghd,bshd->bcghs", q, k,
                         preferred_element_type=jnp.float32)
-    scores = scores * (d ** -0.5)
+    scores = scores * (d ** -0.5 if scale is None else scale)
     qpos = q_offset + jnp.arange(q.shape[1])[:, None]
     kpos = kv_offset + jnp.arange(k.shape[1])[None, :]
     mask = jnp.ones((q.shape[1], k.shape[1]), bool)
@@ -65,10 +66,11 @@ def _attend_chunk(q, k, v, q_offset, kv_offset, causal, window):
     return out.astype(q.dtype)
 
 
-def multihead_attention(q, k, v, *, causal=True, window=None):
+def multihead_attention(q, k, v, *, causal=True, window=None, scale=None):
     """q: (B, Sq, Hq, D), k/v: (B, Skv, Hkv, D) -> (B, Sq, Hq, D).
 
     Query-chunked, memory O(C x Skv); local attention slices the KV band.
+    ``scale`` multiplies the scores (``D ** -0.5`` if None).
     """
     b, sq, hq, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]
@@ -77,7 +79,7 @@ def multihead_attention(q, k, v, *, causal=True, window=None):
     qg = q.reshape(b, sq, g, hkv, d)
     chunk = _pick_chunk(sq)
     if chunk == sq:
-        out = _attend_chunk(qg, k, v, 0, 0, causal, window)
+        out = _attend_chunk(qg, k, v, 0, 0, causal, window, scale)
         return out.reshape(b, sq, hq, dv)
 
     n_chunks = sq // chunk
@@ -97,7 +99,7 @@ def multihead_attention(q, k, v, *, causal=True, window=None):
             start = jnp.minimum(start, skv - band) if skv >= band else 0
             kc = jax.lax.dynamic_slice_in_dim(k, start, min(band, skv), axis=1)
             vc = jax.lax.dynamic_slice_in_dim(v, start, min(band, skv), axis=1)
-            out = _attend_chunk(qc, kc, vc, idx * chunk, start, causal, window)
+            out = _attend_chunk(qc, kc, vc, idx * chunk, start, causal, window, scale)
             return None, out
 
         _, outs = jax.lax.scan(body, None, (qs, jnp.arange(n_chunks)))
@@ -106,7 +108,7 @@ def multihead_attention(q, k, v, *, causal=True, window=None):
         @functools.partial(jax.checkpoint, prevent_cse=False)
         def body(_, args):
             qc, idx = args
-            out = _attend_chunk(qc, k, v, idx * chunk, 0, causal, window)
+            out = _attend_chunk(qc, k, v, idx * chunk, 0, causal, window, scale)
             return None, out
 
         _, outs = jax.lax.scan(body, None, (qs, jnp.arange(n_chunks)))
@@ -580,15 +582,25 @@ def encode_cross_kv(enc_out, p, cfg: ModelConfig):
 # MLA — Multi-head Latent Attention (MiniCPM3 / DeepSeek-V2 style)
 # ---------------------------------------------------------------------------
 
+# HLO scope of decode / verify latent attention (the latent row write, the
+# page gather, the absorbed einsums and the softmax), named for the device
+# trace
+MLA_SCOPE = "mla_attn"
+
+
 def init_mla(key, cfg: ModelConfig):
     m = cfg.mla
     d, h = cfg.d_model, cfg.n_heads
     qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 7)
+    if m.q_lora_rank is None:   # DeepSeek-V2-Lite: a direct query projection
+        q = {"w_q": init_linear(ks[0], d, h * qk_dim)}
+    else:
+        q = {"w_dq": init_linear(ks[0], d, m.q_lora_rank),
+             "q_norm": jnp.ones((m.q_lora_rank,), jnp.float32),
+             "w_uq": init_linear(ks[1], m.q_lora_rank, h * qk_dim)}
     return {
-        "w_dq": init_linear(ks[0], d, m.q_lora_rank),
-        "q_norm": jnp.ones((m.q_lora_rank,), jnp.float32),
-        "w_uq": init_linear(ks[1], m.q_lora_rank, h * qk_dim),
+        **q,
         "w_dkv": init_linear(ks[2], d, m.kv_lora_rank),
         "kv_norm": jnp.ones((m.kv_lora_rank,), jnp.float32),
         "w_uk": init_linear(ks[3], m.kv_lora_rank, h * m.qk_nope_head_dim),
@@ -602,14 +614,27 @@ def _mla_qkv(x, p, cfg, positions):
     m, h = cfg.mla, cfg.n_heads
     b, s, _ = x.shape
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    cq = rmsnorm(linear(x, p["w_dq"], qm, be), p["q_norm"], cfg.norm_eps)
-    q = linear(cq, p["w_uq"], qm, be, tp="col").reshape(b, s, h, -1)
-    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    if m.q_lora_rank is None:
+        q = linear(x, p["w_q"], qm, be, tp="col")
+    else:
+        cq = rmsnorm(linear(x, p["w_dq"], qm, be), p["q_norm"], cfg.norm_eps)
+        q = linear(cq, p["w_uq"], qm, be, tp="col")
+    q_nope, q_rope = jnp.split(q.reshape(b, s, h, -1), [m.qk_nope_head_dim], axis=-1)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     c_kv = rmsnorm(linear(x, p["w_dkv"], qm, be), p["kv_norm"], cfg.norm_eps)
     k_rope = linear(x, p["w_kr"], qm, be).reshape(b, s, 1, m.qk_rope_head_dim)
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)
+    k_rope = apply_rope(k_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope, c_kv, k_rope
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``qk_dim ** -0.5``, times YaRN's ``mscale(factor, mscale_all_dim)``
+    squared where the configuration extends its context (DeepSeek-V2)."""
+    m, rs = cfg.mla, cfg.rope_scaling
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
 
 
 def mla_block(x, p, cfg: ModelConfig, positions):
@@ -622,7 +647,7 @@ def mla_block(x, p, cfg: ModelConfig, positions):
     v = linear(c_kv, p["w_uv"], qm, be, tp="col").reshape(b, s, h, m.v_head_dim)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, s, h, m.qk_rope_head_dim))], axis=-1)
-    out = multihead_attention(q, k, v, causal=True)
+    out = multihead_attention(q, k, v, causal=True, scale=mla_softmax_scale(cfg))
     out = linear(out.reshape(b, s, h * m.v_head_dim), p["wo"], qm, be, tp="row")
     return out, (c_kv, k_rope.reshape(b, s, m.qk_rope_head_dim))
 
@@ -646,7 +671,7 @@ def _mla_attend(q_nope, q_rope, ckv_view, kr_view, pos, p, cfg: ModelConfig):
                         preferred_element_type=jnp.float32)
     scores += jnp.einsum("bchr,bsr->bchs", q_rope.astype(kr_view.dtype), kr_view,
                          preferred_element_type=jnp.float32)
-    scores *= (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scores *= mla_softmax_scale(cfg)
     smax = ckv_view.shape[1]
     c = q_nope.shape[1]
     if c == 1:
@@ -672,14 +697,14 @@ def mla_decode(x_t, p, cfg: ModelConfig, ckv_cache, krope_cache, pos):
     qm, be = cfg.quant_mode, cfg.gemm_backend
     q_nope, q_rope, c_kv_t, k_rope_t = _mla_qkv(x_t, p, cfg, pos[:, None])
 
-    ckv_cache = jax.vmap(
-        lambda c, t, i: jax.lax.dynamic_update_slice_in_dim(c, t, i, axis=0)
-    )(ckv_cache, c_kv_t, pos)
-    krope_cache = jax.vmap(
-        lambda c, t, i: jax.lax.dynamic_update_slice_in_dim(c, t, i, axis=0)
-    )(krope_cache, k_rope_t.reshape(b, 1, m.qk_rope_head_dim), pos)
-
-    out = _mla_attend(q_nope, q_rope, ckv_cache, krope_cache, pos, p, cfg)
+    with jax.named_scope(MLA_SCOPE):
+        ckv_cache = jax.vmap(
+            lambda c, t, i: jax.lax.dynamic_update_slice_in_dim(c, t, i, axis=0)
+        )(ckv_cache, c_kv_t, pos)
+        krope_cache = jax.vmap(
+            lambda c, t, i: jax.lax.dynamic_update_slice_in_dim(c, t, i, axis=0)
+        )(krope_cache, k_rope_t.reshape(b, 1, m.qk_rope_head_dim), pos)
+        out = _mla_attend(q_nope, q_rope, ckv_cache, krope_cache, pos, p, cfg)
     out = out.astype(x_t.dtype)
     return linear(out, p["wo"], qm, be, tp="row"), (ckv_cache, krope_cache)
 
@@ -694,14 +719,14 @@ def mla_verify(x, p, cfg: ModelConfig, ckv_cache, krope_cache, pos):
     positions = pos[:, None] + jnp.arange(w)[None, :]
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(x, p, cfg, positions)
 
-    ckv_cache = jax.vmap(
-        lambda c, t, i: jax.lax.dynamic_update_slice_in_dim(c, t, i, axis=0)
-    )(ckv_cache, c_kv, pos)
-    krope_cache = jax.vmap(
-        lambda c, t, i: jax.lax.dynamic_update_slice_in_dim(c, t, i, axis=0)
-    )(krope_cache, k_rope.reshape(b, w, m.qk_rope_head_dim), pos)
-
-    out = _mla_attend(q_nope, q_rope, ckv_cache, krope_cache, pos, p, cfg)
+    with jax.named_scope(MLA_SCOPE):
+        ckv_cache = jax.vmap(
+            lambda c, t, i: jax.lax.dynamic_update_slice_in_dim(c, t, i, axis=0)
+        )(ckv_cache, c_kv, pos)
+        krope_cache = jax.vmap(
+            lambda c, t, i: jax.lax.dynamic_update_slice_in_dim(c, t, i, axis=0)
+        )(krope_cache, k_rope.reshape(b, w, m.qk_rope_head_dim), pos)
+        out = _mla_attend(q_nope, q_rope, ckv_cache, krope_cache, pos, p, cfg)
     out = out.astype(x.dtype)
     return linear(out, p["wo"], qm, be, tp="row"), (ckv_cache, krope_cache)
 
@@ -720,16 +745,16 @@ def mla_paged_decode(x_t, p, cfg: ModelConfig, cache, pos, tables, *,
     qm, be = cfg.quant_mode, cfg.gemm_backend
     q_nope, q_rope, c_kv_t, k_rope_t = _mla_qkv(x_t, p, cfg, pos[:, None])
 
-    ckvp, krp = cache["ckvp"], cache["krp"]
-    pg, off = _write_page(tables, pos, ckvp.shape[1], active)
-    ckvp = ckvp.at[pg, off].set(c_kv_t[:, 0].astype(ckvp.dtype))
-    krp = krp.at[pg, off].set(
-        k_rope_t.reshape(b, m.qk_rope_head_dim).astype(krp.dtype))
-    new_cache = dict(cache, ckvp=ckvp, krp=krp)
-
-    ckv_view = _gather_pages(ckvp, tables)
-    kr_view = _gather_pages(krp, tables)
-    out = _mla_attend(q_nope, q_rope, ckv_view, kr_view, pos, p, cfg)
+    with jax.named_scope(MLA_SCOPE):
+        ckvp, krp = cache["ckvp"], cache["krp"]
+        pg, off = _write_page(tables, pos, ckvp.shape[1], active)
+        ckvp = ckvp.at[pg, off].set(c_kv_t[:, 0].astype(ckvp.dtype))
+        krp = krp.at[pg, off].set(
+            k_rope_t.reshape(b, m.qk_rope_head_dim).astype(krp.dtype))
+        new_cache = dict(cache, ckvp=ckvp, krp=krp)
+        ckv_view = _gather_pages(ckvp, tables)
+        kr_view = _gather_pages(krp, tables)
+        out = _mla_attend(q_nope, q_rope, ckv_view, kr_view, pos, p, cfg)
     out = out.astype(x_t.dtype)
     return linear(out, p["wo"], qm, be, tp="row"), new_cache
 
@@ -745,16 +770,16 @@ def mla_paged_verify(x, p, cfg: ModelConfig, cache, pos, tables, *,
     positions = pos[:, None] + jnp.arange(w)[None, :]
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(x, p, cfg, positions)
 
-    ckvp, krp = cache["ckvp"], cache["krp"]
-    pg, off = _write_pages(tables, pos, w, ckvp.shape[1], active)
-    ckvp = ckvp.at[pg, off].set(c_kv.astype(ckvp.dtype))
-    krp = krp.at[pg, off].set(
-        k_rope.reshape(b, w, m.qk_rope_head_dim).astype(krp.dtype))
-    new_cache = dict(cache, ckvp=ckvp, krp=krp)
-
-    ckv_view = _gather_pages(ckvp, tables)
-    kr_view = _gather_pages(krp, tables)
-    out = _mla_attend(q_nope, q_rope, ckv_view, kr_view, pos, p, cfg)
+    with jax.named_scope(MLA_SCOPE):
+        ckvp, krp = cache["ckvp"], cache["krp"]
+        pg, off = _write_pages(tables, pos, w, ckvp.shape[1], active)
+        ckvp = ckvp.at[pg, off].set(c_kv.astype(ckvp.dtype))
+        krp = krp.at[pg, off].set(
+            k_rope.reshape(b, w, m.qk_rope_head_dim).astype(krp.dtype))
+        new_cache = dict(cache, ckvp=ckvp, krp=krp)
+        ckv_view = _gather_pages(ckvp, tables)
+        kr_view = _gather_pages(krp, tables)
+        out = _mla_attend(q_nope, q_rope, ckv_view, kr_view, pos, p, cfg)
     out = out.astype(x.dtype)
     return linear(out, p["wo"], qm, be, tp="row"), new_cache
 
@@ -793,6 +818,7 @@ def mla_chunk(x, p, cfg: ModelConfig, cache, tables_row, start, *, positions):
         axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     qg = q.reshape(b, cs, 1, h, q.shape[-1])
-    out = _attend_chunk(qg, k_all, v_all, start[0], 0, True, None)
+    out = _attend_chunk(qg, k_all, v_all, start[0], 0, True, None,
+                        mla_softmax_scale(cfg))
     out = out.astype(x.dtype).reshape(b, cs, h * m.v_head_dim)
     return linear(out, p["wo"], qm, be, tp="row"), new_cache

@@ -5,7 +5,8 @@ scanned period axis) so ``lax.scan`` can thread them through the stack:
 
 * ``attn``       -> {"k","v"}: (B, S_cache, H_kv, D); local_attn uses a
                     ring buffer of S_cache == window (O(1) memory at 500k).
-* ``mla``        -> {"ckv","kr"}: compressed latent cache (the MLA win).
+* ``mla``        -> {"ckv","kr"}: compressed latent cache (the MLA win);
+                    an MLA model's ``moe`` / ``dense_ffn_layer`` too.
 * ``rglru``      -> {"h","conv"}: O(1) recurrent state.
 * ``mlstm``      -> {"C","n"}: matrix memory, O(1) in sequence length.
 * ``slstm``      -> {"c","n","h"}.
@@ -26,7 +27,9 @@ from repro.models.layers import COMPUTE_DTYPE
 
 
 def block_cache_shape(kind: str, cfg: ModelConfig, batch: int, cache_len: int):
-    """ShapeDtypeStructs for one layer's cache of the given kind."""
+    """ShapeDtypeStructs for one layer's cache of the given kind (an MLA
+    model's MoE and dense blocks cache latents, as its ``mla`` blocks do)."""
+    kind = "mla" if cfg.uses_mla(kind) else kind
     hd = cfg.resolved_head_dim
     f32 = jnp.float32
     if kind in ("attn", "local_attn", "moe", "dense_ffn_layer"):
@@ -81,12 +84,14 @@ def paged_block_cache_shape(kind: str, cfg: ModelConfig, batch: int,
     ``kp_scale``/``vp_scale`` planes for the int8 byte-size variant) indexed
     through per-lane block tables.  MLA pages hold the compressed latents
     (``ckvp``/``krp``).  Kinds whose state is already O(1) or O(window) per
-    lane keep their per-lane layout from :func:`block_cache_shape`:
+    lane keep their per-lane layout from :func:`block_cache_shape`; an MLA
+    model's MoE and dense blocks page latents too:
 
     * recurrent state (rglru/mlstm/slstm) — nothing to page;
     * local_attn ring buffers — a window-sized ring is its own best
       packing; paging it would only re-introduce indirection.
     """
+    kind = "mla" if cfg.uses_mla(kind) else kind
     hd = cfg.resolved_head_dim
     if kind in ("attn", "moe", "dense_ffn_layer") or (
         kind == "local_attn" and cfg.sliding_window is None

@@ -12,6 +12,7 @@ kernel's single output write on the Pallas backends.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -171,17 +172,55 @@ def unembed(x, table, quant_mode="bf16"):
 # Rotary embeddings
 # ---------------------------------------------------------------------------
 
-def rope_frequencies(head_dim, theta):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature for a context extended ``factor``-fold."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta=10_000.0):
-    """x: (B, S, H, D) ; positions: (B, S) int32."""
+def _yarn_correction_dim(rotations, head_dim, theta, positions):
+    """The rotary dim that turns ``rotations`` times over ``positions``."""
+    return (head_dim * math.log(positions / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def rope_frequencies(head_dim, theta, scaling=None):
+    """Inverse frequencies of the ``head_dim // 2`` rotary pairs; with YaRN
+    ``scaling`` (``configs.base.RopeScaling``), blended from the original
+    (fast dims) to the ``factor``-interpolated (slow dims) over a linear
+    ramp between the dims that ``beta_fast`` and ``beta_slow`` rotations
+    over the original context fix."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return freqs
+    orig = scaling.original_max_position_embeddings
+    low = max(math.floor(_yarn_correction_dim(scaling.beta_fast, head_dim, theta, orig)), 0)
+    high = min(math.ceil(_yarn_correction_dim(scaling.beta_slow, head_dim, theta, orig)),
+               head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return freqs / scaling.factor * ramp + freqs * (1 - ramp)
+
+
+def rope_attention_factor(scaling=None) -> float:
+    """The factor YaRN scales cos and sin by (1 without scaling)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def apply_rope(x, positions, theta=10_000.0, scaling=None):
+    """x: (B, S, H, D) ; positions: (B, S) int32; ``scaling``: YaRN's
+    ``RopeScaling`` or None (plain rope)."""
     d = x.shape[-1]
-    freqs = rope_frequencies(d, theta)                       # (D/2,)
+    freqs = rope_frequencies(d, theta, scaling)              # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, D/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scaling is not None:
+        mscale = rope_attention_factor(scaling)
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -30,15 +30,22 @@ from repro.models.layers import (
 )
 
 
+# HLO scope of the routed experts (router, dispatch, the held experts'
+# grouped GEMMs with their re-quantization, combine; not the shared
+# experts), named for the device trace
+EXPERTS_SCOPE = "moe_experts"
+
+
 def init_moe(key, cfg: ModelConfig):
+    """The router over every routed expert; weights of the held ones."""
     m = cfg.moe
-    d, e, de = cfg.d_model, m.num_experts, m.d_expert
+    d, e, de = cfg.d_model, m.held, m.d_expert
     ks = jax.random.split(key, 5)
-    import jax.numpy as _jnp
 
     p = {
         # router stays fp32: routing logits are precision-sensitive
-        "router": truncated_normal_init(ks[0], (d, e), scale=0.02, dtype=_jnp.float32),
+        "router": truncated_normal_init(ks[0], (d, m.num_experts), scale=0.02,
+                                        dtype=jnp.float32),
         "experts_gate": truncated_normal_init(ks[1], (e, d, de), scale=0.02),
         "experts_up": truncated_normal_init(ks[2], (e, d, de), scale=0.02),
         "experts_down": truncated_normal_init(ks[3], (e, de, d), scale=0.02),
@@ -105,8 +112,37 @@ def _grouped_glu(x, p, act, quant_mode, backend=None):
     return _grouped_matmul(g * u, p["experts_down"], quant_mode, backend)
 
 
+def _route(x, p, m):
+    """Softmax router over all ``m.num_experts``: (probs (B, S, E), top-k
+    weights and ids (B, S, k)), the weights renormalised over the chosen
+    experts only where ``m.norm_topk_prob`` says so."""
+    logits = jnp.einsum(
+        "bsd,de->bse", x.astype(jnp.float32), p["router"].astype(jnp.float32)
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    topw, topi = jax.lax.top_k(probs, m.top_k)              # (B, S, k)
+    if m.norm_topk_prob:
+        topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
+    return probs, topw, topi
+
+
+def _held_ids(topi, m):
+    """Routed expert ids as this device's expert index: held experts
+    ``[first_held, first_held + held)`` to ``[0, held)``, every other to
+    ``held``, a group no buffer keeps (its part belongs to another
+    device)."""
+    if m.held == m.num_experts:
+        return topi
+    local = topi - m.first_held
+    return jnp.where((local >= 0) & (local < m.held), local, m.held)
+
+
 def moe_ffn(x, p, cfg: ModelConfig):
     """x: (B, S, d) -> (out (B, S, d), aux_loss scalar).
+
+    With ``moe.n_held`` the layer computes its held experts' part only:
+    the router still spans every expert and picks the top-k of all of
+    them; tokens routed elsewhere add nothing here.
 
     Capacity is enforced PER BATCH ROW (local capacity): the sort-based
     dispatch is vmapped over B, so every tensor keeps its leading batch
@@ -118,10 +154,8 @@ def moe_ffn(x, p, cfg: ModelConfig):
     * capacity_factor.
     """
     m = cfg.moe
-    b, s, d = x.shape
-    k = m.top_k
-    e = m.num_experts
-    cap = max(1, math.ceil(s * k / e * m.capacity_factor))
+    s, k = x.shape[1], m.top_k
+    cap = max(1, math.ceil(s * k / m.num_experts * m.capacity_factor))
     if cap > 128:
         # Round capacity up to a 128 multiple: when the expert count does
         # not divide the "model" axis (granite: 40 experts, TP-16), the
@@ -129,12 +163,27 @@ def moe_ffn(x, p, cfg: ModelConfig):
         # divide any model-axis size up to 128 (<=8% padding).
         cap = -(-cap // 128) * 128
 
-    logits = jnp.einsum(
-        "bsd,de->bse", x.astype(jnp.float32), p["router"].astype(jnp.float32)
+    with jax.named_scope(EXPERTS_SCOPE):
+        probs, topi, out = _routed_experts(x, p, cfg, cap)
+    if m.num_shared_experts:
+        out = out + glu_mlp(x, p["shared"], cfg.act, cfg.quant_mode, backend=cfg.gemm_backend)
+
+    # Switch-style load-balance aux loss (global over B*S tokens).
+    dispatch_frac = jnp.mean(
+        jax.nn.one_hot(topi, m.num_experts, dtype=jnp.float32).sum(2), axis=(0, 1)
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    topw, topi = jax.lax.top_k(probs, k)                    # (B, S, k)
-    topw = topw / jnp.sum(topw, axis=-1, keepdims=True)     # renormalize
+    mean_prob = jnp.mean(probs, axis=(0, 1))
+    aux = m.num_experts * jnp.sum(dispatch_frac * mean_prob) / k
+    return out, aux
+
+
+def _routed_experts(x, p, cfg: ModelConfig, cap: int):
+    """Route, dispatch to the held experts' (E_held, C, d) buffers, run
+    their grouped GLU and combine: (probs, top-k ids, out (B, S, d))."""
+    m = cfg.moe
+    b, s, d = x.shape
+    k, e = m.top_k, m.held
+    probs, topw, topi = _route(x, p, m)
 
     def route_row(xrow, topi_row):
         """xrow (S, d), topi_row (S, k) -> (buf (E, C, d), dest, sort_idx)."""
@@ -143,13 +192,13 @@ def moe_ffn(x, p, cfg: ModelConfig):
         sorted_e = flat_e[sort_idx]
         group_start = jnp.searchsorted(sorted_e, sorted_e, side="left")
         pos_in_e = jnp.arange(s * k) - group_start          # rank within expert
-        keep = pos_in_e < cap
+        keep = (pos_in_e < cap) & (sorted_e < e)            # held, in capacity
         dest = jnp.where(keep, sorted_e * cap + pos_in_e, e * cap)  # trash row
         x_sorted = jnp.take(xrow, sort_idx // k, axis=0)    # (S*k, d)
         buf = jnp.zeros((e * cap + 1, d), xrow.dtype).at[dest].set(x_sorted)
         return buf[: e * cap].reshape(e, cap, d), dest, sort_idx
 
-    bufs, dest, sort_idx = jax.vmap(route_row)(x, topi)     # (B, E, C, d), ...
+    bufs, dest, sort_idx = jax.vmap(route_row)(x, _held_ids(topi, m))  # (B, E, C, d), ...
     bufs = _constrain_ep(bufs)                              # B->data, E->model
 
     y = _grouped_glu(bufs, p, cfg.act, cfg.quant_mode, backend=cfg.gemm_backend)      # (B, E, C, d)
@@ -164,17 +213,7 @@ def moe_ffn(x, p, cfg: ModelConfig):
                           topw_row)
 
     out = jax.vmap(combine_row)(y, dest, sort_idx, topw).astype(x.dtype)
-
-    if m.num_shared_experts:
-        out = out + glu_mlp(x, p["shared"], cfg.act, cfg.quant_mode, backend=cfg.gemm_backend)
-
-    # Switch-style load-balance aux loss (global over B*S tokens).
-    dispatch_frac = jnp.mean(
-        jax.nn.one_hot(topi, e, dtype=jnp.float32).sum(2), axis=(0, 1)
-    )
-    mean_prob = jnp.mean(probs, axis=(0, 1))
-    aux = e * jnp.sum(dispatch_frac * mean_prob) / k
-    return out, aux
+    return probs, topi, out
 
 
 def _constrain_ep(bufs):

@@ -32,27 +32,23 @@ def effective_kind(kind: str, cfg: ModelConfig) -> str:
     return kind
 
 
+ATTN_KINDS = ("attn", "local_attn", "mla", "moe", "dense_ffn_layer")
+
+
 def init_block(key, kind: str, cfg: ModelConfig):
     kind = effective_kind(kind, cfg)
     d = cfg.d_model
     k1, k2 = jax.random.split(key)
     p = {"norm1": init_rmsnorm(d)}
-    if kind in ("attn", "local_attn"):
-        p["attn"] = attn.init_attention(k1, cfg)
+    if kind in ATTN_KINDS:
+        p["attn"] = (attn.init_mla(k1, cfg) if cfg.uses_mla(kind)
+                     else attn.init_attention(k1, cfg))
         p["norm2"] = init_rmsnorm(d)
-        p["mlp"] = init_glu_mlp(k2, d, cfg.d_ff)
-    elif kind == "mla":
-        p["attn"] = attn.init_mla(k1, cfg)
-        p["norm2"] = init_rmsnorm(d)
-        p["mlp"] = init_glu_mlp(k2, d, cfg.d_ff)
-    elif kind == "moe":
-        p["attn"] = attn.init_attention(k1, cfg)
-        p["norm2"] = init_rmsnorm(d)
-        p["moe"] = moe_mod.init_moe(k2, cfg)
-    elif kind == "dense_ffn_layer":  # MoE stack's leading dense layer(s)
-        p["attn"] = attn.init_attention(k1, cfg)
-        p["norm2"] = init_rmsnorm(d)
-        p["mlp"] = init_glu_mlp(k2, d, cfg.moe.d_ff_dense or cfg.d_ff)
+        if kind == "moe":
+            p["moe"] = moe_mod.init_moe(k2, cfg)
+        else:   # a MoE stack's leading dense layer(s) take their own width
+            width = cfg.moe.d_ff_dense if kind == "dense_ffn_layer" else 0
+            p["mlp"] = init_glu_mlp(k2, d, width or cfg.d_ff)
     elif kind == "rglru":
         p["cell"] = rec.init_rglru(k1, cfg)
         p["norm2"] = init_rmsnorm(d)
@@ -81,24 +77,20 @@ def apply_block(x, p, kind: str, cfg: ModelConfig, positions, *, causal=True):
     aux = jnp.zeros((), jnp.float32)
     cache_out = None
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
-    if kind in ("attn", "local_attn", "moe", "dense_ffn_layer"):
-        window = cfg.sliding_window if kind == "local_attn" else None
-        a, kv = attn.attention_block(sp_enter(h), p["attn"], cfg, positions,
-                                     causal=causal, window=window)
+    if kind in ATTN_KINDS:
+        if cfg.uses_mla(kind):
+            a, cache_out = attn.mla_block(sp_enter(h), p["attn"], cfg, positions)
+        else:
+            window = cfg.sliding_window if kind == "local_attn" else None
+            a, cache_out = attn.attention_block(sp_enter(h), p["attn"], cfg, positions,
+                                                causal=causal, window=window)
         x = x + sp_exit(a)
-        cache_out = kv
         h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
         if kind == "moe":
             f, aux = moe_mod.moe_ffn(h2, p["moe"], cfg)
         else:
             f = sp_exit(glu_mlp(sp_enter(h2), p["mlp"], cfg.act, cfg.quant_mode, backend=cfg.gemm_backend))
         x = x + f
-    elif kind == "mla":
-        a, ckv = attn.mla_block(sp_enter(h), p["attn"], cfg, positions)
-        x = x + sp_exit(a)
-        cache_out = ckv
-        h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        x = x + sp_exit(glu_mlp(sp_enter(h2), p["mlp"], cfg.act, cfg.quant_mode, backend=cfg.gemm_backend))
     elif kind == "rglru":
         a, state = rec.rglru_block(h, p["cell"], cfg, None)
         x = x + a
@@ -143,8 +135,8 @@ def apply_block_prefill(x, p, kind: str, cfg: ModelConfig, positions, cache_temp
     x, aux, fresh = apply_block(x, p, kind, cfg, positions)
     cache = cache_template
     s = x.shape[1]
-    if kind_e in ("attn", "local_attn", "mla", "moe", "dense_ffn_layer"):
-        if kind_e == "mla":
+    if kind_e in ATTN_KINDS:
+        if cfg.uses_mla(kind_e):
             names, vals = ("ckv", "kr"), fresh
         elif cfg.kv_cache_dtype == "int8":
             # quantize fresh K/V into the byte-size cache (+ scale planes)
@@ -183,12 +175,18 @@ def apply_block_decode(x_t, p, kind: str, cfg: ModelConfig, cache, pos,
     rings) take the same path in both cache modes."""
     kind = effective_kind(kind, cfg)
     h = rmsnorm(x_t, p["norm1"], cfg.norm_eps)
-    if kind in ("attn", "local_attn", "moe", "dense_ffn_layer"):
-        window = cfg.sliding_window if kind == "local_attn" else None
-        if "kp" in cache:
+    if kind in ATTN_KINDS:
+        if "ckvp" in cache:
+            a, cache = attn.mla_paged_decode(h, p["attn"], cfg, cache, pos,
+                                             tables, active=active)
+        elif cfg.uses_mla(kind):
+            a, (ckv, kr) = attn.mla_decode(h, p["attn"], cfg, cache["ckv"], cache["kr"], pos)
+            cache = {**cache, "ckv": ckv, "kr": kr}
+        elif "kp" in cache:
             a, cache = attn.paged_attention_decode(h, p["attn"], cfg, cache, pos,
                                                    tables, active=active)
         else:
+            window = cfg.sliding_window if kind == "local_attn" else None
             a, cache = attn.attention_decode(h, p["attn"], cfg, cache, pos, window=window)
         x_t = x_t + a
         h2 = rmsnorm(x_t, p["norm2"], cfg.norm_eps)
@@ -197,16 +195,6 @@ def apply_block_decode(x_t, p, kind: str, cfg: ModelConfig, cache, pos,
         else:
             f = glu_mlp(h2, p["mlp"], cfg.act, cfg.quant_mode, backend=cfg.gemm_backend)
         x_t = x_t + f
-    elif kind == "mla":
-        if "ckvp" in cache:
-            a, cache = attn.mla_paged_decode(h, p["attn"], cfg, cache, pos,
-                                             tables, active=active)
-        else:
-            a, (ckv, kr) = attn.mla_decode(h, p["attn"], cfg, cache["ckv"], cache["kr"], pos)
-            cache = {**cache, "ckv": ckv, "kr": kr}
-        x_t = x_t + a
-        h2 = rmsnorm(x_t, p["norm2"], cfg.norm_eps)
-        x_t = x_t + glu_mlp(h2, p["mlp"], cfg.act, cfg.quant_mode, backend=cfg.gemm_backend)
     elif kind == "rglru":
         a, state = rec.rglru_decode(h, p["cell"], cfg, cache)
         x_t = x_t + a
@@ -238,22 +226,18 @@ def apply_block_verify(x, p, kind: str, cfg: ModelConfig, cache, pos,
     """
     kind = effective_kind(kind, cfg)
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
-    if kind in ("attn", "dense_ffn_layer"):
-        if "kp" in cache:
+    if kind in ("attn", "mla", "dense_ffn_layer"):
+        if "ckvp" in cache:
+            a, cache = attn.mla_paged_verify(h, p["attn"], cfg, cache, pos,
+                                             tables, active=active)
+        elif cfg.uses_mla(kind):
+            a, (ckv, kr) = attn.mla_verify(h, p["attn"], cfg, cache["ckv"], cache["kr"], pos)
+            cache = {**cache, "ckv": ckv, "kr": kr}
+        elif "kp" in cache:
             a, cache = attn.paged_attention_verify(h, p["attn"], cfg, cache, pos,
                                                    tables, active=active)
         else:
             a, cache = attn.attention_verify(h, p["attn"], cfg, cache, pos)
-        x = x + a
-        h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        x = x + glu_mlp(h2, p["mlp"], cfg.act, cfg.quant_mode, backend=cfg.gemm_backend)
-    elif kind == "mla":
-        if "ckvp" in cache:
-            a, cache = attn.mla_paged_verify(h, p["attn"], cfg, cache, pos,
-                                             tables, active=active)
-        else:
-            a, (ckv, kr) = attn.mla_verify(h, p["attn"], cfg, cache["ckv"], cache["kr"], pos)
-            cache = {**cache, "ckv": ckv, "kr": kr}
         x = x + a
         h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
         x = x + glu_mlp(h2, p["mlp"], cfg.act, cfg.quant_mode, backend=cfg.gemm_backend)

@@ -123,14 +123,10 @@ def _apply_block_chunk(x, p, kind: str, cfg: ModelConfig, cache, table_row,
             x = x + glu_mlp(h2, p["mlp"], cfg.act, cfg.quant_mode,
                             backend=cfg.gemm_backend)
         return x, cache
-    if kind in ("attn", "dense_ffn_layer"):
-        a, cache = attn.attention_chunk(h, p["attn"], cfg, cache, table_row,
-                                        start, positions=positions)
-    elif kind == "mla":
-        a, cache = attn.mla_chunk(h, p["attn"], cfg, cache, table_row,
-                                  start, positions=positions)
-    else:
+    if kind not in CHUNKABLE_KINDS:
         raise ValueError(f"block kind {kind!r} is not chunkable")
+    chunk = attn.mla_chunk if cfg.uses_mla(kind) else attn.attention_chunk
+    a, cache = chunk(h, p["attn"], cfg, cache, table_row, start, positions=positions)
     x = x + a
     h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
     x = x + glu_mlp(h2, p["mlp"], cfg.act, cfg.quant_mode,

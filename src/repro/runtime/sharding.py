@@ -46,7 +46,7 @@ def _div(dim: int, mesh, axis) -> bool:
 # Parameter specs (name-based Megatron rules)
 # ---------------------------------------------------------------------------
 
-_COL = ("wq", "wk", "wv", "w_gate", "w_up", "w_in", "w_uq", "w_uk", "w_uv",
+_COL = ("wq", "wk", "wv", "w_gate", "w_up", "w_in", "w_q", "w_uq", "w_uk", "w_uv",
         "w_gate_branch", "w_x_branch", "w_rec_gate", "w_in_gate", "w_ogate",
         "w_zifo")
 _ROW = ("wo", "w_down", "w_out")

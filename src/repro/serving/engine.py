@@ -128,6 +128,16 @@ def _attn_block_geometry(store: PagedCache):
     return None
 
 
+def _mla_gather_rows(store: PagedCache):
+    """Latent rows one layer's paged MLA decode gathers a step (every
+    lane's whole block table), or None for a stack with no latent pool."""
+    cache = store.cache
+    if any("ckvp" in blk for blk in (*cache["head_blocks"], *cache["blocks"],
+                                       *cache["tail_blocks"])):
+        return store.n_lanes * store.max_pages * store.page_size
+    return None
+
+
 def _roundup(n: int, m: int) -> int:
     return pages_for(n, m) * m
 
@@ -427,6 +437,7 @@ class ServingEngine:
                 _jitted_chunk_step(cfg, self._chunk_len, mesh)
                 if self._chunk_len is not None else None)
             self._attn_blocks = _attn_block_geometry(self.store)
+            self._mla_rows = _mla_gather_rows(self.store)
         else:
             if engine_cfg.prefill_chunk is not None:
                 raise ValueError("chunked prefill requires cache_mode='paged'")
@@ -437,6 +448,7 @@ class ServingEngine:
             self.prefix = None
             self._chunk_len = None
             self._attn_blocks = None
+            self._mla_rows = None
 
         self._admit_fn = (None if self.paged
                           else _jitted_admit(cfg, engine_cfg.cache_len, mesh))
@@ -1164,6 +1176,12 @@ class ServingEngine:
                 self.metrics.max_gauge("peak_pages_used", mgr.pages_in_use)
                 if self._attn_blocks is not None:
                     self._count_attn_blocks(list(running))
+                if self._mla_rows is not None:
+                    # rows each running lane attends: its own and the one
+                    # being written (host lengths, no device sync)
+                    lens = self.store.manager.lengths[list(running)]
+                    self.metrics.inc("mla_rows_live", int(lens.sum()) + len(lens))
+                    self.metrics.inc("mla_rows_gathered", self._mla_rows)
             active = np.zeros((self.engine_cfg.n_slots,), bool)
             active[list(running)] = True
             with tracer.span("decode", lanes=running, batch=len(running)):

@@ -43,6 +43,10 @@ _COUNTERS = (
     # paged-attention kernel blocks per layer, summed over decode steps:
     # the live blocks walked, and lanes x blocks of the whole table
     "attn_blocks_live", "attn_blocks_walked",
+    # paged latent (MLA) rows per layer, summed over decode steps: the rows
+    # the running lanes attend, and the rows the gather builds (every lane's
+    # whole table)
+    "mla_rows_live", "mla_rows_gathered",
     "prefix_hits", "prefix_misses", "prefix_hit_tokens", "prefix_cow_forks",
     "prefix_evicted_pages", "spec_proposed", "spec_accepted",
     "verify_dispatches",
@@ -223,6 +227,11 @@ class EngineMetrics:
             "attn_live_share": round(
                 self.attn_blocks_live / self.attn_blocks_walked, 4)
             if self.attn_blocks_walked else 0.0,
+            "mla_rows_live": self.mla_rows_live,
+            "mla_rows_gathered": self.mla_rows_gathered,
+            "mla_live_share": round(
+                self.mla_rows_live / self.mla_rows_gathered, 4)
+            if self.mla_rows_gathered else 0.0,
             "prefill_s": round(self.prefill_s, 4),
             "decode_s": round(self.decode_s, 4),
             "ttft_mean_s": round(_mean([r.ttft_s for r in reqs]), 4),

@@ -26,12 +26,13 @@ from repro.serving import (
 )
 
 # One representative per cache mechanism:
-#   GQA dense, MLA latents, MoE, mLSTM/sLSTM state, RG-LRU + local ring,
-#   enc-dec cross-attention.
+#   GQA dense, MLA latents, MoE, MLA latents inside MoE blocks with YaRN,
+#   mLSTM/sLSTM state, RG-LRU + local ring, enc-dec cross-attention.
 ARCHS = [
     "llama3.2-1b",
     "minicpm3-4b",
     "granite-moe-3b-a800m",
+    "deepseek-v2-lite",
     "xlstm-125m",
     "recurrentgemma-9b",
     "seamless-m4t-large-v2",
@@ -181,7 +182,7 @@ def test_engine_int8_kv_parity():
 # degenerate paged case: no pools, block tables unused), and the hybrid
 # rglru + local-attn ring (rings stay per-lane inside the paged tree)
 PAGED_ARCHS = ["llama3.2-1b", "minicpm3-4b", "granite-moe-3b-a800m",
-               "xlstm-125m", "recurrentgemma-9b"]
+               "deepseek-v2-lite", "xlstm-125m", "recurrentgemma-9b"]
 RECURRENT_ARCHS = {"xlstm-125m", "recurrentgemma-9b"}
 
 
