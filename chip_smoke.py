@@ -115,7 +115,7 @@ def kernel_phase(seed: int) -> None:
 
     from repro.configs import get_config
     from repro.core.spoga import direct_matmul
-    from repro.kernels.paged_attention import pages_per_block
+    from repro.kernels.paged_attention import page_bytes, pages_per_block
     from repro.kernels.spoga_gemm_dequant import spoga_gemm_dequant
 
     cfg = get_config(LLAMA)
@@ -150,7 +150,10 @@ def kernel_phase(seed: int) -> None:
     # either side of a block boundary, inactive and full lanes
     lanes, n_tbl = 32, 257
     for geometry in ((8, 4, 128), (8, 3, 64)):      # mistral-nemo, granite-3.0
-        rows = ps * pages_per_block(ps, geometry[0], geometry[2], jnp.int8, n_tbl)
+        hkv, _, hd = geometry
+        pools = [jax.ShapeDtypeStruct((1, ps, hkv * hd), jnp.int8)] * 2 + [
+            jax.ShapeDtypeStruct((1, 2, hkv * ps), jnp.float32)]
+        rows = ps * pages_per_block(ps, page_bytes(pools), n_tbl)
         edges = [1, rows - 1, rows, rows + 1, n_tbl * ps]
         lengths = np.concatenate([edges, rng.choice(
             [1, 1, 600, 1065, 2100], lanes - len(edges))])
@@ -158,8 +161,9 @@ def kernel_phase(seed: int) -> None:
 
 
 def check_paged_attention(rng, kind, lanes, geometry, n_tbl, lengths) -> None:
-    """``paged_attention`` on random pages against its reference: tables
-    of distinct random pages (page 0 is the trash page), ``lengths`` rows."""
+    """``paged_attention`` on random pages of the last of two stacked
+    layers against its reference: tables of distinct random pages (page 0
+    is the trash page), ``lengths`` rows."""
     import jax
     import jax.numpy as jnp
 
@@ -171,22 +175,22 @@ def check_paged_attention(rng, kind, lanes, geometry, n_tbl, lengths) -> None:
                          .reshape(lanes, n_tbl), jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     q = jnp.asarray(rng.normal(size=(lanes, hkv, g, hd)), jnp.bfloat16)
-    pool = (n_pages, ps, hkv, hd)
+    pool = (2, n_pages, ps, hkv * hd)
     if kind == "int8":
         kp = jnp.asarray(rng.integers(-127, 128, pool), jnp.int8)
         vp = jnp.asarray(rng.integers(-127, 128, pool), jnp.int8)
-        scales = dict(
-            k_scale=jnp.asarray(rng.uniform(5e-3, 2e-2, pool[:3]), jnp.float32),
-            v_scale=jnp.asarray(rng.uniform(5e-3, 2e-2, pool[:3]), jnp.float32))
+        scales = dict(scales=jnp.asarray(
+            rng.uniform(5e-3, 2e-2, (2, n_pages, 2, hkv * ps)), jnp.float32))
     else:
         kp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
         vp = jnp.asarray(rng.normal(size=pool), jnp.bfloat16)
         scales = {}
     with compile_clock() as c:
-        got = paged_attention(q, kp, vp, tables, lengths, **scales)
+        got = paged_attention(q, kp, vp, tables, lengths, jnp.int32(1), **scales)
         got.block_until_ready()
+    last = {k: v[1:] for k, v in scales.items()}
     with jax.default_matmul_precision("highest"):
-        want = paged_attention_ref(q, kp, vp, tables, lengths, **scales)
+        want = paged_attention_ref(q, kp[1:], vp[1:], tables, lengths, 0, **last)
     got, want = np.asarray(got), np.asarray(want)
     assert np.isfinite(got).all(), f"paged_attention {kind}: non-finite"
     err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))

@@ -1,13 +1,14 @@
 """Paged decode attention over block tables — Pallas TPU kernel + jnp twin.
 
-The serving engine's paged KV cache (repro/paging/) stores K/V in a global
-page pool ``(n_pages, page_size, H_kv, D)`` shared by every lane; a lane's
-logical sequence is the concatenation of the physical pages its block
-table names.  The kernel streams those pages straight from the pool into
-VMEM without ever materializing the gathered (B, S, H, D) view that the
-jnp twin builds.  A flash-style running softmax (per-lane max /
-denominator / value accumulator in VMEM scratch) folds them into the
-output in one pass.
+The serving engine's paged KV cache (repro/paging/) stores K/V in global
+page pools shared by every lane, one pool per layer stacked along a
+leading layer axis; a lane's logical sequence is the concatenation of the
+physical pages its block table names.  The kernel reads layer ``layer``'s
+pages straight from the stacked pools into VMEM, never materializing the
+layer's pool or the gathered (B, S, H, D) view that the jnp twin builds,
+so the decode layer scan can carry the stacked pools in place.  A
+flash-style running softmax (per-lane max / denominator / value
+accumulator in VMEM scratch) folds the pages into the output in one pass.
 
 The grid walks only live blocks.  A block is ``pages_per_block`` whole
 pages of all KV heads (``ppb * page_size`` rows); lane ``b`` needs
@@ -16,32 +17,41 @@ lane (length 1) costs one block and blocks past a lane's length are never
 visited.  The grid is one step per live (lane, block) pair, its length
 a traced value (the sum of the lanes' live blocks), and each step's pages
 arrive as ``ppb`` page-sized BlockSpecs per pool array whose index maps
-read ``tables[b, j]`` from the prefetched scalars: the pipeline fetches
-the next step's pages, the next lane's first block included, while the
-current block is computed.  A ragged last block of the table clamps its
-page indices; every block masks rows by ``kpos < length``.  The block size
-is derived from the shapes (:func:`pages_per_block`), not passed in.  The
-heads are unrolled in the body, each with its own flash state, which is
-set at a lane's first block and emitted at its last.
+read ``(layer, tables[b, j])`` from the prefetched scalars: the pipeline
+fetches the next step's pages, the next lane's first block included, while
+the current block is computed.  A ragged last block of the table clamps
+its page indices; every block masks rows by ``kpos < length``.  The block
+size is derived from the shapes (:func:`pages_per_block`), not passed in.
+The heads are unrolled in the body, each with its own flash state, which
+is set at a lane's first block and emitted at its last.
+
+Storage is lane-dense, so that the device's default layout of every pool
+is row-major and neither the kernel nor the layer scan pays a relayout: a
+page holds its rows with all heads side by side (``H_kv * D`` lanes), and
+the int8 variant's scale pool holds a page's K scales and V scales as two
+rows, each head-major (``H_kv * page_size`` lanes).  With ``D`` or ``H_kv``
+narrower than a lane row, the natural ``(..., H_kv, D)`` and
+``(..., page_size, H_kv)`` shapes get a pages-minor default layout
+instead.  The kernel takes each head's rows from the lane axis.
 
 (Manual async copies from a ``pl.ANY`` pool cannot express this: Mosaic
-refuses a page slice of a pool whose minor dims are not 128-aligned —
-D = 64, and the ``(page_size, H_kv)`` scale planes — while BlockSpec
-windows of the same pages compile.)
+refuses a page slice of a pool whose minor dims are not 128-aligned, while
+BlockSpec windows of the same pages compile.)
 
 The int8 byte-size variant fuses page dequantization: int8 payloads are
-read from the pool and the per-(position, head) scales multiply the K / V
-rows in VMEM before the dot products — the paper's byte-size operand
-stream applied to decode's dominant HBM traffic, in the same shape as
-``spoga_gemm_dequant`` fuses the epilogue.
+read from the pool and the per-(position, head) scales are applied on the
+score and probability side, ``(q.k) * ks`` and ``(p * vs) @ v`` — the
+paper's byte-size operand stream applied to decode's dominant HBM
+traffic, in the same shape as ``spoga_gemm_dequant`` fuses the epilogue.
 
-Layouts (G = query heads per KV head):
+Layouts (G = query heads per KV head, L = stacked layers):
 
     q        (B, H_kv, G, D)        bf16/f32
-    kp, vp   (n_pages, page_size, H_kv, D)   bf16 | int8
-    k_scale, v_scale  (n_pages, page_size, H_kv) f32 (int8 variant)
+    kp, vp   (L, n_pages, page_size, H_kv * D)       bf16 | int8
+    scales   (L, n_pages, 2, H_kv * page_size) f32 (int8 variant: K, V)
     tables   (B, P) int32 physical page ids
     lengths  (B,)   int32 valid rows per lane (pos + 1 at decode), >= 1
+    layer    ()     int32 the layer of the stacked pools to attend
     out      (B, H_kv, G, D) f32
 
 Off-TPU the kernel runs only through the Pallas interpreter
@@ -53,6 +63,7 @@ Off-TPU the kernel runs only through the Pallas interpreter
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -70,14 +81,18 @@ BLOCK_ROWS = 128
 BLOCK_VMEM_BYTES = 8 << 20
 
 
-def pages_per_block(page_size: int, n_kv_heads: int, head_dim: int,
-                    dtype, n_tbl: int) -> int:
-    """Pages per block for a pool of this geometry: ``BLOCK_ROWS`` rows of
-    whole pages, fewer where two buffers of the K and V blocks would pass
-    ``BLOCK_VMEM_BYTES``, never more than the table holds."""
-    dtype = jnp.dtype(dtype)
-    row = n_kv_heads * (head_dim * dtype.itemsize + 4 * (dtype == jnp.int8))
-    fit = BLOCK_VMEM_BYTES // (2 * 2 * page_size * row)
+def page_bytes(pools) -> int:
+    """Bytes of one page across ``pools`` (K, V and, int8, the scales):
+    a page is the two minor dims of every pool layout above."""
+    return sum(math.prod(p.shape[-2:]) * jnp.dtype(p.dtype).itemsize
+               for p in pools)
+
+
+def pages_per_block(page_size: int, nbytes: int, n_tbl: int) -> int:
+    """Pages per block for pools of ``nbytes`` a page (:func:`page_bytes`):
+    ``BLOCK_ROWS`` rows of whole pages, fewer where two buffers of a block
+    would pass ``BLOCK_VMEM_BYTES``, never more than the table holds."""
+    fit = BLOCK_VMEM_BYTES // (2 * nbytes)
     return max(1, min(BLOCK_ROWS // page_size, fit, n_tbl))
 
 
@@ -85,16 +100,17 @@ def _live_blocks(lengths, rows: int, n_blocks: int):
     return jnp.clip(pl.cdiv(lengths, rows), 1, n_blocks)
 
 
-def _kernel(lane_ref, blk_ref, tables_ref, lengths_ref, q_ref, *refs,
-            page_size: int, ppb: int, n_blocks: int, int8: bool):
+def _kernel(layer_ref, lane_ref, blk_ref, tables_ref, lengths_ref, q_ref,
+            *refs, page_size: int, ppb: int, n_blocks: int, int8: bool):
     """One grid step = one live block of one lane, for all its KV heads.
 
-    ``refs`` holds ``ppb`` page blocks ``(1, page_size, H_kv, D)`` of each
-    pool array (K, V, and in the int8 variant K and V scales), then the
-    output and the flash scratch.  A block's last two dims are the pool's
-    own (Mosaic refuses a size-1 block on ``H_kv``).
+    ``refs`` holds ``ppb`` page blocks ``(page_size, H_kv * D)`` of K and V
+    (and in the int8 variant ``(2, H_kv * page_size)`` of their scales),
+    then the output and the flash scratch.  A block's last two dims are the
+    pool's own.
     """
-    n_in = 4 if int8 else 2
+    del layer_ref  # read by the index maps only
+    n_in = 3 if int8 else 2
     pages = [refs[a * ppb:(a + 1) * ppb] for a in range(n_in)]
     o_ref, m_ref, l_ref, acc_ref = refs[n_in * ppb:]
     step = pl.program_id(0)
@@ -109,25 +125,29 @@ def _kernel(lane_ref, blk_ref, tables_ref, lengths_ref, q_ref, *refs,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def gather(a, h):
-        """Rows of head ``h`` of pool array ``a`` across the block's pages."""
-        out = []
-        for p in range(ppb):
-            x = pages[a][p][0, :, h, :].astype(jnp.float32)   # (page_size, D)
-            if int8:
-                # fused dequant: per-row scales ride the page rows, so
-                # q.(k*ks) == (q.k)*ks and p@(v*vs) == (p*vs)@v
-                x = x * pages[a + 2][p][0, :, h:h + 1]
-            out.append(x)
-        return jnp.concatenate(out, axis=0)                  # (rows, D)
+    def head_rows(a, h):
+        """Rows of head ``h`` of pool ``a`` across the block's pages."""
+        return jnp.concatenate(
+            [pages[a][p][:, h * d:(h + 1) * d].astype(jnp.float32)
+             for p in range(ppb)], axis=0)                  # (rows, D)
+
+    def head_scales(a, h):
+        """Scales of head ``h`` of K (``a`` 0) or V (1) across the block's
+        rows."""
+        return jnp.concatenate(
+            [pages[2][p][a:a + 1, h * page_size:(h + 1) * page_size]
+             for p in range(ppb)], axis=1)                  # (1, rows)
 
     kpos = blk * rows + jax.lax.broadcasted_iota(jnp.int32, (g, rows), 1)
     for h in range(n_heads):
         q = q_ref[0, h].astype(jnp.float32)                # (G, D)
-        k, v = gather(0, h), gather(1, h)
+        k, v = head_rows(0, h), head_rows(1, h)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
         ) * (d ** -0.5)                                    # (G, rows)
+        if int8:
+            # fused dequant: per-row scales factor out of the D-contraction
+            s = s * head_scales(0, h)
         s = jnp.where(kpos < length, s, NEG_INF)
 
         # flash update: m/l scratches are (G, 128) lane-replicated scalars
@@ -137,8 +157,9 @@ def _kernel(lane_ref, blk_ref, tables_ref, lengths_ref, q_ref, *refs,
         pexp = jnp.exp(s - m_new[:, :1])                   # (G, rows)
         l_ref[h] = alpha * l_prev + jnp.sum(pexp, axis=1, keepdims=True)
         m_ref[h] = m_new
+        pw = pexp * head_scales(1, h) if int8 else pexp
         pv = jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((), ())),
+            pw, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         acc_ref[h] = acc_ref[h] * alpha[:, :1] + pv
 
@@ -149,18 +170,17 @@ def _kernel(lane_ref, blk_ref, tables_ref, lengths_ref, q_ref, *refs,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_attention(q, kp, vp, tables, lengths, *, k_scale=None,
-                    v_scale=None, interpret: bool = False):
-    """Flash decode attention over paged KV. See module docstring for
-    layouts. ``k_scale``/``v_scale`` select the fused-int8-dequant variant;
-    both or neither must be given."""
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError("int8 paged attention needs both k_scale and v_scale")
+def paged_attention(q, kp, vp, tables, lengths, layer, *, scales=None,
+                    interpret: bool = False):
+    """Flash decode attention over layer ``layer`` of stacked paged KV. See
+    module docstring for layouts. ``scales`` selects the fused-int8-dequant
+    variant."""
     b, hkv, g, d = q.shape
-    page_size = kp.shape[1]
+    page_size = kp.shape[2]
     n_tbl = tables.shape[1]
-    int8 = k_scale is not None
-    ppb = pages_per_block(page_size, hkv, d, kp.dtype, n_tbl)
+    int8 = scales is not None
+    pools = [kp, vp] + ([scales] if int8 else [])
+    ppb = pages_per_block(page_size, page_bytes(pools), n_tbl)
     n_blocks = pl.cdiv(n_tbl, ppb)
     lengths = lengths.astype(jnp.int32)
 
@@ -173,21 +193,20 @@ def paged_attention(q, kp, vp, tables, lengths, *, k_scale=None,
                                dtype=jnp.int32), b - 1)
     blk = steps - (ends - live)[lane]
 
-    def q_idx(i, lane, blk, tbl, ln):
+    def q_idx(i, layer, lane, blk, tbl, ln):
         return (lane[i], 0, 0, 0)
 
     def page_spec(p, block):
-        def idx(i, lane, blk, tbl, ln):
+        def idx(i, layer, lane, blk, tbl, ln):
             j = jnp.minimum(blk[i] * ppb + p, n_tbl - 1)
-            return (tbl[lane[i] * n_tbl + j],) + (0,) * (len(block) - 1)
-        return pl.BlockSpec(block, idx)
+            return (layer[0], tbl[lane[i] * n_tbl + j], 0, 0)
+        return pl.BlockSpec((None, None) + block, idx)
 
-    pools = [kp, vp] + ([k_scale, v_scale] if int8 else [])
     in_specs = [pl.BlockSpec((1, hkv, g, d), q_idx)]
     for pool in pools:
-        in_specs += [page_spec(p, (1,) + pool.shape[1:]) for p in range(ppb)]
+        in_specs += [page_spec(p, pool.shape[2:]) for p in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(ends[-1],),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, hkv, g, d), q_idx),
@@ -205,30 +224,46 @@ def paged_attention(q, kp, vp, tables, lengths, *, k_scale=None,
         # a lane's blocks run in order: its flash state carries across them
         compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lane, blk, tables.astype(jnp.int32).reshape(-1), lengths, q,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lane, blk,
+      tables.astype(jnp.int32).reshape(-1), lengths, q,
       *[pool for pool in pools for _ in range(ppb)])
 
 
-def paged_attention_ref(q, kp, vp, tables, lengths, *, k_scale=None,
-                        v_scale=None):
+def _gather_rows(pool, layer, tables, head_dim):
+    """(L, n_pages, page_size, H * D) pool -> (B, P * page_size, H, D): the
+    logically-contiguous per-lane rows of ``layer`` in one gather."""
+    b, n_tbl = tables.shape
+    g = pool[layer, tables]                        # (B, P, page_size, H * D)
+    return g.reshape(b, n_tbl * pool.shape[2], -1, head_dim)
+
+
+def _gather_scales(pool, layer, tables, page_size):
+    """(L, n_pages, 2, H * page_size) scales -> K and V scales, each
+    (B, H, P * page_size)."""
+    b, n_tbl = tables.shape
+    g = pool[layer, tables].reshape(b, n_tbl, 2, -1, page_size)  # (B,P,2,H,ps)
+    g = g.transpose(2, 0, 3, 1, 4).reshape(2, b, g.shape[3], n_tbl * page_size)
+    return g[0], g[1]
+
+
+def paged_attention_ref(q, kp, vp, tables, lengths, layer, *, scales=None):
     """jnp gather twin (exact softmax) — the reference the kernel is tested
-    against, and the lowering the engine uses off-TPU."""
+    against."""
     b, hkv, g, d = q.shape
-    page_size = kp.shape[1]
+    page_size = kp.shape[2]
     smax = tables.shape[1] * page_size
 
-    def gather(pool):
-        return pool[tables].reshape((b, smax) + pool.shape[2:])
-
-    k_all, v_all = gather(kp), gather(vp)
+    k_all = _gather_rows(kp, layer, tables, d)
+    v_all = _gather_rows(vp, layer, tables, d)
     qf = q.astype(jnp.float32)
     scores = jnp.einsum("bhgd,bshd->bhgs", qf, k_all.astype(jnp.float32))
     scores = scores * (d ** -0.5)
-    if k_scale is not None:
-        scores = scores * gather(k_scale).transpose(0, 2, 1)[:, :, None, :]
+    if scales is not None:
+        ks, vs = _gather_scales(scales, layer, tables, page_size)
+        scores = scores * ks[:, :, None, :]
     valid = jnp.arange(smax)[None, :] < lengths[:, None]
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    if v_scale is not None:
-        probs = probs * gather(v_scale).transpose(0, 2, 1)[:, :, None, :]
+    if scales is not None:
+        probs = probs * vs[:, :, None, :]
     return jnp.einsum("bhgs,bshd->bhgd", probs, v_all.astype(jnp.float32))

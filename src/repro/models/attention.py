@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models.kvcache import from_pages, page_size_of
 from repro.models.layers import apply_rope, init_linear, linear, rmsnorm, yarn_mscale
 
 NEG_INF = -1e30
@@ -360,68 +361,121 @@ def _write_page(tables, pos, page_size, active):
     return pg, off
 
 
-def _gather_pages(pool, tables):
-    """(n_pages, page_size, ...) pool + (B, P) tables -> (B, P*page_size, ...)
-    logically-contiguous per-lane view (gather; the Pallas kernel instead
-    streams pages directly from the pool)."""
-    b, n_tbl = tables.shape
-    g = pool[tables]
-    return g.reshape((b, n_tbl * pool.shape[1]) + pool.shape[2:])
+def _write_rows(cache, pg, off, rows, *, pages_distinct=False):
+    """Write new rows into layer ``cache["layer"]`` of the stacked pools.
+
+    ``rows`` maps pool keys to slot-layout rows ``(N, *row)``
+    (``kvcache.pool_rows``); row ``n`` lands at page ``pg[n]``, offset
+    ``off[n]`` (layouts in ``models/kvcache.paged_block_cache_shape``).
+    Returns the written pool leaves.  K/V and latent rows are one window
+    each.  A page of scales or rope keys holds a row's values in its lane
+    rows, apart (scales: head ``h`` at lane ``h * page_size + off``) or
+    together (rope keys: at ``off * rope_dim``).  Where no two rows share a
+    page (``pages_distinct``: one decode row per lane, and idle lanes'
+    garbage rows all on the trash page) each page's lane rows are read,
+    patched and written back whole, one window a row: around a scatter of
+    single values the compiler relays the whole pool out and back.
+    """
+    out, layer = {}, cache["layer"]
+    for key, r in rows.items():
+        pool = cache[key]
+        if key in ("kp", "vp", "ckvp"):
+            out[key] = pool.at[layer, pg, off].set(
+                r.reshape(r.shape[0], -1).astype(pool.dtype))
+            continue
+        k, w = r.shape[-1], pool.shape[-1]
+        if key == "krp":
+            lanes = off[:, None] * k + jnp.arange(k)           # (N, k)
+        else:
+            lanes = off[:, None] + (w // k) * jnp.arange(k)
+        if not pages_distinct:
+            idx = ((layer, pg[:, None], lanes) if key == "krp" else
+                   (layer, pg[:, None, None], jnp.arange(2)[:, None], lanes[:, None]))
+            out[key] = pool.at[idx].set(r.astype(pool.dtype))
+            continue
+        lane = jnp.arange(w)
+        if key == "krp":
+            hit, full = lane // k == off[:, None], jnp.tile(r, (1, w // k))
+        else:
+            hit = (lane % (w // k) == off[:, None])[:, None]
+            full = jnp.repeat(r, w // k, axis=-1)
+        out[key] = pool.at[layer, pg].set(
+            jnp.where(hit, full.astype(pool.dtype), pool[layer, pg]))
+    return out
+
+
+def _gather_pages(pool, key, layer, tables, page_size):
+    """Layer ``layer`` of stacked pool ``key`` through (B, P) block tables:
+    the (B, P * page_size, w) logically-contiguous per-lane rows in one
+    gather (the Pallas kernel instead streams pages straight from the
+    pool)."""
+    return from_pages(key, pool[layer, tables], page_size)
+
+
+def _kv_rows(cache, tables, page_size, head_dim):
+    """The gathered (B, S, H, D) K/V views and (B, S, H) K and V scales
+    (None for a bf16 pool) of layer ``cache["layer"]``."""
+    b, smax, layer = tables.shape[0], tables.shape[1] * page_size, cache["layer"]
+    k_all, v_all = (_gather_pages(cache[key], key, layer, tables, page_size)
+                    .reshape(b, smax, -1, head_dim) for key in ("kp", "vp"))
+    if "kvp_scale" not in cache:
+        return k_all, v_all, None, None
+    scales = _gather_pages(cache["kvp_scale"], "kvp_scale", layer, tables,
+                           page_size)                      # (B, S, 2, H)
+    return k_all, v_all, scales[:, :, 0], scales[:, :, 1]
+
+
+def _kv_new_rows(k, v, int8_cache):
+    """Slot-layout rows (..., H, D) of fresh K/V as pool rows, quantized
+    into the int8 payload + scale rows for a byte-size pool."""
+    if not int8_cache:
+        return {"kp": k, "vp": v}
+    kq, ks = quantize_kv(k)
+    vq, vs = quantize_kv(v)
+    return {"kp": kq, "vp": vq, "kvp_scale": jnp.stack([ks, vs], axis=-2)}
 
 
 def paged_attention_decode(x_t, p, cfg: ModelConfig, cache, pos, tables, *,
                            active=None):
-    """One-token decode over this layer's page pools.
+    """One-token decode over one layer of the stacked page pools.
 
-    cache: {"kp","vp"[,"kp_scale","vp_scale"]} with payloads
-    (n_pages, page_size, Hkv, D); tables: (B, P) int32 block tables;
-    pos: (B,).  The new token's K/V is scattered into page
-    ``tables[b, pos // page_size]`` and attention runs over the gathered
-    logical view (jnp twin) or streams pages inside the Pallas kernel.
+    cache: a layer's view of the pools (``transformer.on_layer``):
+    {"kp","vp"[,"kvp_scale"]} with a leading layer axis (layouts in
+    ``models/kvcache.paged_block_cache_shape``) and "layer", the layer's
+    index into them; tables: (B, P) int32 block tables; pos: (B,).  The
+    new token's K/V is scattered into page ``tables[b, pos // page_size]``
+    of the layer, in place, and attention runs over the gathered logical
+    view (jnp twin) or streams the layer's pages inside the Pallas kernel.
     With ``P * page_size == cache_len`` the jnp twin is bitwise identical
     to :func:`attention_decode` on a slot cache.
     """
     b = x_t.shape[0]
     hd, hq, hkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    int8_cache = "kp_scale" in cache
     q, k, v = _decode_qkv(x_t, p, cfg, pos)
 
-    kp, vp = cache["kp"], cache["vp"]
-    page_size = kp.shape[1]
+    page_size = page_size_of(cache)
     pg, off = _write_page(tables, pos, page_size, active)
-
-    new_cache = dict(cache)
-    if int8_cache:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        kp, vp = kp.at[pg, off].set(kq[:, 0]), vp.at[pg, off].set(vq[:, 0])
-        kps = cache["kp_scale"].at[pg, off].set(ks[:, 0])
-        vps = cache["vp_scale"].at[pg, off].set(vs[:, 0])
-        new_cache.update(kp_scale=kps, vp_scale=vps)
-    else:
-        kp = kp.at[pg, off].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[pg, off].set(v[:, 0].astype(vp.dtype))
-    new_cache.update(kp=kp, vp=vp)
+    rows = _kv_new_rows(k[:, 0], v[:, 0], "kvp_scale" in cache)
+    new_cache = dict(cache, **_write_rows(cache, pg, off, rows,
+                                          pages_distinct=True))
 
     g = hq // hkv
     impl = _resolve_paged_impl(cfg)
     if impl == "jnp":
         qg = q.reshape(b, 1, g, hkv, hd)
         smax = tables.shape[1] * page_size
-        k_all, v_all = _gather_pages(kp, tables), _gather_pages(vp, tables)
-        ks_all = _gather_pages(kps, tables) if int8_cache else None
-        vs_all = _gather_pages(vps, tables) if int8_cache else None
         valid = jnp.arange(smax)[None, :] <= pos[:, None]
-        out = _decode_attend(qg, k_all, v_all, ks_all, vs_all, valid)
+        out = _decode_attend(qg, *_kv_rows(new_cache, tables, page_size, hd),
+                             valid)
     else:
         from repro.shard.kernels import sharded_paged_attention
 
         qk = q[:, 0].reshape(b, g, hkv, hd).transpose(0, 2, 1, 3)  # (B,Hkv,G,D)
         out = sharded_paged_attention(
-            qk, kp, vp, tables, pos + 1,
-            k_scale=new_cache.get("kp_scale"),
-            v_scale=new_cache.get("vp_scale"),
+            qk, new_cache["kp"], new_cache["vp"], tables, pos + 1,
+            cache["layer"],
+            scales=new_cache.get("kvp_scale"),
             interpret=(impl == "pallas_interpret"),
         )
         out = out.transpose(0, 2, 1, 3)[:, None]  # (B, 1, G, Hkv, D)
@@ -442,9 +496,15 @@ def _write_pages(tables, pos, w, page_size, active):
     return pg, off
 
 
+def _flat_rows(rows):
+    """(B, W, *row) rows as (B * W, *row)."""
+    return {k: r.reshape((-1,) + r.shape[2:]) for k, r in rows.items()}
+
+
 def paged_attention_verify(x, p, cfg: ModelConfig, cache, pos, tables, *,
                            active=None):
-    """W-token speculative verify over this layer's page pools.
+    """W-token speculative verify over one layer of the page pools (cache:
+    a layer's view, as :func:`paged_attention_decode` takes).
 
     The paged twin of :func:`attention_verify`: scatters the window's W
     K/V rows through the block table (engine capacity checks reserve the
@@ -456,34 +516,17 @@ def paged_attention_verify(x, p, cfg: ModelConfig, cache, pos, tables, *,
     b, w, _ = x.shape
     hd, hq, hkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    int8_cache = "kp_scale" in cache
     q, k, v = _verify_qkv(x, p, cfg, pos)
 
-    kp, vp = cache["kp"], cache["vp"]
-    page_size = kp.shape[1]
+    page_size = page_size_of(cache)
     pg, off = _write_pages(tables, pos, w, page_size, active)
-
-    new_cache = dict(cache)
-    kps = vps = None
-    if int8_cache:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        kp, vp = kp.at[pg, off].set(kq), vp.at[pg, off].set(vq)
-        kps = cache["kp_scale"].at[pg, off].set(ks)
-        vps = cache["vp_scale"].at[pg, off].set(vs)
-        new_cache.update(kp_scale=kps, vp_scale=vps)
-    else:
-        kp = kp.at[pg, off].set(k.astype(kp.dtype))
-        vp = vp.at[pg, off].set(v.astype(vp.dtype))
-    new_cache.update(kp=kp, vp=vp)
+    rows = _kv_new_rows(k, v, "kvp_scale" in cache)
+    new_cache = dict(cache, **_write_rows(cache, pg.reshape(-1), off.reshape(-1),
+                                          _flat_rows(rows)))
 
     qg = q.reshape(b, w, hq // hkv, hkv, hd)
-    smax = tables.shape[1] * page_size
-    k_all, v_all = _gather_pages(kp, tables), _gather_pages(vp, tables)
-    ks_all = _gather_pages(kps, tables) if int8_cache else None
-    vs_all = _gather_pages(vps, tables) if int8_cache else None
-    valid = _verify_valid(pos, w, smax)
-    out = _decode_attend(qg, k_all, v_all, ks_all, vs_all, valid)
+    valid = _verify_valid(pos, w, tables.shape[1] * page_size)
+    out = _decode_attend(qg, *_kv_rows(new_cache, tables, page_size, hd), valid)
     out = out.astype(x.dtype).reshape(b, w, hq * hd)
     return linear(out, p["wo"], qm, be, tp="row"), new_cache
 
@@ -500,48 +543,35 @@ def attention_chunk(x, p, cfg: ModelConfig, cache, tables_row, start, *,
                     positions):
     """Chunked-prefill extend of one lane's paged KV (B == 1).
 
-    x: (1, C, d) chunk hidden states; cache: this layer's page pools;
-    tables_row: (1, P) block-table row; start: (1,) absolute position of
-    the chunk's first token; positions: (1, C) for RoPE.  Writes the
-    chunk's K/V into the lane's pages, then attends gathered prefix +
-    chunk under the standard causal mask.  Prior chunks' rows are bitwise
-    what full prefill computes (the bf16 cache roundtrip is lossless) and
-    a padded tail is overwritten by the next chunk before any query can
-    attend it, so chunking stays output-invisible.
+    x: (1, C, d) chunk hidden states; cache: this layer's view of the
+    page pools (as :func:`paged_attention_decode` takes); tables_row:
+    (1, P) block-table row; start: (1,) absolute position of the chunk's
+    first token; positions: (1, C) for RoPE.  Writes the chunk's K/V into the lane's
+    pages, then attends gathered prefix + chunk under the standard causal
+    mask.  Prior chunks' rows are bitwise what full prefill computes (the
+    bf16 cache roundtrip is lossless) and a padded tail is overwritten by
+    the next chunk before any query can attend it, so chunking stays
+    output-invisible.
     """
     b, cs, _ = x.shape
     hd, hq, hkv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     qm, be = cfg.quant_mode, cfg.gemm_backend
-    int8_cache = "kp_scale" in cache
+    int8_cache = "kvp_scale" in cache
     q = linear(x, p["wq"], qm, be, tp="col").reshape(b, cs, hq, hd)
     k = linear(x, p["wk"], qm, be, tp="col").reshape(b, cs, hkv, hd)
     v = linear(x, p["wv"], qm, be, tp="col").reshape(b, cs, hkv, hd)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
 
-    kp, vp = cache["kp"], cache["vp"]
-    page_size = kp.shape[1]
+    page_size = page_size_of(cache)
     pg, off = _chunk_pages(tables_row, start, cs, page_size)
+    rows = _kv_new_rows(k[0], v[0], int8_cache)
+    new_cache = dict(cache, **_write_rows(cache, pg, off, rows))
 
-    new_cache = dict(cache)
-    if int8_cache:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        kp, vp = kp.at[pg, off].set(kq[0]), vp.at[pg, off].set(vq[0])
-        kps = cache["kp_scale"].at[pg, off].set(ks[0])
-        vps = cache["vp_scale"].at[pg, off].set(vs[0])
-        new_cache.update(kp_scale=kps, vp_scale=vps)
-    else:
-        kp = kp.at[pg, off].set(k[0].astype(kp.dtype))
-        vp = vp.at[pg, off].set(v[0].astype(vp.dtype))
-    new_cache.update(kp=kp, vp=vp)
-
-    k_all, v_all = _gather_pages(kp, tables_row), _gather_pages(vp, tables_row)
+    k_all, v_all, ks_all, vs_all = _kv_rows(new_cache, tables_row, page_size, hd)
     if int8_cache:
         # prefill-side chunks attend the dequantized pages (tolerance path;
         # the exactness argument applies to the full-precision pools)
-        ks_all = _gather_pages(kps, tables_row)
-        vs_all = _gather_pages(vps, tables_row)
         k_all = (k_all.astype(jnp.float32) * ks_all[..., None]).astype(x.dtype)
         v_all = (v_all.astype(jnp.float32) * vs_all[..., None]).astype(x.dtype)
     qg = q.reshape(b, cs, hq // hkv, hkv, hd)
@@ -731,32 +761,42 @@ def mla_verify(x, p, cfg: ModelConfig, ckv_cache, krope_cache, pos):
     return linear(out, p["wo"], qm, be, tp="row"), (ckv_cache, krope_cache)
 
 
+def _mla_new_rows(c_kv, k_rope):
+    """(N, rank) latents and (N, 1, rope) rope keys as pool rows."""
+    return {"ckvp": c_kv, "krp": k_rope.reshape(c_kv.shape[0], -1)}
+
+
+def _mla_views(cache, tables):
+    """Layer ``cache["layer"]``'s latent and rope-key rows of every lane's
+    table."""
+    page_size, layer = page_size_of(cache), cache["layer"]
+    return (_gather_pages(cache["ckvp"], "ckvp", layer, tables, page_size),
+            _gather_pages(cache["krp"], "krp", layer, tables, page_size))
+
+
 def mla_paged_decode(x_t, p, cfg: ModelConfig, cache, pos, tables, *,
                      active=None):
-    """Absorbed MLA decode over latent page pools.
+    """Absorbed MLA decode over one layer of the latent page pools.
 
-    cache: {"ckvp","krp"} with (n_pages, page_size, rank) payloads — the
-    compressed latents are already the MLA memory saving; paging makes the
-    *pool* shared across lanes.  jnp gather twin only (the latent view is
-    rank-sized, far below the GQA KV stream the Pallas kernel targets).
+    cache: a layer's view of the pools (``transformer.on_layer``):
+    {"ckvp","krp"} with a leading layer axis (layouts in
+    ``models/kvcache.paged_block_cache_shape``) and "layer", the layer's
+    index into them.  The compressed latents are already the MLA memory
+    saving; paging makes the *pool* shared across lanes.  jnp gather twin
+    only: one gather of the layer's pages of every lane's whole table.
     """
-    m = cfg.mla
-    b = x_t.shape[0]
-    qm, be = cfg.quant_mode, cfg.gemm_backend
     q_nope, q_rope, c_kv_t, k_rope_t = _mla_qkv(x_t, p, cfg, pos[:, None])
 
     with jax.named_scope(MLA_SCOPE):
-        ckvp, krp = cache["ckvp"], cache["krp"]
-        pg, off = _write_page(tables, pos, ckvp.shape[1], active)
-        ckvp = ckvp.at[pg, off].set(c_kv_t[:, 0].astype(ckvp.dtype))
-        krp = krp.at[pg, off].set(
-            k_rope_t.reshape(b, m.qk_rope_head_dim).astype(krp.dtype))
-        new_cache = dict(cache, ckvp=ckvp, krp=krp)
-        ckv_view = _gather_pages(ckvp, tables)
-        kr_view = _gather_pages(krp, tables)
+        pg, off = _write_page(tables, pos, page_size_of(cache), active)
+        rows = _mla_new_rows(c_kv_t[:, 0], k_rope_t[:, 0])
+        new_cache = dict(cache, **_write_rows(cache, pg, off, rows,
+                                              pages_distinct=True))
+        ckv_view, kr_view = _mla_views(new_cache, tables)
         out = _mla_attend(q_nope, q_rope, ckv_view, kr_view, pos, p, cfg)
     out = out.astype(x_t.dtype)
-    return linear(out, p["wo"], qm, be, tp="row"), new_cache
+    return linear(out, p["wo"], cfg.quant_mode, cfg.gemm_backend,
+                  tp="row"), new_cache
 
 
 def mla_paged_verify(x, p, cfg: ModelConfig, cache, pos, tables, *,
@@ -764,24 +804,20 @@ def mla_paged_verify(x, p, cfg: ModelConfig, cache, pos, tables, *,
     """W-token speculative verify over the latent page pools (the paged
     twin of :func:`mla_verify`; gather path only, like
     :func:`mla_paged_decode`)."""
-    m = cfg.mla
     b, w, _ = x.shape
-    qm, be = cfg.quant_mode, cfg.gemm_backend
     positions = pos[:, None] + jnp.arange(w)[None, :]
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(x, p, cfg, positions)
 
     with jax.named_scope(MLA_SCOPE):
-        ckvp, krp = cache["ckvp"], cache["krp"]
-        pg, off = _write_pages(tables, pos, w, ckvp.shape[1], active)
-        ckvp = ckvp.at[pg, off].set(c_kv.astype(ckvp.dtype))
-        krp = krp.at[pg, off].set(
-            k_rope.reshape(b, w, m.qk_rope_head_dim).astype(krp.dtype))
-        new_cache = dict(cache, ckvp=ckvp, krp=krp)
-        ckv_view = _gather_pages(ckvp, tables)
-        kr_view = _gather_pages(krp, tables)
+        pg, off = _write_pages(tables, pos, w, page_size_of(cache), active)
+        rows = _mla_new_rows(c_kv.reshape(b * w, -1), k_rope)
+        new_cache = dict(cache, **_write_rows(cache, pg.reshape(-1),
+                                              off.reshape(-1), rows))
+        ckv_view, kr_view = _mla_views(new_cache, tables)
         out = _mla_attend(q_nope, q_rope, ckv_view, kr_view, pos, p, cfg)
     out = out.astype(x.dtype)
-    return linear(out, p["wo"], qm, be, tp="row"), new_cache
+    return linear(out, p["wo"], cfg.quant_mode, cfg.gemm_backend,
+                  tp="row"), new_cache
 
 
 def mla_chunk(x, p, cfg: ModelConfig, cache, tables_row, start, *, positions):
@@ -800,15 +836,11 @@ def mla_chunk(x, p, cfg: ModelConfig, cache, tables_row, start, *, positions):
     qm, be = cfg.quant_mode, cfg.gemm_backend
     q_nope, q_rope, c_kv, k_rope = _mla_qkv(x, p, cfg, positions)
 
-    ckvp, krp = cache["ckvp"], cache["krp"]
-    pg, off = _chunk_pages(tables_row, start, cs, ckvp.shape[1])
-    ckvp = ckvp.at[pg, off].set(c_kv[0].astype(ckvp.dtype))
-    krp = krp.at[pg, off].set(
-        k_rope.reshape(b, cs, m.qk_rope_head_dim)[0].astype(krp.dtype))
-    new_cache = dict(cache, ckvp=ckvp, krp=krp)
+    pg, off = _chunk_pages(tables_row, start, cs, page_size_of(cache))
+    rows = _mla_new_rows(c_kv[0], k_rope[0])
+    new_cache = dict(cache, **_write_rows(cache, pg, off, rows))
 
-    ckv_all = _gather_pages(ckvp, tables_row)                  # (1, L, rank)
-    kr_all = _gather_pages(krp, tables_row)                    # (1, L, rope)
+    ckv_all, kr_all = _mla_views(new_cache, tables_row)        # (1, L, rank/rope)
     smax = ckv_all.shape[1]
     k_nope = linear(ckv_all, p["w_uk"], qm, be, tp="col").reshape(b, smax, h, m.qk_nope_head_dim)
     v_all = linear(ckv_all, p["w_uv"], qm, be, tp="col").reshape(b, smax, h, m.v_head_dim)

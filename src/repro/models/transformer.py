@@ -19,6 +19,7 @@ from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import recurrent as rec
+from repro.models.kvcache import POOL_KEYS, is_paged
 from repro.models.layers import glu_mlp, init_glu_mlp, init_rmsnorm, rmsnorm
 
 
@@ -166,25 +167,41 @@ def apply_block_prefill(x, p, kind: str, cfg: ModelConfig, positions, cache_temp
 # Decode-time single-token application
 # ---------------------------------------------------------------------------
 
+def on_layer(paged_fn, cache, layer):
+    """``paged_fn(view) -> (out, view)`` on a layer's view of stacked page
+    pools: the pools with a leading layer axis, and under ``"layer"`` the
+    layer's index into them.  A scanned block's pools are the scan's
+    carried stacks at ``layer``; an unscanned block's own pools (``layer``
+    None) are a stack of one."""
+    if layer is not None:
+        out, view = paged_fn({**cache, "layer": layer})
+        return out, {k: v for k, v in view.items() if k != "layer"}
+    view = {k: v[None] if k in POOL_KEYS else v for k, v in cache.items()}
+    out, view = paged_fn({**view, "layer": 0})
+    return out, {k: v[0] if k in POOL_KEYS else v
+                 for k, v in view.items() if k != "layer"}
+
+
 def apply_block_decode(x_t, p, kind: str, cfg: ModelConfig, cache, pos,
-                       tables=None, active=None):
+                       tables=None, active=None, layer=None):
     """One-token decode through one block.  A paged cache is recognized by
     its pool keys (``kp``/``ckvp``); ``tables`` are the block tables
     threaded down from the cache root, ``active`` the live-lane mask (see
-    ``model.decode_step``).  Per-lane kinds (recurrent state, local-attn
+    ``model.decode_step``), ``layer`` the block's layer in stacked pools
+    (see :func:`scan_pools`).  Per-lane kinds (recurrent state, local-attn
     rings) take the same path in both cache modes."""
     kind = effective_kind(kind, cfg)
     h = rmsnorm(x_t, p["norm1"], cfg.norm_eps)
     if kind in ATTN_KINDS:
-        if "ckvp" in cache:
-            a, cache = attn.mla_paged_decode(h, p["attn"], cfg, cache, pos,
-                                             tables, active=active)
+        if is_paged(cache):
+            paged = (attn.mla_paged_decode if "ckvp" in cache
+                     else attn.paged_attention_decode)
+            a, cache = on_layer(
+                lambda c: paged(h, p["attn"], cfg, c, pos, tables, active=active),
+                cache, layer)
         elif cfg.uses_mla(kind):
             a, (ckv, kr) = attn.mla_decode(h, p["attn"], cfg, cache["ckv"], cache["kr"], pos)
             cache = {**cache, "ckv": ckv, "kr": kr}
-        elif "kp" in cache:
-            a, cache = attn.paged_attention_decode(h, p["attn"], cfg, cache, pos,
-                                                   tables, active=active)
         else:
             window = cfg.sliding_window if kind == "local_attn" else None
             a, cache = attn.attention_decode(h, p["attn"], cfg, cache, pos, window=window)
@@ -215,7 +232,7 @@ def apply_block_decode(x_t, p, kind: str, cfg: ModelConfig, cache, pos,
 
 
 def apply_block_verify(x, p, kind: str, cfg: ModelConfig, cache, pos,
-                       tables=None, active=None):
+                       tables=None, active=None, layer=None):
     """W-token speculative verify through one block.
 
     The verify twin of :func:`apply_block_decode`, restricted to the
@@ -227,15 +244,15 @@ def apply_block_verify(x, p, kind: str, cfg: ModelConfig, cache, pos,
     kind = effective_kind(kind, cfg)
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
     if kind in ("attn", "mla", "dense_ffn_layer"):
-        if "ckvp" in cache:
-            a, cache = attn.mla_paged_verify(h, p["attn"], cfg, cache, pos,
-                                             tables, active=active)
+        if is_paged(cache):
+            paged = (attn.mla_paged_verify if "ckvp" in cache
+                     else attn.paged_attention_verify)
+            a, cache = on_layer(
+                lambda c: paged(h, p["attn"], cfg, c, pos, tables, active=active),
+                cache, layer)
         elif cfg.uses_mla(kind):
             a, (ckv, kr) = attn.mla_verify(h, p["attn"], cfg, cache["ckv"], cache["kr"], pos)
             cache = {**cache, "ckv": ckv, "kr": kr}
-        elif "kp" in cache:
-            a, cache = attn.paged_attention_verify(h, p["attn"], cfg, cache, pos,
-                                                   tables, active=active)
         else:
             a, cache = attn.attention_verify(h, p["attn"], cfg, cache, pos)
         x = x + a
@@ -246,25 +263,53 @@ def apply_block_verify(x, p, kind: str, cfg: ModelConfig, cache, pos,
     return x, cache
 
 
+def scan_pools(period, x, stacked_params, stacked_cache, unroll=1):
+    """``lax.scan`` of ``period(h, slot_params, slot_caches, layer) -> (h,
+    slot_caches)`` over the stacked periods, the page pools carried.
+
+    Every leaf indexed through ``block_tables`` (``kvcache.POOL_KEYS``)
+    rides the loop carry whole, so each layer writes its new rows into the
+    stacked pool in place, at ``(layer, page, offset)``, and reads it in
+    place at ``layer`` (the period index, from ``xs``).  Passed as
+    ``xs``/``ys`` instead, each layer would slice its whole pool out of the
+    stack and write it back.  The other leaves (per-lane state, slot
+    caches) stay ``xs``/``ys``.
+    """
+    pools = tuple({k: v for k, v in c.items() if k in POOL_KEYS}
+                  for c in stacked_cache)
+    rest = tuple({k: v for k, v in c.items() if k not in POOL_KEYS}
+                 for c in stacked_cache)
+    n_periods = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]
+
+    def period_fn(carry, xs):
+        h, pools = carry
+        slot_params, slot_rest, layer = xs
+        h, caches = period(h, slot_params,
+                           tuple({**r, **c} for r, c in zip(slot_rest, pools)),
+                           layer)
+        return ((h, tuple({k: c[k] for k in pl} for c, pl in zip(caches, pools))),
+                tuple({k: c[k] for k in r} for c, r in zip(caches, slot_rest)))
+
+    (x, pools), rest = jax.lax.scan(
+        period_fn, (x, pools), (stacked_params, rest, jnp.arange(n_periods)),
+        unroll=unroll)
+    return x, tuple({**r, **c} for r, c in zip(rest, pools))
+
+
 def scan_periods_verify(x, stacked_params, stacked_cache, cfg: ModelConfig, pos,
                         tables=None, active=None):
     from repro.runtime.sharding import constrain_decode_carry
 
-    pattern = cfg.block_pattern
-
-    def period_fn(carry, xs):
-        h = constrain_decode_carry(carry)  # TP: see scan_periods_decode
-        slot_params, slot_cache = xs
+    def period(h, slot_params, slot_cache, layer):
+        h = constrain_decode_carry(h)  # TP: see scan_periods_decode
         new_cache = []
-        for s, kind in enumerate(pattern):
+        for s, kind in enumerate(cfg.block_pattern):
             h, c = apply_block_verify(h, slot_params[s], kind, cfg, slot_cache[s], pos,
-                                      tables=tables, active=active)
+                                      tables=tables, active=active, layer=layer)
             new_cache.append(c)
         return h, tuple(new_cache)
 
-    x, new_cache = jax.lax.scan(period_fn, x, (stacked_params, stacked_cache),
-                                unroll=cfg.scan_unroll)
-    return x, new_cache
+    return scan_pools(period, x, stacked_params, stacked_cache, cfg.scan_unroll)
 
 
 # ---------------------------------------------------------------------------
@@ -317,25 +362,20 @@ def scan_periods_decode(x_t, stacked_params, stacked_cache, cfg: ModelConfig, po
                         tables=None, active=None):
     from repro.runtime.sharding import constrain_decode_carry
 
-    pattern = cfg.block_pattern
-
-    def period_fn(carry, xs):
+    def period(h, slot_params, slot_cache, layer):
         # TP: pin the (B, 1, d) carry replicated-over-model between periods
         # so the partitioner never round-trips it through sharded layouts
-        h = constrain_decode_carry(carry)
-        slot_params, slot_cache = xs
+        h = constrain_decode_carry(h)
         new_cache = []
-        for s, kind in enumerate(pattern):
+        for s, kind in enumerate(cfg.block_pattern):
             # tables/active are loop-invariant captures: every period indexes
-            # its own page pool through the same per-lane block tables
+            # its own layer of the carried pools through the same tables
             h, c = apply_block_decode(h, slot_params[s], kind, cfg, slot_cache[s], pos,
-                                      tables=tables, active=active)
+                                      tables=tables, active=active, layer=layer)
             new_cache.append(c)
         return h, tuple(new_cache)
 
-    x_t, new_cache = jax.lax.scan(period_fn, x_t, (stacked_params, stacked_cache),
-                                  unroll=cfg.scan_unroll)
-    return x_t, new_cache
+    return scan_pools(period, x_t, stacked_params, stacked_cache, cfg.scan_unroll)
 
 
 def scan_periods_prefill(x, stacked_params, stacked_cache_tpl, cfg: ModelConfig, positions):

@@ -3,10 +3,11 @@
 ``PagedCache`` is the paged counterpart of ``serving.slots.SlotCache``:
 one pool pytree allocated once (built from
 ``models/model.paged_cache_shapes``), with attention-family KV in global
-``(n_pages, page_size, ...)`` pools and per-lane state (recurrent cells,
-local-attention rings, ``pos``) in lane-indexed leaves.  Host bookkeeping
-lives in ``manager.PageManager``; the block table is the only
-host-mutated array the jitted decode step reads.
+pools of ``n_pages`` pages (``models/kvcache.paged_block_cache_shape``; a
+scanned stack's pools carry a leading layer axis) and per-lane state
+(recurrent cells, local-attention rings, ``pos``) in lane-indexed leaves.
+Host bookkeeping lives in ``manager.PageManager``; the block table is the
+only host-mutated array the jitted decode step reads.
 
 ``paged_insert`` is traceable so the engine can fuse
 prefill + first-token sample + page scatter into ONE dispatch, exactly
@@ -20,15 +21,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, default_page_count, pages_for
 from repro.models import model as model_lib
-from repro.models.kvcache import zeros_like_shapes
+from repro.models.kvcache import (
+    is_paged, page_size_of, pool_rows, to_pages, zeros_like_shapes)
 from repro.paging.manager import PageManager
-
-# paged-pool leaf -> the key holding the same rows in a contiguous
-# (batch=1) prefill cache from ``model.prefill``
-_POOL_KEY_MAP = {
-    "kp": "k", "vp": "v", "kp_scale": "k_scale", "vp_scale": "v_scale",
-    "ckvp": "ckv", "krp": "kr",
-}
 
 
 def _lane_update(full, part, lane, axis):
@@ -54,22 +49,14 @@ def _scatter_block(pool_blk, single_blk, lane, page_ids, stacked: bool,
     pages, per-lane dicts scatter the lane row.  ``stacked`` marks leaves
     with a leading scanned-period axis; ``src_row`` picks the batch row of
     a batch=k prefill cache (stacked admissions scatter one row per lane)."""
-    if any(k in pool_blk for k in ("kp", "ckvp")):
-        out = {}
+    if is_paged(pool_blk):
+        out, ps = {}, page_size_of(pool_blk)
         for pk, leaf in pool_blk.items():
-            src = single_blk[_POOL_KEY_MAP[pk]]
-            if stacked:
-                rows = src[:, src_row]                # (periods, S, ...)
-                ps = leaf.shape[2]
-                rows = rows.reshape(
-                    (rows.shape[0], rows.shape[1] // ps, ps) + rows.shape[2:])
-                out[pk] = leaf.at[:, page_ids].set(rows.astype(leaf.dtype))
-            else:
-                rows = src[src_row]                   # (S, ...)
-                ps = leaf.shape[1]
-                rows = rows.reshape(
-                    (rows.shape[0] // ps, ps) + rows.shape[1:])
-                out[pk] = leaf.at[page_ids].set(rows.astype(leaf.dtype))
+            rows = pool_rows(pk, single_blk)          # ([periods,] batch, S, ...)
+            rows = rows[:, src_row] if stacked else rows[src_row]
+            pages = to_pages(pk, rows, ps)
+            idx = (slice(None), page_ids) if stacked else page_ids
+            out[pk] = leaf.at[idx].set(pages.astype(leaf.dtype))
         return out
     axis = 1 if stacked else 0
     return jax.tree_util.tree_map(
@@ -143,7 +130,7 @@ _paged_insert = jax.jit(paged_insert, donate_argnums=(0,))
 
 
 def _move_pages_block(blk, src, dst, stacked: bool):
-    if not any(k in blk for k in ("kp", "ckvp")):
+    if not is_paged(blk):
         return blk
     if stacked:
         return {k: leaf.at[:, dst].set(leaf[:, src]) for k, leaf in blk.items()}
@@ -192,7 +179,7 @@ class PagedCache:
             # so the decode program never changes between steps.
             from repro.runtime.sharding import named, pool_specs
 
-            shardings = named(pool_specs(shapes, mesh), mesh)
+            shardings = named(pool_specs(shapes, mesh, cfg.n_kv_heads), mesh)
             self.cache = jax.device_put(zeros_like_shapes(shapes), shardings)
             self._table_sharding = shardings["block_tables"]
         else:

@@ -105,8 +105,9 @@ def _lane_state_update(cache, new_state, lane):
 
 
 def _apply_block_chunk(x, p, kind: str, cfg: ModelConfig, cache, table_row,
-                       lane, start, true_len, positions):
-    """One block over a (1, C, d) chunk against the paged cache."""
+                       lane, start, true_len, positions, layer=None):
+    """One block over a (1, C, d) chunk against the paged cache (``layer``:
+    the block's layer in the scan's carried pools, None unscanned)."""
     kind = tfm.effective_kind(kind, cfg)
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
     if kind in STATEFUL_CHUNK_KINDS:
@@ -126,7 +127,9 @@ def _apply_block_chunk(x, p, kind: str, cfg: ModelConfig, cache, table_row,
     if kind not in CHUNKABLE_KINDS:
         raise ValueError(f"block kind {kind!r} is not chunkable")
     chunk = attn.mla_chunk if cfg.uses_mla(kind) else attn.attention_chunk
-    a, cache = chunk(h, p["attn"], cfg, cache, table_row, start, positions=positions)
+    a, cache = tfm.on_layer(
+        lambda c: chunk(h, p["attn"], cfg, c, table_row, start,
+                        positions=positions), cache, layer)
     x = x + a
     h2 = rmsnorm(x, p["norm2"], cfg.norm_eps)
     x = x + glu_mlp(h2, p["mlp"], cfg.act, cfg.quant_mode,
@@ -171,23 +174,18 @@ def make_chunk_step(cfg: ModelConfig, chunk_len: int):
             new_cache["head_blocks"][i] = c
 
         if params.get("blocks", ()):
-            pattern = cfg.block_pattern
-
-            def period_fn(h, xs):
-                slot_params, slot_cache = xs
+            def period(h, slot_params, slot_cache, layer):
                 out = []
-                for s, kind in enumerate(pattern):
+                for s, kind in enumerate(cfg.block_pattern):
                     h, c = _apply_block_chunk(h, slot_params[s], kind, cfg,
                                               slot_cache[s], table_row,
                                               lane, start, true_len,
-                                              positions)
+                                              positions, layer)
                     out.append(c)
                 return h, tuple(out)
 
-            x, nb = jax.lax.scan(period_fn, x,
-                                 (params["blocks"], cache["blocks"]),
-                                 unroll=cfg.scan_unroll)
-            new_cache["blocks"] = nb
+            x, new_cache["blocks"] = tfm.scan_pools(
+                period, x, params["blocks"], cache["blocks"], cfg.scan_unroll)
 
         new_cache["tail_blocks"] = list(cache["tail_blocks"])
         for i, p in enumerate(params.get("tail_blocks", [])):

@@ -214,24 +214,25 @@ def opt_state_specs(opt_shapes, p_specs, mesh, zero1: bool = True):
 # Paged-pool specs (serving: repro/paging/cache.py page pools)
 # ---------------------------------------------------------------------------
 
-# pool leaves carrying a KV-head axis: kp/vp are (n_pages, page_size, Hkv,
-# head_dim) (stacked variants prepend n_periods), their int8 scales drop
-# the trailing head_dim
-_POOL_HEAD_AXIS = {"kp": -2, "vp": -2, "kp_scale": -1, "vp_scale": -1}
+# pool leaves whose lane axis holds the KV heads side by side: kp/vp rows
+# (H_kv * head_dim) and the int8 scale rows (H_kv * page_size), head-major
+_POOL_HEAD_LEAVES = ("kp", "vp", "kvp_scale")
 
 
-def pool_specs(cache_shapes_tree, mesh):
+def pool_specs(cache_shapes_tree, mesh, n_kv_heads: int):
     """PartitionSpecs for a paged serving cache (``paged_cache_shapes``).
 
-    Page pools shard their KV-head axis over "model" — the axis the
-    attention shards its heads over, so each device's pool slice feeds its
-    own head shard with no gather traffic.  MLA latent pools (``ckvp``)
-    shard the latent rank; the shared rope pool (``krp``) is replicated
-    (every head shard reads all rope dims).  ``pos`` and ``block_tables``
-    replicate: the host-side ``PageManager`` stays the single source of
-    truth and every device sees the same table.  Per-lane leaves
-    (recurrent state, local-attention rings) shard their trailing width.
-    Any non-divisible dim falls back to replication — never an error.
+    Page pools shard their KV heads over "model" — the axis the attention
+    shards its heads over, so each device's pool slice feeds its own head
+    shard with no gather traffic.  The heads lie side by side along a
+    pool's lane axis, so that axis is split into whole heads, where the
+    mesh divides ``n_kv_heads``.  MLA latent pools (``ckvp``) shard the
+    latent rank; the shared rope pool (``krp``) is replicated (every head
+    shard reads all rope dims).  ``pos`` and ``block_tables`` replicate:
+    the host-side ``PageManager`` stays the single source of truth and
+    every device sees the same table.  Per-lane leaves (recurrent state,
+    local-attention rings) shard their trailing width.  Any non-divisible
+    dim falls back to replication — never an error.
     """
     model = _axis_size(mesh, "model")
 
@@ -240,14 +241,11 @@ def pool_specs(cache_shapes_tree, mesh):
         name = _leaf_name(path)
         nd = len(shape)
         axes = [None] * nd
-        if name in ("pos", "block_tables") or nd < 2:
+        if name in ("pos", "block_tables") or nd < 2 or name == "krp":
             return P(*axes)
-        if name in _POOL_HEAD_AXIS:
-            dim = nd + _POOL_HEAD_AXIS[name]
-            if shape[dim] % model == 0:
-                axes[dim] = "model"
-            return P(*axes)
-        if name == "krp":
+        if name in _POOL_HEAD_LEAVES:
+            if n_kv_heads % model == 0:
+                axes[-1] = "model"
             return P(*axes)
         # ckvp latent pools and per-lane leaves (recurrent h/conv/C/n/c,
         # local-attn rings): trailing width over "model" when divisible

@@ -82,7 +82,7 @@ from repro.configs.base import (
     default_cache_len,
     pages_for,
 )
-from repro.kernels.paged_attention import pages_per_block
+from repro.kernels.paged_attention import page_bytes, pages_per_block
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.obs import DISABLED, Observability
 from repro.paging import (
@@ -121,10 +121,11 @@ def _attn_block_geometry(store: PagedCache):
     cache = store.cache
     for blk in (*cache["head_blocks"], *cache["blocks"], *cache["tail_blocks"]):
         if "kp" in blk:
-            kp = blk["kp"]
-            ps, hkv, d = kp.sharding.shard_shape(kp.shape)[-3:]
-            ppb = pages_per_block(ps, hkv, d, kp.dtype, store.max_pages)
-            return ppb * ps, -(-store.max_pages // ppb)
+            pools = [jax.ShapeDtypeStruct(l.sharding.shard_shape(l.shape), l.dtype)
+                     for l in blk.values()]
+            ppb = pages_per_block(store.page_size, page_bytes(pools),
+                                  store.max_pages)
+            return ppb * store.page_size, -(-store.max_pages // ppb)
     return None
 
 

@@ -70,6 +70,6 @@ def init_sharded_params(cfg, key, mesh=None):
     return jax.jit(init, out_shardings=named(specs, mesh))(key)
 
 
-def pool_shardings(cache_shapes_tree, mesh):
+def pool_shardings(cache_shapes_tree, mesh, n_kv_heads: int):
     """NamedShardings for a paged pool tree (see ``sharding.pool_specs``)."""
-    return named(pool_specs(cache_shapes_tree, mesh), mesh)
+    return named(pool_specs(cache_shapes_tree, mesh, n_kv_heads), mesh)

@@ -62,30 +62,29 @@ def sharded_gemm_dequant(backend, x, w, x_scale, w_scale, spec, mesh,
     )(x, w, x_scale, w_scale)
 
 
-def sharded_paged_attention(q, kp, vp, tables, lengths, *, k_scale=None,
-                            v_scale=None, interpret=False):
+def sharded_paged_attention(q, kp, vp, tables, lengths, layer, *, scales=None,
+                            interpret=False):
     """``kernels.paged_attention`` under the ambient mesh (if any): KV heads
     over "model" when the mesh divides them, as ``pool_specs`` shards the
-    pools; the kernel itself when there is no mesh."""
+    stacked pools' head-major lane axis; the kernel itself when there is no
+    mesh."""
     from repro.kernels.paged_attention import paged_attention
 
     mesh = ambient_mesh()
-    int8 = k_scale is not None
 
-    def attend(q, kp, vp, tables, lengths, *scales):
-        return paged_attention(q, kp, vp, tables, lengths,
-                               k_scale=scales[0] if int8 else None,
-                               v_scale=scales[1] if int8 else None,
+    def attend(q, kp, vp, tables, lengths, layer, *scales):
+        return paged_attention(q, kp, vp, tables, lengths, layer,
+                               scales=scales[0] if scales else None,
                                interpret=interpret)
 
-    scales = (k_scale, v_scale) if int8 else ()
+    scales = () if scales is None else (scales,)
     if mesh is None:
-        return attend(q, kp, vp, tables, lengths, *scales)
+        return attend(q, kp, vp, tables, lengths, layer, *scales)
     heads = model_axis_for(mesh, q.shape[1])
     qs = P(None, heads, None, None)
-    pool = P(None, None, heads, None)
+    pool = P(None, None, None, heads)
     return jax.shard_map(
         attend, mesh=mesh,
-        in_specs=(qs, pool, pool, P(), P()) + (P(None, None, heads),) * len(scales),
+        in_specs=(qs, pool, pool, P(), P(), P()) + (pool,) * len(scales),
         out_specs=qs, check_vma=False,
-    )(q, kp, vp, tables, lengths, *scales)
+    )(q, kp, vp, tables, lengths, layer, *scales)

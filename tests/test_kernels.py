@@ -105,3 +105,43 @@ class TestSpogaGemmDequant:
                                  block_k=64, interpret=True)
         want = ref_spoga_gemm_dequant(x, w, xs, ws)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
+
+
+# -- paged attention on stacked, lane-dense pools ---------------------------
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_paged_attention_reads_each_layer_of_a_stacked_pool(int8, head_dim):
+    """The kernel on a stack of layers' pools, told a layer, equals the
+    reference on that layer's pages alone — for every layer, over ragged
+    lengths with idle (length-1) lanes among them."""
+    from repro.kernels.paged_attention import paged_attention, paged_attention_ref
+
+    rng = np.random.default_rng(head_dim + int8)
+    n_layers, hkv, g, ps, n_tbl = 3, 2, 3, 16, 19
+    lengths = jnp.asarray([1, 129, 300, 1, n_tbl * ps, 17], jnp.int32)
+    b = lengths.shape[0]
+    n_pages = b * n_tbl + 1
+    tables = jnp.asarray(rng.permutation(np.arange(1, n_pages)).reshape(b, n_tbl),
+                         jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, hkv, g, head_dim)), jnp.float32)
+    kv = (n_layers, n_pages, ps, hkv * head_dim)
+    if int8:
+        kp, vp = (jnp.asarray(rng.integers(-127, 128, kv), jnp.int8) for _ in "kv")
+        pools = dict(scales=jnp.asarray(
+            rng.uniform(0.005, 0.02, (n_layers, n_pages, 2, hkv * ps)), jnp.float32))
+    else:
+        kp, vp = (jnp.asarray(rng.normal(size=kv), jnp.bfloat16) for _ in "kv")
+        pools = {}
+    outs = []
+    for layer in range(n_layers):
+        one = {k: v[layer][None] for k, v in pools.items()}
+        want = paged_attention_ref(q, kp[layer][None], vp[layer][None], tables,
+                                   lengths, 0, **one)
+        got = paged_attention(q, kp, vp, tables, lengths, jnp.int32(layer),
+                              interpret=True, **pools)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+        outs.append(np.asarray(got))
+    # the layers hold different pages, so a kernel reading the wrong one fails
+    assert not np.allclose(outs[0], outs[1])

@@ -207,7 +207,8 @@ def test_mistral_large_tp4_footprint_analytic():
     per_dev = (
         tree_device_bytes(shapes, param_specs(shapes, mesh, cfg, fsdp=False),
                           mesh)
-        + tree_device_bytes(pshapes, pool_specs(pshapes, mesh), mesh))
+        + tree_device_bytes(pshapes, pool_specs(pshapes, mesh, cfg.n_kv_heads),
+                            mesh))
     unsharded = total_bytes(shapes) + total_bytes(pshapes)
     assert per_dev < unsharded / 2, (
         f"tp=4 per-device {per_dev/2**30:.1f} GiB not < "
